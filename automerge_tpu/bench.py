@@ -23,8 +23,6 @@ and hashes), without paying a full per-replica document replay.
 
 from __future__ import annotations
 
-import json
-import os
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -34,30 +32,50 @@ from .api import AutoDoc
 from .storage.change import HEAD_STORED, ROOT_STORED, ChangeOp, Key, StoredChange, build_change
 from .types import ActorId, ObjType, ScalarValue
 
-TRACE_PATH = "/root/reference/rust/edit-trace/edits.json"
+# Automerge's rust/edit-trace replays 259,778 keystroke edits (the LaTeX
+# source of a paper, typed over many sessions); every text workload here
+# has that length and a generated trace of that shape
+EDIT_TRACE_EDITS = 259_778
 
 _ACTION_PUT = 1
 _ACTION_DELETE = 3
 _ACTION_INCREMENT = 5
 
+_TRACE_ALPHABET = np.frombuffer(b"etaoinshrdlcumwfgypbvk        \n", np.uint8)
 
-def load_trace(limit: Optional[int] = None) -> list:
-    """The canonical editing trace (or a deterministic synthetic fallback)."""
-    if os.path.exists(TRACE_PATH):
-        with open(TRACE_PATH) as f:
-            edits = json.load(f)
-        return edits[:limit] if limit else edits
-    rng = np.random.default_rng(0)
-    n = limit or 260_000
-    edits, length = [], 0
-    for _ in range(n):
-        if length == 0 or rng.random() < 0.85:
-            edits.append([int(rng.integers(0, length + 1)), 0, "x"])
-            length += 1
+
+def synth_edit_trace(n_edits: int = EDIT_TRACE_EDITS, seed: int = 0) -> list:
+    """A seeded keystroke-shaped editing trace in edit-trace's format:
+    ``[pos, 0, ch]`` inserts one character, ``[pos, 1]`` deletes one.
+
+    The shape (all parameters assumed, not fitted): the cursor types runs
+    of geometric length (mean 12); half of them follow a backspace burst
+    (mean 10), which puts inserts at ~70% of edits as in edit-trace; between runs
+    jumps a short distance (80%, within ±64 characters) or anywhere in the
+    document (20%), so the trace has typing runs, cursor locality and
+    backspace bursts. Deterministic in ``seed``."""
+    rng = np.random.default_rng(seed)
+    edits: list = []
+    length = 0
+    cur = 0
+    while len(edits) < n_edits:
+        if length and rng.random() < 0.5:
+            burst = min(int(rng.geometric(1 / 10)), cur)
+            for _ in range(burst):
+                cur -= 1
+                edits.append([cur, 1])
+            length -= burst
+        run = int(rng.geometric(1 / 12))
+        chars = _TRACE_ALPHABET[rng.integers(0, len(_TRACE_ALPHABET), run)]
+        for ch in chars.tobytes().decode():
+            edits.append([cur, 0, ch])
+            cur += 1
+        length += run
+        if rng.random() < 0.8:
+            cur = int(np.clip(cur + rng.integers(-64, 65), 0, length))
         else:
-            edits.append([int(rng.integers(0, length)), 1])
-            length -= 1
-    return edits
+            cur = int(rng.integers(0, length + 1))
+    return edits[:n_edits]
 
 
 def apply_edits(doc: AutoDoc, text_obj: str, edits: Iterable) -> int:
@@ -111,7 +129,8 @@ class BaseInfo:
 def build_base(trace: Sequence, n_edits: int) -> BaseInfo:
     base = AutoDoc(actor=ActorId(bytes([1]) * 16))
     text = base.put_object("_root", "text", ObjType.TEXT)
-    apply_edits(base, text, trace[:n_edits])
+    # bulk native ingest: the same change bytes as an apply_edits replay
+    base.splice_text_many(text, trace[:n_edits])
     base.commit()
     return BaseInfo(base, text)
 

@@ -739,6 +739,9 @@ class ClusterNode(SocketRpcServer):
             # every adjacent replApply frame coalesces regardless of its
             # target doc — the batched drain groups per doc itself
             return ("replApply",)
+        if (self.rpc.cluster_role == "follower"
+                and req.get("method") not in _FOLLOWER_OK):
+            return None  # rpc.handle answers it NotLeader
         return super()._coalesce_key(req)
 
     def _coalesce_single(self, method) -> bool:

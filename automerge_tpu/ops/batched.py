@@ -491,13 +491,9 @@ class CrossDocBatcher:
             if self.mode == "0":
                 self._active = False
             elif self.mode == "auto":
-                plat = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
-                if plat:
-                    self._active = plat != "cpu"
-                else:
-                    import jax
+                import jax
 
-                    self._active = jax.default_backend() != "cpu"
+                self._active = jax.default_backend() != "cpu"
             else:
                 self._active = True
         return self._active
@@ -586,18 +582,15 @@ class CrossDocBatcher:
         one vectorized pass (host_batch.stage_docs), merge them with any
         submitter-staged stages (the scalar-knob mode — an env-knob flip
         mid-generation can mix the two; both drain here), launch once,
-        and release every waiter. On failure everything degrades per
-        doc."""
+        and release every waiter. A failure reaches every waiter."""
         from . import host_batch
 
         stages: List[BatchStage] = list(gen.stages)
-        subs_staged = False
         try:
             if gen.subs:
                 more, results = host_batch.stage_docs(
                     [(s.dev, s.batches) for s in gen.subs]
                 )
-                subs_staged = True
                 trace_of = {}
                 n_changes = 0
                 for s in gen.subs:
@@ -635,27 +628,17 @@ class CrossDocBatcher:
                 collect_stages(d2)
             else:
                 resolve_stages(stages, self.fallback_ratio)
-        except BaseException as e:  # noqa: BLE001 — degrade per doc
+        except BaseException as e:
+            # every document of the generation sees the failure: its
+            # changes were spliced but never resolved, so its caller must
+            # not acknowledge the device work
             obs.count("device.batched_error")
-            recovered = True
+            obs.event("device.batched_error", error=str(e)[:200])
             for st in stages:
-                try:
-                    st.doc._reresolve(st.dirty)
-                except BaseException as e2:  # noqa: BLE001
-                    st.error = e2
-                    recovered = False
-            if not subs_staged:
-                # staging itself failed before any submission's state
-                # moved: every leader-staged submitter must see it
-                for s in gen.subs:
-                    if s.error is None:
-                        s.error = e
-            for st in stages:
-                if st.error is not None:
-                    for s in gen.subs:
-                        if s.dev is st.doc and s.error is None:
-                            s.error = st.error
-            if recovered and stages:
-                obs.event("device.batched_recovered", error=str(e)[:200])
+                if st.error is None:
+                    st.error = e
+            for s in gen.subs:
+                if s.error is None:
+                    s.error = e
         finally:
             gen.done.set()

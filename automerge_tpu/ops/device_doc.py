@@ -1050,8 +1050,8 @@ class DeviceDoc:
         self, n_devices: Optional[int] = None, min_rows: Optional[int] = None
     ) -> bool:
         """Turn on mesh residency. Returns False — and stays on the
-        single-device path — when ``jax.shard_map`` or a multi-device
-        mesh is unavailable (the graceful degrade bench.py uses).
+        single-device path — when fewer than ``n_devices`` (default: all,
+        at least 2) devices exist.
         ``min_rows`` (env AUTOMERGE_TPU_MESH_MIN_ROWS, default 4096)
         keeps small re-resolutions on one chip."""
         import os
@@ -1060,14 +1060,7 @@ class DeviceDoc:
 
         if self._base is not self:
             raise ValueError("enable_mesh on a historical view; use the base doc")
-        if not hasattr(jax, "shard_map"):
-            obs.count("device.mesh_unavailable", labels={"reason": "no_shard_map"})
-            return False
-        try:
-            devs = jax.devices()
-        except Exception:
-            obs.count("device.mesh_unavailable", labels={"reason": "no_backend"})
-            return False
+        devs = jax.devices()
         want = n_devices or len(devs)
         if want < 2 or len(devs) < want:
             obs.count("device.mesh_unavailable", labels={"reason": "single_device"})
@@ -1092,7 +1085,7 @@ class DeviceDoc:
 
     def _mesh_resolve(self) -> Optional[Dict[str, np.ndarray]]:
         """One sharded full-log resolution over the mesh, or None when
-        mesh residency is off / below threshold / degraded."""
+        mesh residency is off or below threshold. A mesh failure raises."""
         if self._mesh is None:
             if self._mesh_env_tried:
                 return None
@@ -1100,21 +1093,16 @@ class DeviceDoc:
             import os
 
             nd = os.environ.get("AUTOMERGE_TPU_MESH_DEVICES")
-            if not nd:
-                return None
-            try:
-                if not self.enable_mesh(int(nd)):
-                    return None
-            except Exception:
+            if not nd or not self.enable_mesh(int(nd)):
                 return None
         if self.log.n < self._mesh_min_rows:
             return None
         try:
             return self._mesh_resolve_inner()
-        except Exception as e:  # noqa: BLE001 — degrade to single device
+        except Exception as e:
             obs.count("device.mesh_unavailable", labels={"reason": "error"})
             obs.event("device.mesh_error", error=str(e)[:200])
-            return None
+            raise
 
     def _mesh_resolve_inner(self) -> Dict[str, np.ndarray]:
         from ..parallel.sharding import sharded_merge_columns

@@ -13,6 +13,7 @@ buffer and materialized lazily on readback.
 from __future__ import annotations
 
 import threading
+from collections import OrderedDict
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -563,7 +564,7 @@ class LazyValues:
         self.off = off
         self.ln = ln
         self.raw = raw
-        self.cache: Dict[int, ScalarValue] = {}
+        self.cache: Dict[int, ScalarValue] = OrderedDict()
         self.cap = _value_cache_cap() if cap is None else cap
         self.hits = 0
         self.misses = 0
@@ -588,7 +589,9 @@ class LazyValues:
             v = self._decode(row)
             if self.cap > 0:
                 if len(self.cache) >= self.cap:
-                    self.cache.pop(next(iter(self.cache)))
+                    # O(1): next(iter(dict)) walks every slot already
+                    # popped, so a plain dict's FIFO grows quadratic
+                    self.cache.popitem(last=False)
                 self.cache[row] = v
         else:
             self.hits += 1

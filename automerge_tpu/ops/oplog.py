@@ -1260,9 +1260,12 @@ def host_linearize(cols_np) -> np.ndarray:
     just uploaded, with zero extra device traffic: a lexsort builds the
     sibling lists and the native preorder walk ranks them.
     """
-    from .. import native
+    from .. import native, obs
 
-    insert, parent_row, first_child, next_sib = host_forest(cols_np)
-    P = len(insert)
-    elem_index = native.preorder_index(first_child, next_sib, parent_row, P)
-    return np.where(insert, elem_index, np.int32(-1))
+    with obs.span("host.linearize", rows=len(cols_np["insert"])):
+        insert, parent_row, first_child, next_sib = host_forest(cols_np)
+        P = len(insert)
+        elem_index = native.preorder_index(
+            first_child, next_sib, parent_row, P
+        )
+        return np.where(insert, elem_index, np.int32(-1))

@@ -30,8 +30,8 @@ gather-latency-bound with per-device cost (P log P)/n plus log P
 all-gathers. All collectives ride the mesh axis (ICI on real chips).
 
 The packed transport (ops/merge.py encode_transport) runs through this
-path too: runs are decoded on device inside the shard_map body, so a
-tunnel-attached multi-chip host ships a few KB per column, not columns.
+path too: runs are decoded on device inside the shard_map body, so the
+host ships a few KB per column, not columns.
 """
 
 from __future__ import annotations

@@ -774,9 +774,9 @@ class RpcServer:
         h, dd = self._store_doc(name)
         try:
             dev = dd.build_device_mirror()
-        except Exception as e:  # noqa: BLE001 — promotion is best-effort
+        except Exception as e:
             obs.count("store.promote_error", error=str(e)[:200])
-            return False
+            raise
         with self._lock:
             for sh, d in self._session_docs.items():
                 if d == h:
@@ -864,8 +864,52 @@ class RpcServer:
         return _b64(self._doc(p).save_incremental_after(self._heads(p) or []))
 
     def applyChanges(self, p):
-        self._doc(p).load_incremental(_unb64(p["data"]), on_partial="error")
+        doc = self._doc(p)
+        self._feed_mirror(doc, [self._apply_bytes(doc, _unb64(p["data"]))])
         return None
+
+    @staticmethod
+    def _apply_bytes(doc, data: bytes) -> list:
+        """Apply change bytes to ``doc``; return the changes that joined
+        its history (what its device mirror must be fed)."""
+        n0 = len(doc.doc.history)
+        doc.load_incremental(data, on_partial="error")
+        return [a.stored for a in doc.doc.history[n0:]]
+
+    def _feed_mirror(self, doc, batches, feed=None) -> None:
+        """Hand changes ``doc`` just applied to its resident device
+        mirror, if it has one, through ``feed(dev, batches)`` (default
+        ``dev.apply_batches``; the serving layer passes its cross-doc
+        batcher). A failure is counted (``sync.device_feed_error``), the
+        mirror is dropped and the error propagates: the request fails
+        instead of acknowledging device work that did not happen."""
+        dev = getattr(doc, "device_doc", None)
+        batches = [b for b in batches if b]
+        if dev is None or not batches:
+            return
+        try:
+            if feed is None:
+                dev.apply_batches(batches)
+            else:
+                feed(dev, batches)
+        except Exception as e:
+            self.device_feed_failed(dev, e)
+            raise
+
+    def device_feed_failed(self, dev, e: Exception) -> None:
+        """Count a failed device feed and drop the mirror — it holds
+        changes spliced but never resolved — from its document and every
+        session, so no read serves it stale."""
+        obs.count("sync.device_feed_error", error=str(e)[:200])
+        with self._lock:
+            docs = [d for d in self._docs.values()
+                    if getattr(d, "device_doc", None) is dev]
+            sessions = [s for s in self._sessions.values()
+                        if s.device_doc is dev]
+        for d in docs:
+            d.drop_device_mirror()
+        for s in sessions:
+            s.device_doc = None
 
     def merge(self, p):
         # the merge source may be cold too: hydrate it like the target
@@ -1018,12 +1062,7 @@ class RpcServer:
         # a durable doc opened with device=true carries a resident
         # DeviceDoc: feed it incrementally so device reads stay current
         # (the serving layer coalesces runs of these into apply_batches)
-        dev = getattr(doc, "device_doc", None)
-        if dev is not None and msg.changes:
-            try:
-                dev.apply_changes(msg.changes)
-            except Exception as e:  # noqa: BLE001 — isolate the sidecar
-                obs.count("sync.device_feed_error", error=str(e)[:200])
+        self._feed_mirror(doc, [list(msg.changes)])
         return None
 
     # resilient sync sessions (retry/backoff/reset over lossy transports;
@@ -1104,8 +1143,15 @@ class RpcServer:
 
     def syncSessionReceive(self, p):
         """Feed wire bytes; corrupt or duplicate frames are absorbed (and
-        counted), never raised."""
-        accepted = self._session(p).receive(_unb64(p["data"]), time.monotonic())
+        counted), never raised. A failed device feed fails the request."""
+        sess = self._session(p)
+        dev = sess.device_doc
+        try:
+            accepted = sess.receive(_unb64(p["data"]), time.monotonic())
+        except Exception as e:
+            if dev is not None:
+                self.device_feed_failed(dev, e)
+            raise
         return {"accepted": accepted}
 
     def syncSessionStats(self, p):
@@ -1236,6 +1282,14 @@ class RpcServer:
              "syncSessionAttach"), "sync"),
     }
 
+    def note_heat(self, method: str, p: dict) -> None:
+        """Count one ``method`` request against its document's heat
+        (the serving layer calls it for the runs it executes itself)."""
+        if _heat.table.enabled:
+            kind = self._HEAT_KINDS.get(method)
+            if kind is not None:
+                self._note_heat(kind, p)
+
     def _note_heat(self, kind: str, p: dict) -> None:
         """Attribute one request (and its payload bytes) to its target
         document's heat entry. Only NAMED durable documents are
@@ -1299,10 +1353,7 @@ class RpcServer:
         return self._dispatch(rid, method, req)
 
     def _dispatch(self, rid, method: str, req: dict) -> dict:
-        if _heat.table.enabled:
-            kind = self._HEAT_KINDS.get(method)
-            if kind is not None:
-                self._note_heat(kind, req.get("params") or {})
+        self.note_heat(method, req.get("params") or {})
         # the span doubles as the per-method request counter (histogram
         # count) and latency distribution (rpc.request{method=...})
         with obs.span("rpc.request", labels={"method": method}):
@@ -1534,6 +1585,9 @@ def main(argv=None) -> int:
              "`python -m automerge_tpu flight-merge`)",
     )
     args = ap.parse_args(argv)
+    from . import compile_cache
+
+    compile_cache.enable()
     flight_dir = args.flight_dir or os.environ.get("AUTOMERGE_TPU_FLIGHT_DIR")
     if flight_dir:
         obs.flight.install(

@@ -46,6 +46,7 @@ flushing durable documents, exactly like EOF does in stdio mode.
 
 from __future__ import annotations
 
+import base64
 import contextlib
 import os
 import socket
@@ -64,7 +65,8 @@ _OPEN_DURABLE_KEY = "__open_durable__"  # serializes name-cache races
 
 # methods whose frames coalesce when adjacent in a drain (same doc, same
 # sync/session handle): their device feed batches into one apply_batches
-_COALESCE_METHODS = ("receiveSyncMessage", "syncSessionReceive")
+_COALESCE_METHODS = ("receiveSyncMessage", "syncSessionReceive",
+                     "applyChanges")
 
 # methods that must NOT hydrate a cold document before executing: they
 # either retire it (free), or exist precisely because the document is
@@ -94,6 +96,19 @@ def _run_trace_links(run) -> list:
         if isinstance(tr := req.get("trace"), dict)
     ]
     return obs.decode_wire_traces(pairs)
+
+
+def _fail_from(out, start: int, method: str, e: Exception) -> None:
+    """Turn the successful responses ``out[start:]`` into errors: their
+    run's device feed failed, so none of them is acknowledged."""
+    obs.count("rpc.errors", labels={"method": method,
+                                    "type": type(e).__name__})
+    err = {"type": type(e).__name__, "message": f"device feed failed: {e}",
+           "retriable": False}
+    out[start:] = [
+        (c, r if "error" in r else {"id": r.get("id"), "error": dict(err)})
+        for c, r in out[start:]
+    ]
 
 
 class _Conn:
@@ -680,13 +695,13 @@ class SocketRpcServer:
                 st.enter_context(lk)
             if method == "syncSessionReceive":
                 self._run_session_receive(run, out)
+            elif method == "applyChanges":
+                self._run_apply_changes(run, out)
             else:
                 self._run_receive_sync(run, out)
 
     def _run_session_receive(self, run, out) -> None:
         rpc = self.rpc
-        import base64
-
         frames, live = [], []
         for conn, req in run:
             p = req.get("params") or {}
@@ -715,51 +730,71 @@ class SocketRpcServer:
             if dev is not None
             else None
         )
-        with obs.span("rpc.request", links=_run_trace_links(run),
-                      labels={"method": "syncSessionReceive"}):
-            accepted = sess.receive_many(
-                frames, time.monotonic(), device_feed=feed
-            )
+        start, err = len(out), None
+        try:
+            with obs.span("rpc.request", links=_run_trace_links(run),
+                          labels={"method": "syncSessionReceive"}):
+                accepted = sess.receive_many(
+                    frames, time.monotonic(), device_feed=feed
+                )
+        except Exception as e:
+            if dev is not None:
+                rpc.device_feed_failed(dev, e)
+            err, accepted = e, [None] * len(live)
         for (conn, req, _), ok in zip(live, accepted):
             out.append((conn, {"id": req.get("id"),
                                "result": {"accepted": ok}}))
+        if err is not None:
+            _fail_from(out, start, "syncSessionReceive", err)
 
     def _run_receive_sync(self, run, out) -> None:
-        rpc = self.rpc
-        import base64
-
         from ..sync.protocol import Message
 
+        def receive(doc, p):
+            msg = Message.decode(base64.b64decode(p["data"]))
+            doc.receive_sync_message(self.rpc._syncs[p["sync"]], msg)
+            return list(msg.changes)
+
+        self._run_doc_feed(run, out, "receiveSyncMessage", receive)
+
+    def _run_apply_changes(self, run, out) -> None:
+        rpc = self.rpc
+        self._run_doc_feed(
+            run, out, "applyChanges",
+            lambda doc, p: rpc._apply_bytes(doc, base64.b64decode(p["data"])))
+
+    def _run_doc_feed(self, run, out, method, apply) -> None:
+        """A run of ``method`` frames for one doc: ``apply(doc, params)``
+        runs each on the host and returns the changes it applied; one
+        device feed then carries the whole run. A failed feed fails
+        every request of the run."""
+        rpc = self.rpc
         doc = None
-        changes_batches = []
+        batches = []
+        start = len(out)
         with obs.span("rpc.request", links=_run_trace_links(run),
-                      labels={"method": "receiveSyncMessage"}):
+                      labels={"method": method}):
             for conn, req in run:
                 p = req.get("params") or {}
                 if rpc.deadlines_enabled and request_expired(req):
                     out.append((conn, deadline_response(
-                        req.get("id"), "receiveSyncMessage", "pre_fsync")))
+                        req.get("id"), method, "pre_fsync")))
                     continue
+                rpc.note_heat(method, p)
                 try:
                     doc = rpc._doc(p)
-                    msg = Message.decode(base64.b64decode(p["data"]))
-                    doc.receive_sync_message(rpc._syncs[p["sync"]], msg)
-                    if msg.changes:
-                        changes_batches.append(list(msg.changes))
+                    batches.append(apply(doc, p))
                     out.append((conn, {"id": req.get("id"), "result": None}))
                 except Exception as e:
                     obs.count("rpc.errors", labels={
-                        "method": "receiveSyncMessage",
-                        "type": type(e).__name__})
+                        "method": method, "type": type(e).__name__})
                     out.append((conn, {"id": req.get("id"), "error": {
                         "type": type(e).__name__, "message": str(e),
                         "retriable": bool(getattr(e, "retriable", False))}}))
-        dev = getattr(doc, "device_doc", None)
-        if dev is not None and changes_batches:
-            try:
-                self._feed_device(dev, changes_batches)
-            except Exception as e:  # noqa: BLE001 — isolate the sidecar
-                obs.count("sync.device_feed_error", error=str(e)[:200])
+        try:
+            rpc._feed_mirror(doc, batches, feed=self._feed_device)
+        except Exception as e:
+            _fail_from(out, start, method, e)
 
     def _feed_device(self, dev, batches) -> None:
         """Route a drained document's device feed through the cross-doc

@@ -283,7 +283,15 @@ class DocStore:
                 and self._counts[TIER_HOT] >= self.budgets.hot_docs
             ):
                 return
-            if self.ops.build_device(e.name):
+            try:
+                built = self.ops.build_device(e.name)
+            except Exception:
+                # this request fails (store.promote_error counts it);
+                # later ones are served from the host until an
+                # openDurable with device=true asks again
+                e.want_device = False
+                raise
+            if built:
                 with self._lock:
                     self._counts[e.tier] -= 1
                     self._counts[TIER_HOT] += 1

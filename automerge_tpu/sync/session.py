@@ -30,10 +30,11 @@ classic ARQ toolbox:
 
 All recovery paths emit labeled ``obs`` counters (``sync.retry``,
 ``sync.reset{source=peer|epoch}``, ``sync.resync``, ``sync.dup``,
-``sync.malformed{stage=frame|message}``, ``sync.rejected``,
-``sync.device_feed_error``), and the round phases run inside
-``obs.span``s (``sync.generate``, ``sync.receive`` > ``sync.apply``) so
-a whole session renders as a flame chart via ``obs.export_trace``.
+``sync.malformed{stage=frame|message}``, ``sync.rejected``), and the
+round phases run inside ``obs.span``s (``sync.generate``,
+``sync.receive`` > ``sync.apply``) so a whole session renders as a
+flame chart via ``obs.export_trace``. A failed device feed raises to
+the caller (the serving layer counts ``sync.device_feed_error``).
 
 A session may carry a resident ``DeviceDoc`` (``device_doc=``): changes
 received off the wire feed its incremental append/re-resolve path
@@ -283,13 +284,10 @@ class SyncSession:
             self._device_batches = None
         if batches:
             obs.count("sync.coalesced_batches", n=len(batches))
-            try:
-                if device_feed is not None:
-                    device_feed(batches)
-                else:
-                    self.device_doc.apply_batches(batches)
-            except Exception as e:  # noqa: BLE001 — isolate the sidecar
-                obs.count("sync.device_feed_error", error=str(e)[:200])
+            if device_feed is not None:
+                device_feed(batches)
+            else:
+                self.device_doc.apply_batches(batches)
         return accepted
 
     def _receive(self, data: bytes, now: float) -> bool:
@@ -436,12 +434,9 @@ class SyncSession:
                 # inside receive_many: defer into one apply_batches call
                 self._device_batches.append(list(msg.changes))
             else:
-                # feed the resident device document incrementally; device-
-                # side trouble must never break the host sync session
-                try:
-                    self.device_doc.apply_changes(msg.changes)
-                except Exception as e:  # noqa: BLE001 — isolate the sidecar
-                    obs.count("sync.device_feed_error", error=str(e)[:200])
+                # feed the resident device document incrementally; a
+                # device failure propagates to the caller
+                self.device_doc.apply_changes(msg.changes)
         self.stats["received"] += 1
         self._awaiting = False
         self._retries = 0

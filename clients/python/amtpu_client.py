@@ -215,6 +215,35 @@ class RetryingClient:
             self._drop_conn()
             raise
 
+    def pipeline(self, calls, timeout: Optional[float] = None
+                 ) -> List[Dict[str, Any]]:
+        """Send ``calls`` (``[(method, params), ...]``) back to back, then
+        read their responses: one raw response dict per call, in call
+        order, no retry. The server answers requests for one document in
+        order and may interleave others, so responses match by id."""
+        self._ensure_conn(timeout=timeout)
+        self._sock.settimeout(timeout)
+        first = self._rid + 1
+        lines = []
+        for method, params in calls:
+            self._rid += 1
+            lines.append(json.dumps(
+                {"id": self._rid, "method": method, "params": params or {}}))
+        try:
+            self._sock.sendall(("\n".join(lines) + "\n").encode("utf-8"))
+            by_id: Dict[int, Dict[str, Any]] = {}
+            while len(by_id) < len(calls):
+                raw = self._f.readline()
+                if not raw:
+                    raise OSError("connection closed mid-pipeline")
+                resp = json.loads(raw)
+                if first <= resp.get("id", 0) <= self._rid:
+                    by_id[resp["id"]] = resp
+        except (OSError, ValueError):
+            self._drop_conn()
+            raise
+        return [by_id[first + i] for i in range(len(calls))]
+
     # -- the reference retry loop --------------------------------------------
 
     def call(self, method: str, *, deadline_s: Optional[float] = None,

@@ -13,7 +13,7 @@ import pstats
 from automerge_tpu import bench as W
 from automerge_tpu.ops import OpLog
 
-trace = W.load_trace()
+trace = W.synth_edit_trace()
 base_edits = int(os.environ.get("BENCH_BASE_EDITS", 120_000))
 n_replicas = int(os.environ.get("BENCH_REPLICAS", 1024))
 fork_edits = int(os.environ.get("BENCH_FORK_EDITS", 250))

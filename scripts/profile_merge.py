@@ -12,7 +12,7 @@ from automerge_tpu import native
 from automerge_tpu.ops import DeviceDoc, OpLog
 from automerge_tpu.ops.merge import merge_columns
 
-trace = W.load_trace()
+trace = W.synth_edit_trace()
 base_edits = int(os.environ.get("BENCH_BASE_EDITS", 259_778))
 n_replicas = int(os.environ.get("BENCH_REPLICAS", 1024))
 fork_edits = int(os.environ.get("BENCH_FORK_EDITS", 250))

@@ -6,8 +6,8 @@ same resolution arrays, same reads, same historical views — across
 random interleavings, mixed document sizes, out-of-order delivery,
 duplicate re-delivery, empty deltas, and the fallback-ratio boundary.
 Plus: the group-commit batcher under real threads, and the whale-doc
-mesh residency mode degrading cleanly when jax.shard_map / a
-multi-device mesh is unavailable.
+mesh residency mode degrading cleanly when no multi-device mesh
+exists.
 """
 
 import random
@@ -352,11 +352,11 @@ def test_cross_doc_batcher_inactive_mode():
 def _mesh_usable(n: int = 2) -> bool:
     import jax
 
-    return hasattr(jax, "shard_map") and len(jax.devices()) >= n
+    return len(jax.devices()) >= n
 
 
 def test_enable_mesh_degrades_cleanly():
-    """Without jax.shard_map / a multi-device mesh, enable_mesh refuses
+    """Without a multi-device mesh, enable_mesh refuses
     (returns False) and every apply keeps working single-device — the
     graceful skip the acceptance criteria require. On a capable mesh the
     sharded full re-resolution must match the per-doc kernel exactly."""
@@ -379,23 +379,20 @@ def test_enable_mesh_degrades_cleanly():
 
 
 @pytest.mark.skipif(
-    not _mesh_usable(2), reason="jax.shard_map or a multi-device mesh absent"
+    not _mesh_usable(2), reason="no multi-device mesh"
 )
-def test_mesh_full_reresolve_matches_single_device():
+def test_mesh_full_reresolve_matches_single_device(monkeypatch):
     chs, delta = _doc_with_delta(1, ballast=400, edits=4)
     dev = DeviceDoc.resolve(OpLog.from_changes(chs))
     ref = DeviceDoc.resolve(OpLog.from_changes(chs))
     assert dev.enable_mesh(2, min_rows=0)
     before = launch_counts()
-    # force the full re-resolution path (every delta over the limit)
-    import os
-
-    os.environ["AUTOMERGE_TPU_DIRTY_FRACTION"] = "0"
-    try:
-        dev.apply_changes(delta)
-        ref.apply_changes(delta)
-    finally:
-        del os.environ["AUTOMERGE_TPU_DIRTY_FRACTION"]
+    # the batched staging (the served route on an accelerator) skips the
+    # host delta resolution: over the dirty-fraction limit it
+    # re-resolves the whole log, here over the mesh
+    monkeypatch.setenv("AUTOMERGE_TPU_DIRTY_FRACTION", "0")
+    assert dev.stage_batches([delta]) == (len(delta), None)
+    ref.apply_changes(delta)
     after = launch_counts()
     assert after.get("sharded", 0) > before.get("sharded", 0)
     assert_bit_identical(dev, ref)

@@ -8,6 +8,7 @@ router tier end to end (proxying, failover with zero acked-write loss,
 migration between shard groups).
 """
 
+import base64
 import json
 import os
 import socket
@@ -33,6 +34,7 @@ from automerge_tpu.storage.journal import (
     REC_CHANGE,
     REC_META,
 )
+from automerge_tpu.types import ActorId
 
 
 # -- helpers ------------------------------------------------------------------
@@ -153,6 +155,20 @@ def test_replication_quorum_converges_and_promotes(tmp_path):
         # follower rejects client mutations
         r = fc.call("create", allow_error=True)
         assert r["error"]["type"] == "NotLeader", r
+        # a pipelined run of writes, which the serving layer coalesces,
+        # is refused the same way
+        hd = fc.call("openDurable", name="docA")["doc"]
+        w = AutoDoc(actor=ActorId(bytes([9]) * 16))
+        w.put("_root", "x", 1)
+        w.commit()
+        data = base64.b64encode(w.save()).decode()
+        fc.sock.sendall("".join(json.dumps(
+            {"id": 900 + i, "method": "applyChanges",
+             "params": {"doc": hd, "data": data}}) + "\n"
+            for i in range(2)).encode())
+        for _ in range(2):
+            r = json.loads(fc.f.readline())
+            assert r["error"]["type"] == "NotLeader", r
         # the quorum ack means the follower ALREADY holds everything
         st = fc.call("clusterStatus")
         assert st["role"] == "follower"

@@ -1,8 +1,8 @@
 """Native host merge engine vs the jax kernel: bit-exact equivalence.
 
-merge_cols.cpp is the second engine behind ops/merge.py merge_columns —
-below the size threshold (or via AUTOMERGE_TPU_ENGINE=native) it replaces
-the device kernel on remote-accelerator hosts. Every output array must
+merge_cols.cpp is the second engine behind ops/merge.py merge_columns:
+AUTOMERGE_TPU_ENGINE=native selects it in place of the device kernel,
+which is the default on every backend. Every output array must
 match the jit kernel exactly on every workload shape, including historical
 (covered-mask) views; mirrors the reference requirement that all apply
 paths converge to one op set (reference: rust/automerge/tests/test.rs
@@ -73,7 +73,7 @@ def _workload(name):
         mc, _ = W.synth_mapcounter(cdoc, keys, 12, 8)
         changes = [a.stored for a in cdoc.doc.history] + mc
     else:
-        trace = W.load_trace(4000)
+        trace = W.synth_edit_trace(4000)
         base = W.build_base(trace, 1500)
         if name == "fanin":
             changes = list(base.changes) + W.synth_fanin(base, trace, 12, 40, 1500)
@@ -114,18 +114,27 @@ def test_engine_equivalence_historical():
 
 
 def test_merge_columns_engine_env(monkeypatch):
-    """AUTOMERGE_TPU_ENGINE=native routes merge_columns to the host engine
-    and document reads stay identical."""
+    """Only AUTOMERGE_TPU_ENGINE=native routes merge_columns to the host
+    engine (a ``merge.host`` span), and document reads stay identical."""
+    from automerge_tpu import obs
+
+    def host_merges():
+        return obs.timing_summary().get("merge.host", {}).get("n", 0)
+
     changes = _rich_changes()
     log = OpLog.from_changes(changes)
 
+    monkeypatch.delenv("AUTOMERGE_TPU_ENGINE", raising=False)
+    n0 = host_merges()
     res_jax = merge_columns(
         log.padded_columns(), fetch=DeviceDoc.READ_FETCH, n_objs=log.n_objs
     )
+    assert host_merges() == n0
     monkeypatch.setenv("AUTOMERGE_TPU_ENGINE", "native")
     res_nat = merge_columns(
         log.padded_columns(), fetch=DeviceDoc.READ_FETCH, n_objs=log.n_objs
     )
+    assert host_merges() == n0 + 1
     assert set(res_nat) == set(DeviceDoc.READ_FETCH)
     d1 = DeviceDoc(log, res_jax)
     d2 = DeviceDoc(OpLog.from_changes(changes), res_nat)

@@ -526,9 +526,10 @@ def test_perf_gate_size_gated_metrics_skip_on_mismatch(tmp_path):
     assert "replay" not in p.stdout
 
 
-def test_perf_gate_salvages_committed_r05_tail():
-    # the real committed trajectory: r05's wrapper has parsed=null and
-    # only a truncated tail — its micro guards must still be recovered
+def test_perf_gate_salvages_truncated_tail(tmp_path):
+    # a driver wrapper with parsed=null and only a truncated tail of the
+    # bench line (the shape one round-5 record had): its micro guards
+    # must still be recovered
     import importlib.util
     from importlib.machinery import SourceFileLoader
 
@@ -536,7 +537,21 @@ def test_perf_gate_salvages_committed_r05_tail():
     spec = importlib.util.spec_from_loader("perf_gate_mod", loader)
     pg = importlib.util.module_from_spec(spec)
     loader.exec_module(pg)
-    point = pg.load_point(os.path.join(REPO, "BENCH_r05.json"))
+    tail = (
+        '9, "kernel_chain": 4, "transport_bytes_in": 5571088}, "rga": '
+        '{"actors": 1000, "ops": 1003000, "ops_per_sec": 10483886.0}, '
+        '"micro": {"map_100": {"put_ops_per_sec": 160038.9, "save_ms": '
+        '1.98, "load_ms": 1.9, "apply_ops_per_sec": 120097.5}, '
+        '"map_10000": {"put_ops_per_sec": 704343.1, "save_ms": 22.31, '
+        '"load_ms": 49.54, "apply_ops_per_sec": 138955.8}, "range_10000": '
+        '{"iter_elems_per_sec": 1243899.0}}}}\n'
+    )
+    rec = tmp_path / "BENCH_r05.json"
+    rec.write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 0, "tail": tail,
+        "parsed": None,
+    }))
+    point = pg.load_point(str(rec))
     assert point is not None and point.get("salvaged") is True
     micro = point["configs"]["micro"]["map_10000"]
     assert micro["put_ops_per_sec"] > 0 and micro["save_ms"] > 0

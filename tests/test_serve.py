@@ -353,6 +353,44 @@ def test_receive_sync_coalescing_feeds_device_once(server):
     c.close()
 
 
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_failed_device_feed_fails_apply_changes(server, monkeypatch,
+                                                pipelined):
+    """A device feed that raises fails the applyChanges requests it
+    carried (never an ack), counts sync.device_feed_error and drops the
+    stale mirror; later writes are served from the host."""
+    from automerge_tpu.ops import DeviceDoc
+    from automerge_tpu.ops.batched import CrossDocBatcher
+
+    c = Client(server.address)
+    d = c.call("openDurable", name="feed", device=True)["doc"]
+    w = AutoDoc(actor=ActorId(bytes([3]) * 16))
+    changes = []
+    for i in range(3):
+        w.put("_root", f"k{i}", i)
+        w.commit()
+        changes.append(base64.b64encode(
+            w.get_last_local_change().raw_bytes).decode())
+
+    def boom(self, *args):
+        raise RuntimeError("device lost")
+
+    # either route a feed takes: per document, or the cross-doc batcher
+    monkeypatch.setattr(DeviceDoc, "apply_batches", boom)
+    monkeypatch.setattr(CrossDocBatcher, "apply", boom)
+    before = trace.counters.get("sync.device_feed_error", 0)
+    n = 2 if pipelined else 1
+    resps = c.pipeline([("applyChanges", {"doc": d, "data": ch})
+                        for ch in changes[:n]], allow_errors=True)
+    assert all("device lost" in r["error"]["message"] for r in resps), resps
+    assert trace.counters.get("sync.device_feed_error", 0) == before + 1
+    assert server.rpc._docs[d].device_doc is None
+    for ch in changes[n:]:
+        c.call("applyChanges", doc=d, data=ch)
+    assert c.call("keys", doc=d, obj="_root") == ["k0", "k1", "k2"]
+    c.close()
+
+
 def test_hostile_frames_over_socket(server):
     """Garbled JSON, oversized lines and unknown methods answer errors
     over the socket without killing the connection or the server."""

@@ -181,6 +181,31 @@ def test_hot_tier_device_mirror_drops_and_rebuilds(server):
     assert s._docs[h].device_doc is not None
 
 
+def test_failed_promotion_fails_one_request_then_host_serves(
+        server, monkeypatch):
+    from automerge_tpu import trace
+
+    s = server
+    h = s.openDurable({"name": "pf", "device": True})["doc"]
+    s.put({"doc": h, "obj": "_root", "prop": "k", "value": 5})
+    s.commit({"doc": h})
+    s.store.demote("pf", TIER_WARM)
+    dd = s._docs[h]
+
+    def boom(self):
+        raise RuntimeError("no room in device memory")
+
+    monkeypatch.setattr(type(dd), "build_device_mirror", boom)
+    before = trace.counters.get("store.promote_error", 0)
+    get = {"id": 1, "method": "get",
+           "params": {"doc": h, "obj": "_root", "prop": "k"}}
+    assert "no room" in s.handle(get)["error"]["message"]
+    # the next requests are served from the host, without a rebuild
+    assert s.handle(get)["result"] == s.handle(get)["result"] is not None
+    assert trace.counters.get("store.promote_error", 0) == before + 1
+    assert s.store.tier("pf") == TIER_WARM and dd.device_doc is None
+
+
 def test_mutation_on_evicted_instance_is_retriable(server):
     from automerge_tpu.storage.durable import DocumentEvicted
 
