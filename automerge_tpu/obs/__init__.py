@@ -13,7 +13,15 @@ thin shims over this module):
   span: nests via a contextvar, accumulates wall time into the legacy
   ``trace.timings`` dict AND a log-bucketed histogram (p50/p95/p99), and
   lands in the bounded ring buffer that ``obs.export_trace(path)`` dumps
-  as Chrome-trace/Perfetto JSON.
+  as Chrome-trace/Perfetto JSON. While a ``jax.profiler`` capture is
+  active (whoever started it) the span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the capture's
+  host plane holds the program's spans on the profiler's own clock.
+* ``obs.record_span(name, start, end, ...)`` — a span whose interval
+  is already known (a request's time in a queue, a garbage collection).
+* ``host.gc`` spans and the ``host.gc_collections{generation}`` counter
+  come from a ``gc.callbacks`` hook installed when this module is
+  imported.
 * ``obs.render_prometheus()`` — text exposition of every instrument
   (scraped via the RPC ``metrics`` method or the CLI ``metrics``
   subcommand).
@@ -30,10 +38,13 @@ concurrently).
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
 import re
+import sys
 import threading
+from collections import deque
 from time import perf_counter as _perf_counter
 from typing import Optional
 
@@ -258,12 +269,60 @@ def counter_values(name: str, label: str) -> dict:
 # -- hierarchical spans ------------------------------------------------------
 
 
+_TraceAnnotation = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler capture is
+    active, else None. Until JAX is imported this costs one dict lookup
+    (no capture can exist before it), then TraceMe's own enabled check."""
+    global _TraceAnnotation
+    ta = _TraceAnnotation
+    if ta is None:
+        ta = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+        if ta is None:
+            return None
+        _TraceAnnotation = ta
+    return ta if ta.is_enabled() else None
+
+
+def _finish(name, span_id, parent, t0, dur, labels, fields, status,
+            trace_id, links, thread_id) -> None:
+    """Account one completed span: ``trace.timings``, the ``name``
+    histogram and the ring (``t0`` is a ``perf_counter`` reading)."""
+    with registry.lock:
+        slot = legacy_timings.get(name)
+        if slot is None:
+            legacy_timings[name] = [dur, 1]
+        else:
+            slot[0] += dur
+            slot[1] += 1
+        registry._get_locked(
+            name, "histogram", labels or {}
+        )._observe_locked(dur)
+    if recorder.capacity > 0:
+        dropped = recorder.record(SpanRecord(
+            name, span_id, parent, t0 - _ORIGIN, dur, thread_id, fields,
+            status, trace_id, links,
+        ))
+        if dropped:
+            # the ring wrapping silently was invisible before: count
+            # it so a truncated flight dump advertises itself
+            with registry.lock:
+                registry._get_locked(
+                    "obs.spans_dropped", "counter", {})._inc_locked(1)
+    if logger.isEnabledFor(_DEBUG):
+        event(name, ms=round(dur * 1e3, 3), **(labels or {}), **fields)
+
+
 class span:
     """``with obs.span("device.kernel", rows=n):`` — a timed span that
     nests under the contextually-active span, accumulates into
     ``trace.timings`` and the ``name`` histogram, and records into the
     ring buffer for Perfetto export. Always on; cost is two clock reads,
-    one lock round-trip and a deque append.
+    one lock round-trip, a deque append and a check for an active
+    ``jax.profiler`` capture, whose host plane then gets a
+    ``TraceAnnotation`` of the same name.
 
     ``links`` is an optional list of ``(trace_id, span_id)`` pairs for
     work this span covers without parenting it — a group-commit fsync
@@ -273,7 +332,7 @@ class span:
     """
 
     __slots__ = ("name", "labels", "fields", "links", "t0",
-                 "_id", "_parent", "_token")
+                 "_id", "_parent", "_token", "_ann")
 
     def __init__(self, name: str, labels: Optional[dict] = None,
                  links=None, **fields):
@@ -295,44 +354,109 @@ class span:
         self._token = current_span.set(self._id)
         if cycle_enter is not None:
             cycle_enter(self.name)
+        ta = _annotation()
+        self._ann = None
+        if ta is not None:
+            self._ann = ta(self.name)
+            self._ann.__enter__()
         self.t0 = _perf_counter()
         return self
 
     def __exit__(self, etype, evalue, tb):
         t1 = _perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         dur = t1 - self.t0
         current_span.reset(self._token)
         name = self.name
         if cycle_exit is not None:
             cycle_exit(name, dur)
-        with registry.lock:
-            slot = legacy_timings.get(name)
-            if slot is None:
-                legacy_timings[name] = [dur, 1]
-            else:
-                slot[0] += dur
-                slot[1] += 1
-            registry._get_locked(
-                name, "histogram", self.labels or {}
-            )._observe_locked(dur)
-        if recorder.capacity > 0:
-            dropped = recorder.record(SpanRecord(
-                name, self._id, self._parent, self.t0 - _ORIGIN, dur,
-                threading.get_ident(), self.fields,
-                "error" if etype is not None else "ok",
+        _finish(name, self._id, self._parent, self.t0, dur, self.labels,
+                self.fields, "error" if etype is not None else "ok",
                 current_trace.get(),
                 tuple(self.links) if self.links else None,
-            ))
-            if dropped:
-                # the ring wrapping silently was invisible before: count
-                # it so a truncated flight dump advertises itself
-                with registry.lock:
-                    registry._get_locked(
-                        "obs.spans_dropped", "counter", {})._inc_locked(1)
-        if logger.isEnabledFor(_DEBUG):
-            event(name, ms=round(dur * 1e3, 3),
-                  **(self.labels or {}), **self.fields)
+                threading.get_ident())
+        if _gc_pending:
+            _fold_gc()
         return False
+
+
+def record_span(name: str, start: float, end: float,
+                parent: Optional[int] = None,
+                labels: Optional[dict] = None, **fields) -> int:
+    """Record a span whose interval is already known: ``start`` and
+    ``end`` are ``time.perf_counter()`` readings (a request's time in a
+    queue, from its submit to its dequeue). ``parent`` is a span id;
+    None parents it to the calling context's active span, as
+    ``obs.span`` would. Accounted like a span that closed now (timings,
+    histogram, ring), without a profiler annotation, whose clock cannot
+    be set after the fact. Returns the new span's id."""
+    sid = next_span_id()
+    _finish(name, sid, current_span.get() if parent is None else parent,
+            start, max(end - start, 0.0), labels, fields, "ok",
+            current_trace.get(), None, threading.get_ident())
+    if _gc_pending:
+        _fold_gc()
+    return sid
+
+
+# -- garbage collection ------------------------------------------------------
+#
+# The hook runs in whichever thread allocated, at any point — possibly
+# while that thread holds the registry's or the recorder's lock. So it
+# takes no lock: it bumps a per-generation count and queues collections
+# of generation 1 and 2 on a bounded deque (appends are atomic); the
+# next span exit, ``record_span``, ``recorder.snapshot()``,
+# ``obs.snapshot()`` or ``render_prometheus()`` folds them into the
+# ``host.gc_collections{generation}`` counter and ``host.gc`` spans.
+# CPython runs one collection at a time (its callbacks included), so
+# the hook's own globals see no concurrent writer.
+
+_gc_counts = [0, 0, 0]
+_gc_folded = [0, 0, 0]
+_gc_pending: deque = deque(maxlen=4096)
+_gc_fold_lock = threading.Lock()
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = _perf_counter()
+        return
+    gen = info["generation"]
+    _gc_counts[gen] += 1
+    if gen:
+        _gc_pending.append((
+            _gc_t0, _perf_counter(), gen, info["collected"],
+            info["uncollectable"], threading.get_ident(), current_span.get(),
+        ))
+
+
+def _fold_gc() -> None:
+    """Fold what ``_on_gc`` queued (a no-op in a thread that finds
+    another thread folding, or itself re-entered)."""
+    if not _gc_fold_lock.acquire(blocking=False):
+        return
+    try:
+        for gen in range(3):
+            n = _gc_counts[gen] - _gc_folded[gen]
+            if n:
+                _gc_folded[gen] += n
+                count("host.gc_collections", n, labels={"generation": gen})
+        while _gc_pending:
+            t0, t1, gen, collected, uncollectable, tid, parent = (
+                _gc_pending.popleft())
+            _finish("host.gc", next_span_id(), parent, t0, t1 - t0, None,
+                    {"generation": gen, "collected": collected,
+                     "uncollectable": uncollectable},
+                    "ok", None, None, tid)
+    finally:
+        _gc_fold_lock.release()
+
+
+gc.callbacks.append(_on_gc)
+recorder.before_snapshot = _fold_gc
 
 
 class trace_scope:
@@ -413,10 +537,12 @@ def export_trace(path: str) -> int:
 
 
 def render_prometheus() -> str:
+    _fold_gc()
     return registry.render_prometheus()
 
 
 def snapshot() -> list:
+    _fold_gc()
     return registry.snapshot()
 
 

@@ -21,9 +21,11 @@ span completed inside the cycle into a fixed stage taxonomy:
   (the ``_take_ready`` halves), ``device.apply`` (the staging umbrella,
   whose interior breaks down into ``device.stage.splice``,
   ``device.materialize``, ``device.delta_resolve``, ``device.extract``);
-* **device** — ``device.pack``, ``device.h2d``, ``device.kernel``,
-  ``device.linearize``, ``device.readback``, ``device.scatter``,
-  ``device.mesh_resolve``;
+* **device** — ``device.pack``, ``device.h2d``, ``device.kernel`` (the
+  asynchronous dispatch: the device time itself is in a profiler
+  trace), ``device.linearize``, ``device.readback`` (the umbrella of
+  ``device.wait``, the host blocked on the device, and ``device.d2h``,
+  the copy after it), ``device.scatter``, ``device.mesh_resolve``;
 * **fsync** — ``journal.fsync`` (the group-commit share of a serve
   drain's ack path).
 
@@ -51,11 +53,11 @@ Surfaces: the ``perfStatus`` RPC and ``python -m automerge_tpu
 perf-report`` render ``profiler.status()`` — a host-vs-device
 percentage breakdown with occupancy, docs-per-launch, queue-wait and
 fsync share. ``profileStart`` / ``profileStop`` additionally capture a
-``jax.profiler`` device trace with a named annotation
-(``prof.annotate``) wrapped around every kernel-launch site; on boxes
-where the profiler backend is unavailable they degrade to an
-``{"ok": false}`` answer, never an error (the ``enable_mesh``
-contract).
+``jax.profiler`` device trace; every ``obs.span`` opened during the
+capture lands in its host plane under its own name (see
+``obs.span``); on boxes where the profiler backend is unavailable they
+degrade to an ``{"ok": false}`` answer, never an error (the
+``enable_mesh`` contract).
 
 Env knobs: ``AUTOMERGE_TPU_PROF=0`` disarms cycle collection entirely
 (cycles become no-ops); ``AUTOMERGE_TPU_PROF_TOPK`` sizes the
@@ -68,7 +70,6 @@ from __future__ import annotations
 import contextvars
 import os
 import threading
-from contextlib import nullcontext
 from time import perf_counter
 from typing import Dict, List, Optional
 
@@ -97,9 +98,12 @@ STAGES: Dict[str, tuple] = {
     # fused INTO the kernel, this stage -> 0) is visible, not folded
     # into h2d
     "device.expand": ("expand", "device"),
+    # the asynchronous enqueue of a launch; the host's wait for its
+    # results is device.wait, inside device.readback
     "device.kernel": ("kernel", "device"),
     "device.linearize": ("linearize", "device"),
-    "device.readback": ("readback", "device"),
+    "device.wait": ("wait", "device"),
+    "device.d2h": ("d2h", "device"),
     "device.scatter": ("scatter", "device"),
     "device.mesh_resolve": ("mesh", "device"),
     "serve.write": ("write", "host"),
@@ -117,6 +121,8 @@ STAGES: Dict[str, tuple] = {
 PARENTS: Dict[str, tuple] = {
     "device.apply": (None, "host"),
     "device.stage.splice": ("splice", "host"),
+    # a stage row whose wait/d2h children stay breakdown-only
+    "device.readback": ("readback", "device"),
     "device.batched": (None, "device"),
     "rpc.request": (None, "host"),
     # the cross-doc splice is a stage row AND a parent umbrella, so any
@@ -631,6 +637,11 @@ def summarize_flight_events(events: List[dict]) -> dict:
     return out
 
 
+# perf-report row names where the stage key alone misleads: a launch's
+# span times its enqueue, the host's wait for the device is its own row
+_STAGE_LABELS = {"kernel": "kernel_dispatch", "wait": "device_wait"}
+
+
 def render_text(summary: dict, top: Optional[int] = None) -> str:
     """The human perf-report: host-vs-device percentage breakdown, stage
     table, occupancy, and the expensive-docs tail."""
@@ -689,16 +700,24 @@ def render_text(summary: dict, top: Optional[int] = None) -> str:
             f"compress ratio {ratio if ratio is not None else 1.0}x)"
         )
     if stages:
-        lines.append(f"  {'stage':<14} {'seconds':>10} {'% wall':>8}")
+        wait = stages.get("wait")
+        if wait:
+            lines.append(
+                f"blocked on device: {wait['seconds']:.4f}s "
+                f"({wait['pct_of_wall']:.1f}% of wall; the kernel row is "
+                "the dispatch only)"
+            )
+        lines.append(f"  {'stage':<16} {'seconds':>10} {'% wall':>8}")
         for k, v in stages.items():
             lines.append(
-                f"  {k:<14} {v['seconds']:>10.4f} {v['pct_of_wall']:>7.1f}%"
+                f"  {_STAGE_LABELS.get(k, k):<16} {v['seconds']:>10.4f} "
+                f"{v['pct_of_wall']:>7.1f}%"
             )
         other = summary.get("host_other_s", 0.0)
         if other:
             wall = summary.get("wall_s", 0.0) or 1.0
             lines.append(
-                f"  {'host_other':<14} {other:>10.4f} "
+                f"  {'host_other':<16} {other:>10.4f} "
                 f"{100.0 * other / wall:>7.1f}%"
             )
     occ = summary.get("occupancy")
@@ -798,16 +817,3 @@ def jax_profile_stop() -> dict:
             return {"ok": False, "reason": str(e)[:200], "dir": d}
         return {"ok": True, "dir": d}
 
-
-def annotate(name: str):
-    """A named ``jax.profiler.TraceAnnotation`` around a kernel-launch
-    site while a capture is active; a free ``nullcontext`` otherwise
-    (the common case costs one dict read)."""
-    if not _jax_trace["active"]:
-        return nullcontext()
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        return nullcontext()

@@ -26,7 +26,7 @@ import threading
 import uuid
 from collections import deque
 from time import perf_counter
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 # all span timestamps are seconds since this process-wide origin, so the
 # exported trace starts near ts=0 regardless of perf_counter's epoch
@@ -129,6 +129,10 @@ class SpanRecorder:
         self.capacity = capacity
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=max(capacity, 0))
+        # called (without the lock) before every snapshot: the process
+        # recorder folds spans queued where no lock may be taken (the
+        # garbage-collection hook in obs/__init__) into the ring
+        self.before_snapshot: Optional[Callable[[], None]] = None
 
     def record(self, rec: SpanRecord) -> bool:
         """Append; returns True when the ring was full and an old span
@@ -141,6 +145,8 @@ class SpanRecorder:
         return evicted
 
     def snapshot(self) -> List[SpanRecord]:
+        if self.before_snapshot is not None:
+            self.before_snapshot()
         with self._lock:
             return list(self._buf)
 

@@ -183,9 +183,7 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
     # device_put moves run tables, not dense rows; with run-native
     # kernels the tables are the kernel's input itself
     dispatch = prepare_resolution(cols, n_objs, n_props)
-    with obs.span("device.kernel", rows=P), \
-            _prof.annotate("amtpu.batched_launch"):
-        out = dispatch()  # async dispatch
+    out = dispatch()  # async dispatch
     with obs.span("device.linearize", rows=P):
         ei = host_linearize(cols)
     return {"out": out, "ei": ei, "P": P}
@@ -193,7 +191,9 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
 
 def _collect_packed(handle):
     """The blocking half of a packed launch: read the resolution back."""
-    with obs.span("device.readback", rows=handle["P"]):
+    from .merge import readback
+
+    with readback(handle["out"], handle["P"]):
         res = {
             k: np.asarray(handle["out"][k])
             for k in ("visible", "winner", "conflicts",

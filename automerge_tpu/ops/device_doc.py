@@ -38,7 +38,7 @@ from ..patches.patch import (
     SpliceText,
 )
 from ..types import ObjType, is_make_action, objtype_for_action
-from .merge import merge_columns
+from .merge import merge_columns, readback
 from .oplog import (
     ELEM_HEAD, ELEM_MISSING, MAKE_ACTIONS, ACTOR_BITS, OpLog, TAG_COUNTER,
 )
@@ -168,11 +168,10 @@ class DeviceDoc:
     def resolve(cls, log: OpLog) -> "DeviceDoc":
         obs.count("device.kernel_launches", labels={"path": "per_doc"})
         _prof.note("launches")
-        with _prof.annotate("amtpu.resolve"):
-            res = merge_columns(
-                log.columns(), fetch=cls.READ_FETCH, n_objs=log.n_objs,
-                n_props=len(log.props),
-            )
+        res = merge_columns(
+            log.columns(), fetch=cls.READ_FETCH, n_objs=log.n_objs,
+            n_props=len(log.props),
+        )
         return cls(log, res)
 
     # -- incremental updates ------------------------------------------------
@@ -412,11 +411,10 @@ class DeviceDoc:
         log = OpLog.from_changes(changes)
         obs.count("device.kernel_launches", labels={"path": "per_doc"})
         _prof.note("launches")
-        with _prof.annotate("amtpu.rebuild"):
-            res = merge_columns(
-                log.columns(), fetch=self.READ_FETCH, n_objs=log.n_objs,
-                n_props=len(log.props),
-            )
+        res = merge_columns(
+            log.columns(), fetch=self.READ_FETCH, n_objs=log.n_objs,
+            n_props=len(log.props),
+        )
         self.__init__(log, res)
         self._pending = pend
         self._mesh, self._mesh_min_rows, self._mesh_env_tried = mesh_state
@@ -943,11 +941,10 @@ class DeviceDoc:
             if res is None:
                 obs.count("device.kernel_launches", labels={"path": "per_doc"})
                 _prof.note("launches")
-                with _prof.annotate("amtpu.reresolve_full"):
-                    res = merge_columns(
-                        log.columns(), fetch=self.READ_FETCH,
-                        n_objs=log.n_objs, n_props=len(log.props),
-                    )
+                res = merge_columns(
+                    log.columns(), fetch=self.READ_FETCH,
+                    n_objs=log.n_objs, n_props=len(log.props),
+                )
             n = log.n
             vis = np.asarray(res["visible"])[:n]
             win = np.asarray(res["winner"])[:n]
@@ -969,11 +966,10 @@ class DeviceDoc:
         cols = self._subset_cols(rows, dirty)
         obs.count("device.kernel_launches", labels={"path": "per_doc"})
         _prof.note("launches")
-        with _prof.annotate("amtpu.reresolve_subset"):
-            res_sub = merge_columns(
-                cols, fetch=self.READ_FETCH, n_objs=len(dirty),
-                n_props=len(log.props),
-            )
+        res_sub = merge_columns(
+            cols, fetch=self.READ_FETCH, n_objs=len(dirty),
+            n_props=len(log.props),
+        )
         with obs.span("device.scatter", rows=len(rows)):
             self._scatter_subset(rows, dirty, res_sub)
 
@@ -1008,9 +1004,7 @@ class DeviceDoc:
         dispatch = prepare_resolution(cols_np, D, len(log.props))
         obs.count("device.kernel_launches", labels={"path": "per_doc"})
         _prof.note("launches")
-        with obs.span("device.kernel", rows=P), \
-                _prof.annotate("amtpu.dispatch_async"):
-            out = dispatch()  # async dispatch
+        out = dispatch()  # async dispatch
         # element order overlaps the kernel — it needs only the columns
         with obs.span("device.linearize", rows=P):
             ei = host_linearize(cols_np)
@@ -1022,7 +1016,7 @@ class DeviceDoc:
         out = handle["out"]
         S = len(handle["rows"])
         D = len(handle["dirty"])
-        with obs.span("device.readback", rows=S):
+        with readback(out, S):
             res_sub = {
                 "visible": np.asarray(out["visible"]),
                 "winner": np.asarray(out["winner"]),
@@ -1208,13 +1202,12 @@ class DeviceDoc:
             covered = base.log.covered_mask(base._clock_vec(heads))
             obs.count("device.kernel_launches", labels={"path": "per_doc"})
             _prof.note("launches")
-            with _prof.annotate("amtpu.at_view"):
-                res = merge_columns(
-                    base.log.padded_columns(covered=covered),
-                    fetch=self.VIEW_FETCH,
-                    n_objs=base.log.n_objs,
-                    n_props=len(base.log.props),
-                )
+            res = merge_columns(
+                base.log.padded_columns(covered=covered),
+                fetch=self.VIEW_FETCH,
+                n_objs=base.log.n_objs,
+                n_props=len(base.log.props),
+            )
             view = DeviceDoc(base.log, res, covered=covered, base=base)
             base._views[key] = view
         return view

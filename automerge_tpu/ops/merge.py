@@ -32,6 +32,7 @@ gathers and segmented reductions — shapes XLA maps well onto the VPU.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -51,11 +52,57 @@ _PUT = 1
 # the default backend and compile a kernel at IMPORT time
 NONE32 = -1
 
+# the names every resolution program is jitted under (its XLA module is
+# ``jit_<name>``): ``merge_kernel`` ranks element order on the device,
+# ``merge_kernel_core`` leaves it to the host. Every other program here
+# is named for what it does (``expand_runs``)
+RESOLUTION_PROGRAMS = ("merge_kernel", "merge_kernel_core")
+
 
 def _ceil_log2(n: int) -> int:
     return max(1, int(n - 1).bit_length())
 
 
+def _program(name: str, fn):
+    """``jax.jit(fn)`` as the program ``name``: a profile then names its
+    XLA module ``jit_<name>``, not after a local helper's name."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _stage(name: str):
+    """Trace a kernel stage under ``jax.named_scope(name)``: every XLA op
+    it emits carries the stage in its metadata (``op_name``), so a
+    profile splits the device time by stage. Metadata only: the
+    optimized program is unchanged."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def staged(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return staged
+
+    return wrap
+
+
+@contextlib.contextmanager
+def readback(outputs, rows: int, name: str = "device.readback"):
+    """``with readback(out, rows): host = np.asarray(out)`` — the
+    blocking half of a launch, as a ``name`` span around two children:
+    ``device.wait``, the host blocked until ``outputs`` are ready (the
+    device time that the launch's ``device.kernel`` span, an enqueue,
+    does not cover), then ``device.d2h``, the copy the body makes. The
+    copy blocked on the same outputs before; nothing new waits."""
+    with obs.span(name, rows=rows):
+        with obs.span("device.wait", rows=rows):
+            jax.block_until_ready(outputs)
+        with obs.span("device.d2h", rows=rows):
+            yield
+
+
+@_stage("succ_resolution")
 def succ_resolution(c):
     """Phase 1: pred scatter -> per-op succ/inc counters (batched add_succ).
 
@@ -106,6 +153,7 @@ def visibility(c, succ_count, inc_count):
     )
 
 
+@_stage("resolve_state")
 def resolve_state(c, succ_count, inc_count, counter_inc, obj_cap=None):
     """Phases 2-4: visibility, per-key winners, RGA linearization.
 
@@ -214,6 +262,7 @@ def resolve_state(c, succ_count, inc_count, counter_inc, obj_cap=None):
     return core
 
 
+@_stage("device_linearize")
 def device_linearize(c, core):
     """Document-order element indices computed fully on device.
 
@@ -301,6 +350,7 @@ def merge_kernel_core(c):
     return resolve_state(c, *succ_resolution(c))
 
 
+@_stage("device_linearize")
 def device_linearize_condensed(c, core, rcap: int, obj_cap: int = None):
     """All-device document order via CHAIN CONDENSATION.
 
@@ -513,13 +563,12 @@ def merge_kernel_condensed(rcap: int, obj_cap: int = None):
     A static ``obj_cap`` also arms resolve_state's packed single-key
     winner sort."""
 
-    @jax.jit
-    def _kernel(c):
+    def kernel(c):
         core = resolve_state(c, *succ_resolution(c), obj_cap=obj_cap)
         core["elem_index"] = device_linearize_condensed(c, core, rcap, obj_cap)
         return core
 
-    return _kernel
+    return _program("merge_kernel", kernel)
 
 
 # -- scatter-based resolution -------------------------------------------------
@@ -593,6 +642,7 @@ def forest(c):
     return is_elem, parent_row, first_child, next_sib
 
 
+@_stage("resolve_state_scatter")
 def resolve_state_scatter(c, succ_count, inc_count, counter_inc,
                           n_objs2: int, n_props: int):
     """Sort-free resolve_state: same output dict, winners via scatter-max/
@@ -659,13 +709,12 @@ def scatter_kernel_core(n_objs: int, n_props: int):
     if fn is None:
         n_objs2, np_eff = key
 
-        @jax.jit
-        def f(c):
+        def core(c):
             return resolve_state_scatter(
                 c, *succ_resolution(c), n_objs2=n_objs2, n_props=np_eff
             )
 
-        fn = _scatter_core_cache[key] = f
+        fn = _scatter_core_cache[key] = _program("merge_kernel_core", core)
     return fn
 
 
@@ -853,7 +902,7 @@ def _expander(n, rcap, bools):
     key = (n, rcap, bools)
     fn = _EXPAND_CACHE.get(key)
     if fn is None:
-        def f(W, C, S):
+        def expand(W, C, S):
             i = jnp.arange(n, dtype=jnp.int32)
 
             def one(w, c, s):
@@ -868,7 +917,7 @@ def _expander(n, rcap, bools):
                 for k, b in enumerate(bools)
             )
 
-        fn = _EXPAND_CACHE[key] = jax.jit(f)
+        fn = _EXPAND_CACHE[key] = _program("expand_runs", expand)
     return fn
 
 
@@ -1035,7 +1084,8 @@ def run_native_kernel(plan, geom, fetch=RESOLVE_FETCH):
     if fn is None:
         core = _resolution_body(geom)
 
-        def f(dense, stacks):
+        @_stage("expand_runs")
+        def expand(dense, stacks):
             c = dict(dense)
             for (n, rcap, cls, names, bools), arrs in zip(plan, stacks):
                 i = jnp.arange(n, dtype=jnp.int32)
@@ -1054,10 +1104,14 @@ def run_native_kernel(plan, geom, fetch=RESOLVE_FETCH):
                     )(arrs[0], arrs[1], arrs[2])
                 for idx, (name, b) in enumerate(zip(names, bools)):
                     c[name] = colv[idx].astype(jnp.bool_) if b else colv[idx]
-            out = core(c)
+            return c
+
+        def resolve(dense, stacks):
+            out = core(expand(dense, stacks))
             return {k: out[k] for k in fetch}
 
-        fn = _RUN_NATIVE_CACHE[key] = jax.jit(f)
+        name = "merge_kernel" if geom[0] == "full" else "merge_kernel_core"
+        fn = _RUN_NATIVE_CACHE[key] = _program(name, resolve)
     return fn
 
 
@@ -1082,9 +1136,16 @@ def resolution_geom(P: int, n_objs=None, n_props=None, full=False):
 def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
                        fetch=RESOLVE_FETCH):
     """Stage bucket-padded dict columns for one resolution launch and
-    return a zero-arg dispatch closure (callers wrap the call in their
-    own ``device.kernel`` span / trace annotation — staging spans
+    return a zero-arg dispatch closure (staging spans
     ``device.h2d``/``device.expand`` land here, before it).
+
+    The closure enqueues the launch inside a ``device.kernel`` span and
+    returns without waiting: the span times the dispatch, and the
+    device's time shows in the ``device.wait`` span of the ``readback``
+    that collects it. Its ``program`` field says which program ran:
+    ``run_native`` (run tables staged as the kernel's input), else,
+    over eagerly expanded columns, ``scatter`` (the scatter-max winner
+    body) or ``dense`` (the sort-based body).
 
     Chooses the run-native staging (run tables stay the kernel input,
     counted as a ``path=run_native`` launch) when enabled and at least
@@ -1093,7 +1154,8 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
     allows, the sort-based core otherwise; ``full=True`` pins the
     everything-on-device body (on-chip linearization). The launch
     returns the ``fetch`` outputs only."""
-    geom = resolution_geom(len(cols_np["action"]), n_objs, n_props, full)
+    P = len(cols_np["action"])
+    geom = resolution_geom(P, n_objs, n_props, full)
     if run_native_enabled():
         dense, stacks, plan = stage_cols_run_native(cols_np)
         if plan:
@@ -1102,7 +1164,14 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
     else:
         dense, stacks, plan = stage_cols_device(cols_np), (), ()
     fn = run_native_kernel(plan, geom, tuple(fetch))
-    return lambda: fn(dense, stacks)
+    program = ("run_native" if plan
+               else "scatter" if geom[0] == "scatter" else "dense")
+
+    def dispatch():
+        with obs.span("device.kernel", rows=P, program=program):
+            return fn(dense, stacks)
+
+    return dispatch
 
 
 def encode_transport(cols) -> tuple:
@@ -1165,6 +1234,7 @@ def _expand(w, cum, slope, n):
     return w[j] + slope * i
 
 
+@_stage("transport_unpack")
 def _unpack_transport(static_key, arrays, P, Q):
     run_namesP, plainP, run_namesQ, plainQ = static_key
     cols = {}
@@ -1203,6 +1273,7 @@ def _bitunpack(words, P):
     return bits[:P].astype(bool)
 
 
+@_stage("emit")
 def _emit(core, fetch, obj_cap):
     """Concatenate the requested outputs into one int32 transfer vector."""
     outs = []
@@ -1221,8 +1292,9 @@ def _emit(core, fetch, obj_cap):
 
 
 def _runs_fn(fetch, obj_cap, static_key, P, Q, scatter_geom=None):
-    @jax.jit
-    def f(arrays):
+    """The packed-transport resolution program: run tables in, one flat
+    int32 vector out."""
+    def packed(arrays):
         c = _unpack_transport(static_key, arrays, P, Q)
         if scatter_geom is not None:
             core = resolve_state_scatter(
@@ -1235,7 +1307,8 @@ def _runs_fn(fetch, obj_cap, static_key, P, Q, scatter_geom=None):
             core["elem_index"] = device_linearize(c, core)
         return _emit(core, fetch, obj_cap)
 
-    return f
+    name = "merge_kernel" if "elem_index" in fetch else "merge_kernel_core"
+    return _program(name, packed)
 
 
 from .oplog import host_linearize  # noqa: F401  (moved: jax-free)
@@ -1312,10 +1385,10 @@ def _packed_merge(cols_np, fetch, n_objs, n_props=None):
     with obs.span("device.h2d", rows=P, bytes=pk_bytes):
         arrays_dev = {k: jnp.asarray(v) for k, v in arrays.items()}
     _note_h2d(pk_bytes, sum(np.asarray(v).nbytes for v in cols_np.values()))
-    with obs.span("device.kernel", rows=P):
+    with obs.span("device.kernel", rows=P, program="packed"):
         flat_dev = fn(arrays_dev)  # async dispatch
     elem_index = host_linearize(cols_np) if host_elem else None
-    with obs.span("device.readback", rows=P):
+    with readback(flat_dev, P):
         flat = np.asarray(flat_dev)
     with obs.span("device.materialize", rows=P):
         out = _split_flat(flat, dev_fetch, P, obj_cap)
@@ -1426,7 +1499,7 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
 
     def pull(out, keys):
         host = {}
-        with obs.span("device.readback", rows=len(cols_np["action"])):
+        with readback(out, len(cols_np["action"])):
             for k in keys:
                 v = out[k]
                 if k in ("obj_vis_len", "obj_text_width") and n_objs is not None:
@@ -1435,7 +1508,6 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
         return host
 
     if linearize == "native":
-        P = len(cols_np["action"])
         # staging (run-native or eager-expand) happens here, outside the
         # kernel span; the closure dispatches the specialized kernel
         dispatch = prepare_resolution(
@@ -1444,8 +1516,7 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
                 k for k in ALL_OUTPUTS if k in need and k != "elem_index"
             ),
         )
-        with obs.span("device.kernel", rows=P):
-            out = dispatch()
+        out = dispatch()
         host = pull(out, need - {"elem_index"})
         if "elem_index" in need:
             # ranked from the host-resident columns — zero device traffic
@@ -1455,6 +1526,4 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
         cols_np, n_objs_eff, full=True,
         fetch=tuple(k for k in ALL_OUTPUTS if k in need),
     )
-    with obs.span("device.kernel", rows=len(cols_np["action"])):
-        out = dispatch()
-    return pull(out, need)
+    return pull(dispatch(), need)

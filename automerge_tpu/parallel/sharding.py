@@ -52,6 +52,7 @@ from ..ops.merge import (
     _unpack_transport,
     encode_transport,
     forest as _forest,
+    readback,
     resolve_state,
     succ_resolution,
     visibility,
@@ -582,8 +583,7 @@ def sharded_merge_columns(
                 for k, v in arrays.items()
             }
             cond = put_cond() if R2 else None
-        with obs.span("parallel.kernel", rows=Ptot, devices=n), \
-                _prof.annotate("amtpu.sharded_launch"):
+        with obs.span("parallel.kernel", rows=Ptot, devices=n):
             out = fn(arrs, cond) if R2 else fn(arrs)
     else:
         with obs.span("parallel.h2d", rows=Ptot):
@@ -593,8 +593,7 @@ def sharded_merge_columns(
             }
             cond = put_cond() if R2 else None
         fn = _make_sharded_fn(mesh, Ptot, n_objs2, np_eff, None, R2)
-        with obs.span("parallel.kernel", rows=Ptot, devices=n), \
-                _prof.annotate("amtpu.sharded_launch"):
+        with obs.span("parallel.kernel", rows=Ptot, devices=n):
             out = fn(cols, cond) if R2 else fn(cols)
-    with obs.span("parallel.readback", rows=Ptot):
+    with readback(out, Ptot, "parallel.readback"):
         return {k: np.asarray(v) for k, v in out.items()}

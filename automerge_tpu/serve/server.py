@@ -84,6 +84,12 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
+def _history_len(doc) -> int:
+    """How many changes ``doc`` (an RPC document, or None) holds."""
+    history = getattr(getattr(doc, "doc", None), "history", None)
+    return len(history) if history is not None else 0
+
+
 def _run_trace_links(run) -> list:
     """Span links for a coalesced run: the wire trace context of every
     request the shared span covers (a coalesced receive executes many
@@ -536,15 +542,32 @@ class SocketRpcServer:
         written only after the covering fsync. The whole drain is one
         profiler cycle (``drain.cycle_seconds`` / ``drain.docs``), so
         cycle reports anchor to real serve drains, not just bench
-        drains."""
+        drains.
+
+        The drain is a ``serve.drain`` span (``doc``, ``requests``, and
+        ``changes``: how many changes joined the document's history).
+        Each request it took gets a ``serve.queued`` span under it, from
+        the request's submit to the dequeue (``method``, ``id``, and its
+        ``position`` in the batch)."""
         t_cycle = time.perf_counter()
         doc_name = (
             self.rpc._handle_names.get(key) or f"doc{key}"
             if isinstance(key, int)
             else str(key)
         )
-        with prof.cycle(kind="serve", doc=doc_name):
-            self._execute_batch_inner(key, items)
+        n0 = _history_len(self.rpc._docs.get(key))
+        with obs.span("serve.drain", doc=doc_name,
+                      requests=len(items)) as drain:
+            for pos, ((_conn, req), t) in enumerate(
+                    zip(items, items.submitted)):
+                obs.record_span("serve.queued", t, items.dequeued,
+                                parent=drain.span_id,
+                                method=self._bounded_method(req),
+                                id=req.get("id"), position=pos)
+            with prof.cycle(kind="serve", doc=doc_name):
+                self._execute_batch_inner(key, items)
+            drain.fields["changes"] = max(
+                _history_len(self.rpc._docs.get(key)) - n0, 0)
         obs.observe("drain.cycle_seconds", time.perf_counter() - t_cycle)
         docs = {key} if isinstance(key, int) else set()
         for _conn, req in items:

@@ -23,14 +23,17 @@ without limit — the client is the retry loop). Gauges:
 
 The pool is generic over the work items: the server submits
 ``(connection, request)`` pairs and supplies ``execute(key, items)``;
-the pool owns only ordering, bounding and thread placement.
+the pool owns only ordering, bounding and thread placement. ``items``
+is a ``Batch``: the drained items in order, with each one's submit time
+and the time the worker dequeued them (``perf_counter`` readings, the
+clock ``obs.record_span`` takes).
 """
 
 from __future__ import annotations
 
 import threading
 from collections import deque
-from time import monotonic as _monotonic
+from time import perf_counter as _clock
 from typing import Callable, Dict, Hashable, List, Optional
 
 from .. import obs
@@ -41,11 +44,24 @@ class QueueFull(Exception):
     bound — the backpressure signal."""
 
 
+class Batch(list):
+    """One drain's items, in order. ``submitted[i]`` is when item ``i``
+    was submitted, ``dequeued`` when a worker took them."""
+
+    __slots__ = ("submitted", "dequeued")
+
+    def __init__(self):
+        super().__init__()
+        self.submitted: List[float] = []
+        self.dequeued = 0.0
+
+
 class _DocQueue:
-    __slots__ = ("items", "scheduled", "first_ts")
+    __slots__ = ("items", "submitted", "scheduled", "first_ts")
 
     def __init__(self):
         self.items: deque = deque()
+        self.submitted: deque = deque()  # each queued item's submit time
         self.scheduled = False  # a worker owns (or is queued to own) this doc
         self.first_ts = 0.0  # enqueue time of the oldest queued item
 
@@ -105,9 +121,11 @@ class ShardPool:
                     f"queue for doc {key!r} is full "
                     f"({self.max_queue} pending requests)"
                 )
+            now = _clock()
             if not q.items:
-                q.first_ts = _monotonic()
+                q.first_ts = now
             q.items.append(item)
+            q.submitted.append(now)
             if not q.scheduled:
                 q.scheduled = True
                 self._ready.append(key)
@@ -156,11 +174,13 @@ class ShardPool:
                     return
                 key = self._ready.popleft()
                 q = self._queues[key]
-                batch = []
+                batch = Batch()
                 while q.items and len(batch) < self.max_batch:
                     batch.append(q.items.popleft())
-                waited = _monotonic() - q.first_ts if batch else 0.0
-                q.first_ts = _monotonic()  # the remainder starts waiting now
+                    batch.submitted.append(q.submitted.popleft())
+                batch.dequeued = _clock()
+                waited = batch.dequeued - q.first_ts if batch else 0.0
+                q.first_ts = batch.dequeued  # the remainder starts waiting now
                 self._busy += 1
                 busy = self._busy
                 depth = len(q.items)
@@ -176,12 +196,12 @@ class ShardPool:
             obs.gauge_set("rpc.queue_depth", depth, labels={"doc": str(key)})
             obs.gauge_set("rpc.pool_busy", busy)
             obs.gauge_set("rpc.pool_utilization", busy / n_workers)
-            t0 = _monotonic()
+            t0 = _clock()
             try:
                 if batch:
                     self._execute(key, batch)
             finally:
-                dt = _monotonic() - t0
+                dt = _clock() - t0
                 popped = False
                 with self._lock:
                     if batch:
@@ -219,6 +239,7 @@ class ShardPool:
             if not drain:
                 for q in self._queues.values():
                     q.items.clear()
+                    q.submitted.clear()
             self._cond.notify_all()
         for t in self.workers:
             t.join(timeout=timeout)

@@ -302,7 +302,7 @@ def test_profile_start_stop_rpc_clean_degrade(tmp_path):
         {"id": 3, "method": "profileStart", "params": {}}
     )["result"]
     assert again["ok"] is False
-    # kernel-launch sites annotate while the capture is active
+    # the launch's spans mirror into the capture while it is active
     from automerge_tpu.ops.batched import apply_cross_doc
 
     apply_cross_doc(_cross_doc_work(2, seed=9))
@@ -317,14 +317,35 @@ def test_profile_start_stop_rpc_clean_degrade(tmp_path):
     assert found, "profiler capture produced no artifacts"
 
 
-def test_annotate_is_free_when_inactive():
-    from contextlib import AbstractContextManager
-
-    cm = prof.annotate("amtpu.test")
-    assert isinstance(cm, AbstractContextManager)
-    with cm:
-        pass
-    assert prof._jax_trace["active"] is False
+def test_readback_umbrella_counts_its_wait_and_copy_once():
+    # device.readback holds device.wait (blocked on the device) and
+    # device.d2h (the copy): both are breakdown rows, the umbrella alone
+    # adds to the split, so attributed time never exceeds the wall
+    with prof.cycle(kind="t") as c:
+        with obs.span("device.kernel"):
+            _spin(0.001)
+        with obs.span("device.readback"):
+            with obs.span("device.wait"):
+                _spin(0.004)
+            with obs.span("device.d2h"):
+                _spin(0.002)
+    r = c.report
+    assert r["stages"]["wait"] >= 0.004 and r["stages"]["d2h"] >= 0.002
+    assert r["stages"]["readback"] >= r["stages"]["wait"] + r["stages"]["d2h"]
+    assert r["attributed_s"] <= r["wall_s"] * 1.01
+    assert r["device_s"] < r["stages"]["readback"] + r["stages"]["kernel"] + 0.001
+    # under a host umbrella the whole readback moves to the device side
+    with prof.cycle(kind="t") as c:
+        with obs.span("device.apply"):
+            with obs.span("device.readback"):
+                with obs.span("device.wait"):
+                    _spin(0.004)
+    r = c.report
+    assert r["attributed_s"] <= r["wall_s"] * 1.01
+    assert r["device_s"] >= 0.004 and r["host_s"] < r["device_s"]
+    text = prof.render_text(prof.profiler.status())
+    assert "blocked on device:" in text
+    assert "kernel_dispatch" in text and "device_wait" in text
 
 
 # -- perf-report CLI ----------------------------------------------------------
