@@ -799,6 +799,48 @@ def _slope_rle(x: np.ndarray):
     return w.astype(np.int32), cum.astype(np.int32), s
 
 
+_SCAN_BLOCK = 1024
+
+
+def _expand_runs(W, C, S, n):
+    """Decode a stack of K ``_slope_rle`` run tables on device: (K, R)
+    run values ``W`` and cumulative ends ``C`` (padded with ``C == n``),
+    per-table slopes ``S`` (None: every slope is 0) -> (K, n) int32 rows,
+    row i of table k being ``W[k, r] + S[k] * i`` for the run r that
+    holds i.
+
+    Each run's step from the run before is written at the run's first
+    row, and a prefix sum over the rows carries it forward: one scatter
+    of K*R entries and one scan, with no search over the runs and no
+    gather over the rows. The steps may wrap in int32; the wrapped sum
+    is still the exact value. Padded entries start past the last row,
+    so they either drop or land in the rows cut off at the end.
+
+    The sum runs in blocks of ``_SCAN_BLOCK`` rows, then over the
+    blocks' totals: the TPU compiler takes seconds to compile one scan
+    along a row of 262k, and a fraction of that for the two short
+    ones."""
+    K, R = W.shape
+    nb = -(-n // _SCAN_BLOCK)
+    zero = jnp.zeros((K, 1), jnp.int32)
+    starts = jnp.concatenate([zero, C[:, :-1]], axis=1)
+    # padded entries would all start at n: give each its own index past
+    # the end, so the scatter's indices stay unique
+    starts = jnp.where(starts < n, starts, n + jnp.arange(R, dtype=jnp.int32))
+    steps = W - jnp.concatenate([zero, W[:, :-1]], axis=1)
+    k = jnp.broadcast_to(jnp.arange(K, dtype=jnp.int32)[:, None], (K, R))
+    heads = jnp.zeros((K, nb, _SCAN_BLOCK), jnp.int32).at[
+        k, starts // _SCAN_BLOCK, starts % _SCAN_BLOCK
+    ].add(steps, mode="drop", indices_are_sorted=True, unique_indices=True)
+    inner = jax.lax.cumsum(heads, axis=2)
+    total = inner[:, :, -1]
+    before = jax.lax.cumsum(total, axis=1) - total
+    rows = (inner + before[:, :, None]).reshape(K, -1)[:, :n]
+    if S is not None:
+        rows = rows + S[:, None] * jnp.arange(n, dtype=jnp.int32)
+    return rows
+
+
 def _note_h2d(actual: int, dense: int) -> None:
     """Byte accounting every H2D site shares: the counter pair the
     perf-report ratio line reads, plus the cycle profiler notes."""
@@ -816,10 +858,9 @@ def stage_cols_device(cols_np):
     Per column: slope-RLE runs (the resident format's device image) are
     ``device_put`` as (w, cum) run tables padded to run-capacity buckets
     — so ``device_put`` moves compressed bytes, not dense int32 rows —
-    and expanded ON device with one vectorized searchsorted gather per
-    column (the ops/merge.py packed-transport ``_expand`` rule, run
-    eagerly so the jit kernel caches never churn on data-dependent run
-    shapes). A column whose run structure degenerates past the
+    and expanded ON device by ``_expand_runs``, one dispatch per stack
+    (run eagerly so the jit kernel caches never churn on data-dependent
+    run shapes). A column whose run structure degenerates past the
     ``_slope_rle`` gate ships dense (counted via
     ``oplog.compress_fallback{column,reason=h2d}``).
 
@@ -881,6 +922,9 @@ def stage_cols_device(cols_np):
     # (device.expand): it is the exact work the run-native kernels fuse
     # away, so the split must show it apart from the device_put h2d
     if dev_stacks:
+        obs.count("device.runs_expanded",
+                  n=sum(len(cols) for _, _, cols, _, _, _ in dev_stacks),
+                  labels={"path": "eager"})
         with obs.span("device.expand", rows=P, stacks=len(dev_stacks)):
             for n, rcap, cols, W, C, S in dev_stacks:
                 bools = tuple(b for _, _, b in cols)
@@ -903,15 +947,7 @@ def _expander(n, rcap, bools):
     fn = _EXPAND_CACHE.get(key)
     if fn is None:
         def expand(W, C, S):
-            i = jnp.arange(n, dtype=jnp.int32)
-
-            def one(w, c, s):
-                j = jnp.clip(
-                    jnp.searchsorted(c, i, side="right"), 0, rcap - 1
-                ).astype(jnp.int32)
-                return w[j] + s * i
-
-            cols = jax.vmap(one)(W, C, S)
+            cols = _expand_runs(W, C, S, n)
             return tuple(
                 cols[k].astype(jnp.bool_) if b else cols[k]
                 for k, b in enumerate(bools)
@@ -927,17 +963,17 @@ def _expander(n, rcap, bools):
 # EAGERLY (the device.expand dispatch) before the resolution kernel runs,
 # so kernel input bandwidth is dense again the moment resolution starts.
 # Run-native mode keeps the run tables as the KERNEL's input: the
-# expansion gathers (searchsorted over R run heads + stride arithmetic —
-# the StrideRuns.join trick, on device) move INSIDE the kernel jit, where
-# XLA fuses them into their consumers, so device input traffic for
+# expansion (_expand_runs: run steps scattered at their first rows, a
+# prefix sum, plus stride arithmetic) moves INSIDE the kernel jit, where
+# XLA fuses it into its consumers, so device input traffic for
 # run-eligible columns scales with run count, not history size (the
 # LSM-OPD compute-on-compressed argument, arXiv:2508.11862). Kernels are
 # specialized per column-encoding signature via control-flow duplication
-# (arXiv:2302.10098): pure-RLE stacks (every stride 0) expand as a plain
-# run gather w[j], delta+RLE stacks add the dynamic stride term
-# w[j] + s*i, and a column whose run structure degenerates past the
-# resident ratio gate (compressed.run_gate) ships dense, counted per
-# column on device.run_native_fallback{column,reason}.
+# (arXiv:2302.10098): pure-RLE stacks (every stride 0) expand without a
+# stride term, delta+RLE stacks add the dynamic one s*i, and a column
+# whose run structure degenerates past the resident ratio gate
+# (compressed.run_gate) ships dense, counted per column on
+# device.run_native_fallback{column,reason}.
 
 
 def run_native_enabled() -> bool:
@@ -1075,10 +1111,10 @@ def run_native_kernel(plan, geom, fetch=RESOLVE_FETCH):
 
     One compiled variant exists per (plan, geom) — the
     control-flow-duplication axis: every distinct per-column encoding
-    signature compiles its own kernel whose in-jit expansion is
-    specialized to the encoding class (pure-RLE: ``w[j]``; delta+RLE:
-    ``w[j] + s*i`` with dynamic slopes), and XLA fuses those gathers into
-    the resolution consumers."""
+    signature compiles its own kernel whose in-jit expansion
+    (``_expand_runs``) is specialized to the encoding class (pure-RLE:
+    no stride term; delta+RLE: ``+ s*i`` with dynamic slopes), and XLA
+    fuses it into the resolution consumers."""
     key = (plan, geom, fetch)
     fn = _RUN_NATIVE_CACHE.get(key)
     if fn is None:
@@ -1087,21 +1123,10 @@ def run_native_kernel(plan, geom, fetch=RESOLVE_FETCH):
         @_stage("expand_runs")
         def expand(dense, stacks):
             c = dict(dense)
-            for (n, rcap, cls, names, bools), arrs in zip(plan, stacks):
-                i = jnp.arange(n, dtype=jnp.int32)
-
-                def gather(w, cum, _i=i, _rcap=rcap):
-                    j = jnp.clip(
-                        jnp.searchsorted(cum, _i, side="right"), 0, _rcap - 1
-                    ).astype(jnp.int32)
-                    return w[j]
-
-                if cls == "rle":
-                    colv = jax.vmap(gather)(arrs[0], arrs[1])
-                else:
-                    colv = jax.vmap(
-                        lambda w, cum, s, _g=gather, _i=i: _g(w, cum) + s * _i
-                    )(arrs[0], arrs[1], arrs[2])
+            for (n, _, cls, names, bools), arrs in zip(plan, stacks):
+                colv = _expand_runs(
+                    arrs[0], arrs[1], None if cls == "rle" else arrs[2], n
+                )
                 for idx, (name, b) in enumerate(zip(names, bools)):
                     c[name] = colv[idx].astype(jnp.bool_) if b else colv[idx]
             return c
@@ -1161,6 +1186,9 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
         if plan:
             obs.count("device.kernel_launches",
                       labels={"path": "run_native"})
+            obs.count("device.runs_expanded",
+                      n=sum(len(names) for _, _, _, names, _ in plan),
+                      labels={"path": "run_native"})
     else:
         dense, stacks, plan = stage_cols_device(cols_np), (), ()
     fn = run_native_kernel(plan, geom, tuple(fetch))
@@ -1180,8 +1208,7 @@ def encode_transport(cols) -> tuple:
     The op columns are extremely runny in real workloads (typing runs give
     ``elem_ref[i] = i-1`` or stride-N interleaves, long spans share one
     object/action/width), so most of the input compresses to a few KB.
-    Runs are decoded on device by one vectorized searchsorted per column
-    (_expand).
+    Runs are decoded on device by ``_expand_runs``, one call per stack.
 
     Returns (static_key, arrays) where ``static_key`` identifies the jit
     variant (which columns are plain) and ``arrays`` is the input pytree.
@@ -1226,12 +1253,16 @@ def encode_transport(cols) -> tuple:
     return (run_namesP, plainP, run_namesQ, plainQ), arrays
 
 
-def _expand(w, cum, slope, n):
-    """Decode one slope-RLE column on device: (R,) runs -> (n,) values."""
-    i = jnp.arange(n, dtype=jnp.int32)
-    j = jnp.searchsorted(cum, i, side="right").astype(jnp.int32)
-    j = jnp.clip(j, 0, w.shape[0] - 1)
-    return w[j] + slope * i
+def count_transport(static_key) -> None:
+    """Count one packed-transport launch's columns: those decoded from
+    runs on the device (``device.runs_expanded{path=packed}``) and each
+    shipped plain (``oplog.compress_fallback{column,reason=transport}``)."""
+    run_namesP, plainP, run_namesQ, plainQ = static_key
+    obs.count("device.runs_expanded", n=len(run_namesP) + len(run_namesQ),
+              labels={"path": "packed"})
+    for k in plainP + plainQ:
+        obs.count("oplog.compress_fallback",
+                  labels={"column": k, "reason": "transport"})
 
 
 @_stage("transport_unpack")
@@ -1242,11 +1273,13 @@ def _unpack_transport(static_key, arrays, P, Q):
         ("P", run_namesP, plainP, P),
         ("Q", run_namesQ, plainQ, Q),
     ):
-        for i, k in enumerate(run_names):
-            cols[k] = _expand(
-                arrays[f"w{gname}"][i], arrays[f"c{gname}"][i],
-                arrays[f"s{gname}"][i], n,
+        if run_names:
+            runs = _expand_runs(
+                arrays[f"w{gname}"], arrays[f"c{gname}"],
+                arrays[f"s{gname}"], n,
             )
+            for i, k in enumerate(run_names):
+                cols[k] = runs[i]
         for i, k in enumerate(plain_names):
             cols[k] = arrays[f"plain{gname}"][i]
     flags = cols.pop("flags")
@@ -1372,6 +1405,7 @@ def _packed_merge(cols_np, fetch, n_objs, n_props=None):
     )
 
     static_key, arrays = encode_transport(cols_np)
+    count_transport(static_key)
     key = (dev_fetch, obj_cap, static_key, P, Q, scatter_geom)
     fn = _packed_cache.get(key)
     if fn is None:

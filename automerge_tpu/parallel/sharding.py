@@ -50,6 +50,7 @@ from ..ops.merge import (
     NONE32,
     _ceil_log2,
     _unpack_transport,
+    count_transport,
     encode_transport,
     forest as _forest,
     readback,
@@ -573,6 +574,7 @@ def sharded_merge_columns(
     _prof.note("launches")
     if transport == "packed":
         static_key, arrays = encode_transport(cols_np)
+        count_transport(static_key)
         fn = _make_sharded_fn(
             mesh, Ptot, n_objs2, np_eff,
             (static_key, len(cols_np["pred_src"])), R2,

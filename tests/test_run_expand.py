@@ -1,0 +1,206 @@
+"""On-device decode of slope-RLE run tables (ops/merge.py ``_expand_runs``).
+
+A stack of run tables expands with one scatter of each run's step at its
+first row and one prefix sum over the rows. These tests hold it to the
+column ``_slope_rle`` was given, bit for bit, on the shapes the encoder
+produces (one run, constant, slope 1, a stride-N slope, the n/8 gate, a
+run capacity padded far past the runs, values and steps at the ends of
+int32, 32 rows, bool columns), then hold the three launch sites that
+share it (packed transport, run-native kernel, eager expansion) to the
+dense oracle (``AUTOMERGE_TPU_COMPRESSED=0``) on small edit traces, and
+check the counters that say how many columns each launch decoded.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+
+from automerge_tpu import bench as W
+from automerge_tpu import obs
+from automerge_tpu.ops import OpLog
+from automerge_tpu.ops import merge as M
+from automerge_tpu.ops.oplog import _next_pow2, pad_columns
+
+I32_MIN, I32_MAX = -(1 << 31), (1 << 31) - 1
+
+
+def _column(case, n=4096, seed=0):
+    rng = np.random.default_rng(seed)
+    i = np.arange(n, dtype=np.int64)
+    if case == "one_run":
+        x = 100 + 3 * i
+    elif case == "constant":
+        x = np.full(n, 7)
+    elif case == "slope_1":
+        # typing chains: elem_ref = row - 1, broken by cursor jumps
+        x = i - 1 + np.repeat(rng.integers(-500, 500, n // 64), 64)
+    elif case == "stride_n":
+        # 16 replicas' same-counter ops interleaved in Lamport order
+        x = 16 * i + np.repeat(rng.integers(-50, 50, n // 128), 128)
+    elif case == "at_gate":
+        # the most runs the encoder accepts: n/8
+        v = np.cumsum(rng.integers(1, 1000, n // 8))
+        x = np.repeat(v, 8)
+    elif case == "wrap":
+        # every step overflows int32
+        x = np.repeat(np.resize([I32_MAX, I32_MIN, I32_MAX - 1, I32_MIN + 1],
+                                n // 32), 32)
+    elif case == "full_range":
+        x = np.repeat(rng.integers(I32_MIN, I32_MAX, n // 256,
+                                   endpoint=True), 256)
+    elif case == "short":
+        x = np.array([5] * 20 + [-3] * 12)
+    else:
+        raise ValueError(case)
+    return x.astype(np.int32)
+
+
+def _table(encs, n, rcap):
+    K = len(encs)
+    Wt = np.zeros((K, rcap), np.int32)
+    Ct = np.full((K, rcap), np.int32(n), np.int32)
+    St = np.empty(K, np.int32)
+    for k, (w, cum, s) in enumerate(encs):
+        Wt[k, : len(w)] = w
+        Ct[k, : len(cum)] = cum
+        St[k] = s
+    return Wt, Ct, St
+
+
+CASES = ["one_run", "constant", "slope_1", "stride_n", "at_gate", "wrap",
+         "full_range", "short"]
+
+
+@pytest.mark.parametrize("pad", [1, 64], ids=["tight", "padded"])
+@pytest.mark.parametrize("case", CASES)
+def test_expand_runs_decodes_what_was_encoded(case, pad):
+    x = _column(case)
+    n = len(x)
+    enc = M._slope_rle(x)
+    assert enc is not None
+    w, cum, s = enc
+    if case == "one_run":
+        assert len(w) == 1 and s == 3
+    if case == "stride_n":
+        assert s == 16
+    if case == "at_gate":
+        assert len(w) == n // 8
+    if case == "short":
+        assert n == 32
+    rcap = pad * _next_pow2(max(len(w), 16))
+    Wt, Ct, St = _table([enc], n, rcap)
+    fn = jax.jit(M._expand_runs, static_argnums=3)
+    got = np.asarray(fn(Wt, Ct, St, n))
+    assert got.dtype == np.int32 and got.shape == (1, n)
+    np.testing.assert_array_equal(got[0], x)
+    if s == 0:
+        # the run-native kernels drop the stride term of a pure-RLE stack
+        np.testing.assert_array_equal(np.asarray(fn(Wt, Ct, None, n))[0], x)
+
+
+def test_expand_runs_decodes_a_stack_at_once():
+    # one call decodes every table of a stack; the runniest sets rcap
+    cols = [_column(c) for c in ("slope_1", "constant", "wrap", "stride_n")]
+    encs = [M._slope_rle(x) for x in cols]
+    n = len(cols[0])
+    rcap = _next_pow2(max(len(e[0]) for e in encs))
+    got = np.asarray(M._expand_runs(*_table(encs, n, rcap), n))
+    np.testing.assert_array_equal(got, np.stack(cols))
+
+
+def test_eager_expander_keeps_bool_columns():
+    n = 256
+    b = np.zeros(n, bool)
+    b[40:90] = True
+    b[200:] = True
+    x = _column("slope_1", n)
+    encs = [M._slope_rle(b.astype(np.int32)), M._slope_rle(x)]
+    rcap = _next_pow2(max(len(e[0]) for e in encs) + 16)
+    got_b, got_x = M._expander(n, rcap, (True, False))(*_table(encs, n, rcap))
+    assert got_b.dtype == np.bool_ and got_x.dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(got_b), b)
+    np.testing.assert_array_equal(np.asarray(got_x), x)
+
+
+# -- the three launch sites against the dense oracle --------------------------
+
+
+def _log(kind):
+    """``edit``: an edit trace; ``fanin``: the same with 12 replicas'
+    16-edit changes merged in; ``typing``: one sequential typing run with
+    those replicas, whose pred columns run-encode with slopes."""
+    n_edits = 1500
+    trace = W.synth_edit_trace(n_edits, seed=3)
+    typed = [[i, 0, "a"] for i in range(n_edits)]
+    base = W.build_base(typed if kind == "typing" else trace, n_edits)
+    changes = list(base.changes)
+    if kind != "edit":
+        changes += W.synth_fanin(base, trace, 12, 16, n_edits)
+    return OpLog.from_changes(changes)
+
+
+def _resolve(log, monkeypatch, env):
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    return M.merge_columns(log.columns(), fetch=M.RESOLVE_FETCH,
+                           n_objs=log.n_objs, n_props=len(log.props))
+
+
+def _runs_expanded(path):
+    return obs.counter_values("device.runs_expanded", "path").get(path, 0)
+
+
+SITES = {
+    "packed": {"AUTOMERGE_TPU_TRANSPORT": "packed",
+               "AUTOMERGE_TPU_COMPRESSED": "1"},
+    "run_native": {"AUTOMERGE_TPU_TRANSPORT": "dict",
+                   "AUTOMERGE_TPU_COMPRESSED": "1",
+                   "AUTOMERGE_TPU_RUN_NATIVE": "1"},
+    "eager": {"AUTOMERGE_TPU_TRANSPORT": "dict",
+              "AUTOMERGE_TPU_COMPRESSED": "1",
+              "AUTOMERGE_TPU_RUN_NATIVE": "0"},
+}
+
+
+@pytest.mark.parametrize("kind", ["edit", "fanin", "typing"])
+@pytest.mark.parametrize("site", sorted(SITES))
+def test_site_matches_the_dense_oracle(site, kind, monkeypatch):
+    log = _log(kind)
+    dense = _resolve(log, monkeypatch, {"AUTOMERGE_TPU_TRANSPORT": "dict",
+                                        "AUTOMERGE_TPU_COMPRESSED": "0"})
+    before = _runs_expanded(site)
+    got = _resolve(log, monkeypatch, SITES[site])
+    assert _runs_expanded(site) > before, "no column was decoded from runs"
+    for k in M.RESOLVE_FETCH:
+        have, want = got[k], dense[k]
+        if site == "packed":
+            if k == "conflicts":
+                # the packed transport returns a conflicted flag (1 or 2)
+                have, want = have > 1, want > 1
+            if k in M._OBJ_STATS:
+                # ... and the per-object stats at a bucketed capacity
+                have = have[: log.n_objs + 2]
+        np.testing.assert_array_equal(have, want, err_msg=k)
+
+
+def _fallbacks(reason):
+    return sum(
+        e["value"] for e in obs.snapshot()
+        if e["type"] == "counter" and e["name"] == "oplog.compress_fallback"
+        and e["labels"].get("reason") == reason
+    )
+
+
+def test_packed_launch_counts_every_column(monkeypatch):
+    log = _log("edit")
+    cols = pad_columns(log.columns(), log.n_objs)
+    static_key, _ = M.encode_transport(cols)
+    runs = len(static_key[0]) + len(static_key[2])
+    plain = len(static_key[1]) + len(static_key[3])
+    assert runs and plain
+    r0, f0 = _runs_expanded("packed"), _fallbacks("transport")
+    _resolve(log, monkeypatch, SITES["packed"])
+    r1, f1 = _runs_expanded("packed"), _fallbacks("transport")
+    assert (r1 - r0, f1 - f0) == (runs, plain)
+    assert r1 - r0 + f1 - f0 == len(M._P_ORDER) + len(M._Q_ORDER)

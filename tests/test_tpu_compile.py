@@ -6,7 +6,8 @@ fast memory, a program that cannot be partitioned) fails here at no chip
 time. Each case compiles one kernel at a 4,096-row bucket with the real
 columns' widths and dtypes: the run-native resolution bodies, the
 packed-transport kernel, and the 4-device sharded merge on a mesh of the
-described chips.
+described chips. The run-table expansion of the packed and run-native
+kernels is checked to compile without a loop.
 
 The topology is described only inside the module fixture (never at
 import or collection): one process at a time may load libtpu, and every
@@ -16,6 +17,7 @@ read back without one.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -101,6 +103,45 @@ def test_packed_transport_kernel_compiles(one_chip, doc_log):
     fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn, Q,
                     M.scatter_geom_key(log.n_objs, len(log.props)))
     fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()}).compile()
+
+
+def _while_op_names(hlo_text):
+    """The ``op_name`` metadata of every ``while`` op of an HLO text."""
+    return [
+        m.group(1) if m else ""
+        for line in hlo_text.splitlines() if " while(" in line
+        for m in [re.search(r'op_name="([^"]*)"', line)]
+    ]
+
+
+@pytest.mark.parametrize("program", ["packed", "run_native_core"])
+def test_run_expansion_has_no_loop(program, one_chip, doc_log):
+    # the run tables expand with one scatter and a prefix sum; a search
+    # over the runs lowers to a while loop over every row, per column
+    from automerge_tpu.ops.device_doc import DeviceDoc
+
+    log = doc_log
+    cols = pad_columns(log.columns(), log.n_objs)
+    Pn, Q = len(cols["action"]), len(cols["pred_src"])
+    if program == "packed":
+        static_key, arrays = M.encode_transport(cols)
+        assert static_key[0], "the edit-trace columns must run-encode"
+        fetch = tuple(k for k in DeviceDoc.READ_FETCH if k != "elem_index")
+        fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn,
+                        Q, M.scatter_geom_key(log.n_objs, len(log.props)))
+        lowered = fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()})
+    else:
+        dense, stacks, plan = M.stage_cols_run_native(cols)
+        assert plan, "the edit-trace columns must run-encode"
+        fn = M.run_native_kernel(plan, M.resolution_geom(Pn, log.n_objs),
+                                 M.RESOLVE_FETCH)
+        lowered = fn.lower(
+            {k: _spec(v, one_chip) for k, v in dense.items()},
+            tuple(tuple(_spec(a, one_chip) for a in st) for st in stacks),
+        )
+    names = _while_op_names(lowered.compile().as_text())
+    assert not [n for n in names
+                if "transport_unpack" in n or "expand_runs" in n], names
 
 
 def test_sharded_merge_compiles_on_four_chips(topo, doc_log):
