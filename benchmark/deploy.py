@@ -1,10 +1,16 @@
 """Deployments: the documents a configuration holds, made from the seed,
 loaded into the server, and checked against the reference.
 
-``kind`` in a configuration's file picks one of the classes below:
-
-* ``text``: ``docs`` durable text documents, each typed by one writer
-  from a generated edit trace of ``edits_per_doc`` keystrokes.
+``kind`` in a configuration's file names its deployment kind, the file
+``benchmark/kinds/<kind>.py``. That module's ``Deployment(cfg, seed,
+pool)`` gives ``build()`` (the documents, made from the seed; ``pool`` is
+a spawned process pool for the heavy part), ``docs``, ``base_max_op``,
+``apply(doc, rank, start_op, ops)`` (one change's abstract ops into the
+reference) and ``compare(client, srv, doc)``: ``{check: 0 or 1}``, the
+comparisons of one served document with its reference that decide
+``correct``. What every kind shares is here. The changes sent to a
+deployment come from a traffic mix's writer, ``benchmark/writers/<writer>.py``
+(``traffic.py``).
 
 Every document is opened with a device mirror (``device``) and the
 journal policy ``fsync``. Nothing here imports JAX.
@@ -13,11 +19,14 @@ journal policy ``fsync``. Nothing here imports JAX.
 from __future__ import annotations
 
 import base64
+import os
 
 import numpy as np
 
+from . import load
 from . import reference as R
-from . import synth as S
+
+KINDS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "kinds")
 
 
 def rng(seed: int, *path: int):
@@ -50,43 +59,10 @@ class Doc:
         return [h for h in self.hashes if h not in self.deps]
 
 
-class TextDeployment:
-    def __init__(self, cfg, seed, pool):
-        self.cfg, self.seed = cfg, seed
-        self._futs = [pool.submit(S.replay_base, cfg["edits_per_doc"],
-                                  [seed % (1 << 63), 1, d])
-                      for d in range(cfg["docs"])]
-        self.docs = None
-        self.traces = None
-
-    def build(self):
-        out = [f.result() for f in self._futs]
-        self.traces = [t for t, _ in out]
-        self.docs = [Doc(f"text-{d}", S.text_base_change(t), ref)
-                     for d, (t, ref) in enumerate(out)]
-        self.base_max_op = self.cfg["edits_per_doc"] + 1
-        return self.docs
-
-    def base_rows(self, d):
-        return self.cfg["edits_per_doc"] + 1
-
-    def base_preds(self, d):
-        return sum(1 for e in self.traces[d] if e[1])
-
-    def base_vis(self, d):
-        return [e for v in self.docs[d].ref.vis for e in v]
-
-    def apply(self, doc, rank, start_op, ops):
-        ref = doc.ref
-        for i, op in enumerate(ops):
-            if op[0] == "ins":
-                ref.insert_after(op[1], R.op_id(start_op + i, rank), op[2])
-            else:
-                ref.delete(op[1])
-
-
-KINDS = {"text": TextDeployment}
+def kind(name: str):
+    """The module of deployment kind ``name``."""
+    return load(KINDS_DIR, name)
 
 
 def make(cfg, seed, pool):
-    return KINDS[cfg["kind"]](cfg, seed, pool)
+    return kind(cfg["kind"]).Deployment(cfg, seed, pool)

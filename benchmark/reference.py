@@ -1,6 +1,7 @@
-"""The plain reference: Automerge's text (RGA) and map (last-writer-wins)
-semantics over the abstract ops the generator made, written from the
-published data model and importing nothing of the program.
+"""The plain reference: Automerge's text (RGA) semantics over the abstract
+ops the generator made, written from the published data model and
+importing nothing of the program. A deployment kind of other semantics
+(``benchmark/kinds/``) brings its own reference.
 
 An element or op id is one int, ``counter << 20 | actor rank``, where the
 rank orders actors by their bytes: so ``a > b`` is Automerge's op-id order

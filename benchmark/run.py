@@ -6,7 +6,10 @@
 The cell, its configuration (``benchmark/configs/<config>.json``), its
 traffic mix (``benchmark/traffic/<traffic>.json``) and its metrics (one
 reader each, ``benchmark/metrics/<metric>.py``) are found by the names in
-``BENCHMARK.json``.
+``BENCHMARK.json``; the configuration's deployment kind
+(``benchmark/kinds/<kind>.py``) and the mix's traffic writer
+(``benchmark/writers/<writer>.py``) by the names in those files. A name
+with no file ends the run with exit code 2 before JAX starts.
 
 This process holds the chip. It starts JAX with the persistent compile
 cache at ``.jax_cache/`` in the checkout, fails without a TPU, makes the
@@ -25,7 +28,6 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import multiprocessing  # noqa: E402
 import os  # noqa: E402
@@ -41,8 +43,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import (deploy, devtrace, reference, synth, traffic,  # noqa: E402
-                       wire)
+from benchmark import deploy, devtrace, load, traffic, wire  # noqa: E402
 
 GRACE_S = 60.0
 # set when the window opens: a planted fault (benchmark/faults.py) that
@@ -100,12 +101,7 @@ def load_cell(root: str, name: str) -> dict:
 
 def reader(name: str):
     """The ``read(ctx)`` function of metric ``name``."""
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load(os.path.join(HERE, "metrics"), name).read
 
 
 # -- what the program counts ---------------------------------------------------
@@ -304,25 +300,13 @@ def apply_ref(dep, ch):
 
 def check(client, srv, dep, plan, out) -> dict:
     """Every acknowledged change into the reference, then each document
-    compared: the served text and values, the digest, and the device
-    mirror's resolved values. Returns {check: documents that differ}."""
+    compared by its deployment kind's ``compare``. Returns {check:
+    documents that differ}."""
     apply_acked(dep, plan.clients, out["by_client"])
-    bad = {"rpc_text": 0, "rpc_materialize": 0, "doc_digest": 0,
-           "device_state": 0}
+    bad = {}
     for d in dep.docs:
-        text, mat, digest = client.pipeline([
-            ("text", {"doc": d.handle, "obj": synth.TEXT_OBJ}),
-            ("materialize", {"doc": d.handle}),
-            ("docDigest", {"doc": d.handle})])
-        want = {"text": d.ref.text()}
-        bad["rpc_text"] += text != want["text"]
-        bad["rpc_materialize"] += mat != want
-        bad["doc_digest"] += digest.get("digest") != reference.doc_digest(
-            d.hashes, d.heads())
-        dd = srv.rpc._docs[d.handle]
-        with dd.lock:
-            dev = dd.device_doc
-            bad["device_state"] += dev is None or dev.hydrate() != want
+        for k, v in dep.compare(client, srv, d).items():
+            bad[k] = bad.get(k, 0) + v
     return bad
 
 
@@ -374,15 +358,21 @@ def run(args, require_tpu=True, spec=None) -> int:
         os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT,
                                                                ".jax_cache")
     os.environ["AUTOMERGE_TPU_SPAN_BUFFER"] = str(1 << 18)
-    readers = {m["name"]: reader(m["name"])
-               for m in (spec["per_layer"] if args.trace else spec["end_to_end"])}
+    try:
+        readers = {m["name"]: reader(m["name"]) for m in (
+            spec["per_layer"] if args.trace else spec["end_to_end"])}
+        deployment = deploy.kind(cfg["kind"])
+        writer = traffic.writer(mix["writer"])
+    except FileNotFoundError as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 2
 
     # the reference's replay of each base document runs in JAX-free
     # workers while this process starts JAX
     pool = ProcessPoolExecutor(max_workers=min(8, os.cpu_count() or 1),
                                mp_context=multiprocessing.get_context("spawn"))
     try:
-        dep = deploy.make(cfg, args.seed, pool)
+        dep = deployment.Deployment(cfg, args.seed, pool)
         import jax
 
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -406,16 +396,20 @@ def run(args, require_tpu=True, spec=None) -> int:
 
         compile_cache.enable()
         dep.build()
-        plan = traffic.make(mix, dep, args.seed, args.seconds)
+        plan = writer.plan(mix, dep, args.seed, args.seconds)
     finally:
         pool.shutdown()
     log(f"generated {len(dep.docs)} documents, {len(plan.warm)} warm-up "
         f"changes and {sum(len(c['requests']) for c in plan.clients)} "
         "traffic requests")
-    return window(args, spec, dep, plan, readers, meter, devs, peaks)
+    return window(args, spec, dep, plan, readers, meter, devs, peaks,
+                  getattr(writer, "warm", warm_growth))
 
 
-def window(args, spec, dep, plan, readers, meter, devs, peaks) -> int:
+def window(args, spec, dep, plan, readers, meter, devs, peaks, warm) -> int:
+    """Serve the documents, warm up (``warm``: the writer's own, or
+    ``warm_growth``), open the window, then read the metrics and compare
+    the served state; prints the result line."""
     import jax
 
     cell, cfg, mix = spec["cell"], spec["config"], spec["traffic"]
@@ -434,7 +428,7 @@ def window(args, spec, dep, plan, readers, meter, devs, peaks) -> int:
         log("documents open and loaded")
         send_warm(client, dep, plan)
         log("warm-up sent")
-        n = warm_growth(client, dep, plan, cfg, meter)
+        n = warm(client, dep, plan, cfg, meter)
         if n:
             log(f"growth warmed in {n} steps")
 

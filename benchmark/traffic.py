@@ -1,12 +1,14 @@
 """The one traffic generator. A traffic mix is a data file,
-``benchmark/traffic/<mix>.json``; its keys pick and size what is made here:
+``benchmark/traffic/<mix>.json``; its keys pick and size what is made:
 
 * ``loop``: ``open`` (every writer sends on its own Poisson schedule) or
   ``closed`` (a client's next request is due when the previous one is
   answered);
-* ``writer``: what each change holds: ``keystroke`` (one op at an
-  editor's cursor) or ``replica`` (a slice of the document's own trace,
-  re-anchored on the base text, as a replica that was offline sends it);
+* ``writer``: what each change holds, the file
+  ``benchmark/writers/<writer>.py``. Its ``plan(t, dep, seed, seconds)``
+  makes the ``Plan``. It may also give ``warm(client, dep, plan, cfg,
+  meter)``, which set-up then runs in place of ``run.warm_growth`` and
+  which returns the resolutions it made;
 * sizes: writers, rates, changes per writer and per request, the warm-up
   and the replay of the window's growth in set-up.
 
@@ -14,18 +16,22 @@ Every change is generated in set-up, from the seed, with its abstract ops
 for the reference. A client sends requests, each a list of changes in one
 ``applyChanges``. Each seed gives the same number of writers, changes,
 requests and arrivals; only positions, keys and the order of arrivals
-differ.
+differ. What every writer shares is here. The documents a writer writes
+to come from the configuration's deployment kind,
+``benchmark/kinds/<kind>.py`` (``deploy.py``).
 """
 
 from __future__ import annotations
 
+import os
 from collections import namedtuple
 
 import numpy as np
 
-from . import synth as S
-from .deploy import rng
-from .reference import op_id
+from . import load
+
+WRITERS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "writers")
 
 Change = namedtuple("Change", "doc rank start_op ops raw hash deps")
 
@@ -39,11 +45,12 @@ class Plan:
         # [(document, the window's requests in the order they reach it,
         # the replay's stride)]: replayed on scratch copies in set-up so
         # that every program shape the window can reach is compiled
-        # before it opens (run.warm_growth)
+        # before it opens (run.warm_growth, unless the writer brings its
+        # own ``warm``)
         self.growth = []
 
 
-def _chain_changes(chain, enc, doc, rank, make_ops, n):
+def chain_changes(chain, enc, doc, rank, make_ops, n):
     out = []
     for _ in range(n):
         start, deps = chain.next_op, list(chain.deps)
@@ -65,95 +72,10 @@ def arrivals(r, n, seconds):
     return np.cumsum(r.permutation(gaps)).tolist()
 
 
-def keystroke(t, dep, seed, seconds):
-    """Open loop: ``writers_per_doc`` editors on each document, one
-    connection each, ``rate_per_s`` keystrokes a second over all of
-    them, one change a request. Every change of the window is replayed in
-    set-up on a scratch copy of its document, in the order they are due,
-    ``growth_every`` at a time (see ``Plan.growth``)."""
-    p = Plan()
-    n_docs = len(dep.docs)
-    E = t["writers_per_doc"]
-    per = int(round(t["rate_per_s"] * seconds / (n_docs * E)))
-    for d, doc in enumerate(dep.docs):
-        actors = [S.actor_of(1, d * E + e) for e in range(E)]
-        enc = S.Encoder([S.BASE_ACTOR] + actors)
-        vis = dep.base_vis(d)
-        timed = []
-        for e, actor in enumerate(actors):
-            r = rng(seed, 2, d, e)
-            ed = S.Editor(vis, r, t["insert_share"], t["jump_share"])
-            rank = enc.rank(actor)
-            chain = S.Chain(actor, [doc.base_hash], dep.base_max_op + 1)
-            ch = _chain_changes(chain, enc, d, rank,
-                                lambda s: [ed.keystroke(op_id(s, rank))],
-                                t["warmup_per_writer"] + per)
-            p.warm += ch[:t["warmup_per_writer"]]
-            due = arrivals(r, per, seconds)
-            reqs = [[c] for c in ch[t["warmup_per_writer"]:]]
-            p.clients.append({"conn": len(p.clients), "requests": reqs,
-                              "due": due})
-            timed += zip(due, reqs)
-        timed.sort(key=lambda x: x[0])
-        p.growth.append((d, [r for _, r in timed], t["growth_every"]))
-    p.connections = len(p.clients)
-    return p
-
-
-def replica(t, dep, seed, seconds):
-    """Closed loop through one relay: ``writers`` replicas reconnect to
-    document ``doc``, each with its chain of ``changes_per_writer``
-    changes of ``edits_per_change`` trace edits, and one peer connection
-    relays them, ``changes_per_request`` changes a request, one request
-    in flight. The relay's order is drawn from the seed: the chains
-    interleaved at random, each in its own order. Every drain then ends on
-    a request's boundary, so set-up can replay each state the window can
-    reach: the first ``growth_requests`` requests, ``growth_every`` at a
-    time (``Plan.growth``). ``warmup_writers`` more replicas send one
-    change of ``warmup_edits_per_change`` edits each in set-up: enough to
-    carry the document past the row bucket it starts just under."""
-    p = Plan()
-    p.mode = "closed"
-    d = t["doc"]
-    doc = dep.docs[d]
-    n_w, n_warm = t["writers"], t["warmup_writers"]
-    actors = [S.actor_of(2, i) for i in range(n_w + n_warm)]
-    enc = S.Encoder([S.BASE_ACTOR] + actors)
-    ranks = {a: i + 1 for i, a in enumerate(sorted(actors))}
-    vis = dep.base_vis(d)
-    K = t["changes_per_writer"]
-    slices = S.replica_slices(dep.traces[d], n_w, t["edits_per_change"], K)
-    slices += S.replica_slices(dep.traces[d], n_warm,
-                               t["warmup_edits_per_change"], 1)
-    chains = []
-    for i, actor in enumerate(actors):
-        r = rng(seed, 3, i)
-        rank = ranks[actor]
-        chain = S.Chain(actor, [doc.base_hash], dep.base_max_op + 1)
-        deleted = set()
-        it = iter(slices[i])
-
-        def make(start, _r=r, _rank=rank, _del=deleted, _it=it):
-            return S.replica_ops(vis, next(_it), _r, _del,
-                                 lambda j: op_id(start + j, _rank))
-
-        ch = _chain_changes(chain, enc, d, rank, make,
-                            1 if i >= n_w else K)
-        if i >= n_w:
-            p.warm += ch
-        else:
-            chains.append(iter(ch))
-    who = rng(seed, 5).permutation(np.repeat(np.arange(n_w), K))
-    order = [next(chains[i]) for i in who]
-    m = t["changes_per_request"]
-    reqs = [order[j:j + m] for j in range(0, len(order), m)]
-    p.clients.append({"conn": 0, "requests": reqs})
-    p.growth = [(d, reqs[:t["growth_requests"]], t["growth_every"])]
-    return p
-
-
-WRITERS = {"keystroke": keystroke, "replica": replica}
+def writer(name: str):
+    """The module of traffic writer ``name``."""
+    return load(WRITERS_DIR, name)
 
 
 def make(t, dep, seed, seconds) -> Plan:
-    return WRITERS[t["writer"]](t, dep, seed, seconds)
+    return writer(t["writer"]).plan(t, dep, seed, seconds)
