@@ -35,19 +35,28 @@ Two entry points:
   nothing else can touch a document between its host-side stage and the
   scatter of its kernel results.
 
-Fallback: a document whose subset rows exceed
-``AUTOMERGE_TPU_BATCH_FALLBACK_RATIO`` (default 0.5, strict) of the
-combined batch is peeled off and resolved through the existing per-doc
-path — padding 99 small documents up to a whale's capacity bucket (and
-making them wait out its kernel) costs more than the launch it saves.
-Documents whose dirty fraction trips the per-doc full-re-resolution
-cost model never reach the packer (``stage_batches`` resolves them
-per-doc immediately, same as ``apply_changes`` would).
+A stage is one of two kinds. A *subset* stage covers the dirty
+objects' rows. A *full* stage covers every row and object of its
+document: the staging half returns one where the dirty fraction trips
+the full-re-resolution cost model (``AUTOMERGE_TPU_DIRTY_FRACTION``),
+which is the common case for a small document, whose change dirties the
+objects that hold most of its rows. ``dispatch_stages`` decides once,
+for both kinds, where a stage resolves:
 
-Every packed launch counts ``device.kernel_launches{path=batched}``;
-the per-doc and sharded dispatch sites carry the same counter with
-their own ``path`` label, so "launches per drain cycle" is directly
-observable (and asserted by the ``serve_batched`` bench config).
+* alone in its generation: per document (``DeviceDoc._reresolve``);
+* peeled by the whale rule: per document. A document whose stage rows
+  exceed ``AUTOMERGE_TPU_BATCH_FALLBACK_RATIO`` (default 0.5, strict) of
+  the combined batch is peeled off — padding 99 small documents up to a
+  whale's capacity bucket (and making them wait out its kernel) costs
+  more than the launch it saves;
+* a full stage of a document on a mesh: per document, over the mesh;
+* every other stage: packed into the shared launch.
+
+Every packed launch counts ``device.kernel_launches{path=batched}`` and
+each stage it carries ``device.batch_stages{kind=full|subset}``; the
+per-doc and sharded dispatch sites carry the launch counter with their
+own ``path`` label, so "launches per drain cycle" and "documents per
+shared launch" are directly observable.
 """
 
 from __future__ import annotations
@@ -55,7 +64,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -73,6 +82,11 @@ _PACK_COLS = (
 )
 
 
+# the longest a flush leader waits past the batch window for the drains
+# in flight to join its generation (CrossDocBatcher.in_flight)
+FILL_WAIT_S = 1.0
+
+
 def _env_float(name: str, default: float) -> float:
     try:
         return float(os.environ.get(name, default))
@@ -82,15 +96,19 @@ def _env_float(name: str, default: float) -> float:
 
 class BatchStage:
     """One document's staged host append awaiting kernel resolution:
-    the dirty-object subset (``rows`` are log row indices, ``dirty`` the
-    dense dirty-object ids) plus the document itself for the scatter."""
+    the rows to resolve (``rows`` are log row indices, ``dirty`` the
+    dense object ids they belong to) plus the document itself for the
+    scatter. ``full`` says the stage covers every row and object of the
+    document (``full_stage``)."""
 
-    __slots__ = ("doc", "rows", "dirty", "error", "trace")
+    __slots__ = ("doc", "rows", "dirty", "full", "error", "trace")
 
-    def __init__(self, doc, rows: np.ndarray, dirty: np.ndarray):
+    def __init__(self, doc, rows: np.ndarray, dirty: np.ndarray,
+                 full: bool = False):
         self.doc = doc
         self.rows = rows
         self.dirty = dirty
+        self.full = full
         self.error: Optional[BaseException] = None
         # the submitting request's trace context (trace_id, span_id), so
         # the shared launch span can link back to every request it served
@@ -99,6 +117,13 @@ class BatchStage:
     @property
     def n_rows(self) -> int:
         return len(self.rows)
+
+
+def full_stage(doc) -> BatchStage:
+    """The stage of a whole-document re-resolution: every row, every
+    object."""
+    return BatchStage(doc, np.arange(doc.log.n, dtype=np.int64),
+                      np.arange(doc.log.n_objs, dtype=np.int64), full=True)
 
 
 def plan_stages(
@@ -156,10 +181,9 @@ def _pack(stages: Sequence[BatchStage]):
 
 
 def _dispatch_packed(cols, n_objs: int, n_props: int):
-    """The host + dispatch half of a packed launch: pad, stage
-    (run-native run tables or the eager-expand staging), dispatch the
-    kernel WITHOUT reading back, and rank element order host-side while
-    it flies — exactly like the per-doc dispatch
+    """The host + dispatch half of a packed launch: pad, stage plain
+    columns, dispatch the kernel WITHOUT reading back, and rank element
+    order host-side while it flies — exactly like the per-doc dispatch
     (DeviceDoc._dispatch_async). Returns an in-flight handle for
     ``_collect_packed``."""
     from .merge import prepare_resolution
@@ -167,7 +191,10 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
 
     useful = len(cols["action"])
     with obs.span("device.pack", rows=useful):
-        cols = pad_columns(cols, n_objs)
+        # pred edges padded to the rows' bucket at least, so the launch's
+        # shapes follow its rows alone where a document's ops have about
+        # one predecessor each
+        cols = pad_columns(cols, n_objs, min_preds=useful)
     P = len(cols["action"])
     # occupancy at the pack site: padded-vs-useful rows were invisible
     # before, and the ratio is the first input the super-batch tuner
@@ -178,11 +205,11 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
     _prof.note("padded_rows", P - useful)
     _prof.note("launches")
     obs.count("device.kernel_launches", labels={"path": "batched"})
-    # the super-batch ships compressed: runs are packed under the same
-    # _capacity buckets as the rows, so jit caches stay warm and
-    # device_put moves run tables, not dense rows; with run-native
-    # kernels the tables are the kernel's input itself
-    dispatch = prepare_resolution(cols, n_objs, n_props)
+    # the super-batch ships plain: which of its columns would run-encode
+    # follows the documents that happen to drain together, and would
+    # pick the program; plain columns leave it to the bucketed shapes,
+    # so a set-up that met a launch's shapes has compiled its program
+    dispatch = prepare_resolution(cols, n_objs, n_props, runs=False)
     out = dispatch()  # async dispatch
     with obs.span("device.linearize", rows=P):
         ei = host_linearize(cols)
@@ -229,18 +256,28 @@ def _scatter(metas, res) -> None:
 def dispatch_stages(
     stages: Sequence[BatchStage], fallback_ratio: Optional[float] = None
 ) -> dict:
-    """The dispatch half of ``resolve_stages``: whales resolve per-doc
-    immediately (they never pipeline), the rest pack into ONE kernel
-    launch that is dispatched but NOT collected. The returned handle
-    feeds ``collect_stages`` — possibly after the caller has staged more
-    host work under the in-flight launch (the drain pipeline)."""
-    batch, whales = plan_stages(stages, fallback_ratio)
+    """The dispatch half of ``resolve_stages``: a stage alone in
+    ``stages``, a whale (``plan_stages``) and a full stage of a document
+    on a mesh resolve per-doc immediately (they never pipeline); the
+    rest pack into ONE kernel launch that is dispatched but NOT
+    collected. The returned handle feeds ``collect_stages`` — possibly
+    after the caller has staged more host work under the in-flight
+    launch (the drain pipeline)."""
+    own, rest = [], []
+    for st in stages:
+        alone = len(stages) == 1 or (st.full and st.doc.on_mesh())
+        (own if alone else rest).append(st)
+    batch, whales = plan_stages(rest, fallback_ratio)
     for w in whales:
         obs.count("device.batched_fallback")
-        w.doc._reresolve(w.dirty)
+    for st in own + whales:
+        st.doc._reresolve(st.dirty)
     handle = None
     metas = None
     if batch:
+        for st in batch:
+            obs.count("device.batch_stages",
+                      labels={"kind": "full" if st.full else "subset"})
         links = [st.trace for st in batch if st.trace is not None]
         with obs.span("device.batched", links=links, docs=len(batch)):
             obs.observe("device.batch_docs", len(batch))
@@ -252,7 +289,7 @@ def dispatch_stages(
             handle = _dispatch_packed(cols, n_objs, max(n_props, 1))
     return {
         "batched": len(batch),
-        "fallback": len(whales),
+        "fallback": len(own) + len(whales),
         "handle": handle,
         "metas": metas,
     }
@@ -439,9 +476,18 @@ class CrossDocBatcher:
     ``apply(dev, batches)`` stages the document's drained device feed
     (the caller MUST hold that document's execution lock) and blocks
     until a shared launch has resolved it. The first stager of a
-    generation is the leader: it waits up to ``window_ms`` for
-    co-arriving documents (waking early at ``max_docs``), closes the
-    generation, and runs ``resolve_stages`` for everyone.
+    generation is the leader: it waits ``window_ms`` for co-arriving
+    documents (waking early at ``max_docs``), closes the generation, and
+    runs ``resolve_stages`` for everyone.
+
+    ``in_flight``, where the caller sets it (the serving layer passes its
+    shard pool's), counts the drains running or ready to run. Once the
+    window is out the leader keeps waiting while fewer documents than
+    that have joined, up to ``FILL_WAIT_S``: drains that are past their
+    queue reach the batcher within a drain's host work, so generations
+    fill instead of closing on whichever drain happened to come first.
+    With no other drain in flight the leader closes at the window, as
+    without ``in_flight``.
 
     ``mode``: "1" always batches, "0" never (callers fall back to
     ``apply_batches``), "auto" batches only on accelerator backends —
@@ -484,6 +530,7 @@ class CrossDocBatcher:
         self._cv = threading.Condition(threading.Lock())
         self._gen = _Generation()
         self._active: Optional[bool] = None
+        self.in_flight: Optional[Callable[[], int]] = None
 
     def active(self) -> bool:
         """Whether device feeds should route through this batcher."""
@@ -537,8 +584,8 @@ class CrossDocBatcher:
             # leader-staged subs and submitter-staged stages in one
             # generation, and exactly ONE leader must flush it
             leader = len(gen.stages) + len(gen.subs) == 1
-            if not leader and len(gen.stages) + len(gen.subs) >= self.max_docs:
-                self._cv.notify_all()  # wake the leader early
+            if not leader:
+                self._cv.notify_all()  # the leader counts the arrivals
         if leader:
             self._lead(gen)
         else:
@@ -553,8 +600,8 @@ class CrossDocBatcher:
             gen = self._gen
             gen.subs.append(sub)
             leader = len(gen.stages) + len(gen.subs) == 1
-            if not leader and len(gen.stages) + len(gen.subs) >= self.max_docs:
-                self._cv.notify_all()  # wake the leader early
+            if not leader:
+                self._cv.notify_all()  # the leader counts the arrivals
         if leader:
             self._lead(gen)
         else:
@@ -565,16 +612,34 @@ class CrossDocBatcher:
 
     def _lead(self, gen: _Generation) -> None:
         """The (single) flush leader: wait out the batch window for
-        co-arriving documents, close the generation, flush it."""
-        deadline = time.monotonic() + self.window
-        with self._cv:
-            while len(gen.stages) + len(gen.subs) < self.max_docs:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                self._cv.wait(left)
-            if self._gen is gen:  # close the generation we lead
-                self._gen = _Generation()
+        co-arriving documents, and past it for the drains in flight
+        (``in_flight``, up to ``FILL_WAIT_S``), close the generation,
+        flush it. The wait is a ``device.batch_window`` span: ``docs`` in
+        the generation when it closed, ``reason`` ``full`` (``max_docs``
+        or every drain in flight joined) or ``timeout``."""
+        t0 = time.monotonic()
+        with obs.span("device.batch_window") as sp:
+            with self._cv:
+                while True:
+                    docs = len(gen.stages) + len(gen.subs)
+                    waited = time.monotonic() - t0
+                    if docs >= self.max_docs:
+                        full = True
+                        break
+                    if waited < self.window:
+                        self._cv.wait(self.window - waited)
+                        continue
+                    full = (self.in_flight is None
+                            or docs >= self.in_flight())
+                    if full or waited >= FILL_WAIT_S:
+                        break
+                    # a drain that ends without joining does not notify:
+                    # look again each window
+                    self._cv.wait(max(self.window, 1e-3))
+                if self._gen is gen:  # close the generation we lead
+                    self._gen = _Generation()
+            sp.fields["docs"] = docs
+            sp.fields["reason"] = "full" if full else "timeout"
         self._flush(gen)
 
     def _flush(self, gen: _Generation) -> None:

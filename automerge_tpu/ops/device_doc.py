@@ -304,11 +304,10 @@ class DeviceDoc:
 
         Returns ``(applied, stage_or_None)``. ``None`` means resolution
         already completed inside this call: the delta was empty/pure
-        bookkeeping, the log had to rebuild (empty/partial resident
-        history), or the dirty fraction tripped the per-doc full
-        re-resolution cost model — the same per-doc fallbacks
-        ``apply_changes`` takes, run eagerly so a returned stage is
-        always pack-eligible.
+        bookkeeping, or the log had to rebuild (empty/partial resident
+        history). Where the dirty fraction trips the full re-resolution
+        cost model (``_resolves_whole``), the stage is a full one
+        (``batched.full_stage``): every row and object of the document.
         """
         if self._base is not self:
             raise ValueError("stage_batches on a historical view; use the base doc")
@@ -329,7 +328,7 @@ class DeviceDoc:
             return self._stage_ready(ready)
 
     def _stage_ready(self, ready):
-        from .batched import BatchStage
+        from .batched import BatchStage, full_stage
 
         if not ready:
             return 0, None
@@ -347,14 +346,9 @@ class DeviceDoc:
             return len(ready), None
         dirty = np.asarray(info.dirty_objs, np.int64)
         rows = self._subset_rows(dirty)
-        if (
-            len(rows) / self.log.n > self._dirty_fraction_limit()
-            or len(dirty) >= self.log.n_objs
-        ):
-            self._reresolve(dirty)
-            self._export_doc_gauges()
-            return len(ready), None
         self._export_doc_gauges()
+        if self._resolves_whole(rows, dirty):
+            return len(ready), full_stage(self)
         return len(ready), BatchStage(self, rows, dirty)
 
     def pending_changes(self) -> int:
@@ -918,10 +912,14 @@ class DeviceDoc:
         self._res_bufs[name] = nbuf
         return out
 
-    def _dirty_fraction_limit(self) -> float:
+    def _resolves_whole(self, rows, dirty) -> bool:
+        """The cost model: re-resolve the whole document when the dirty
+        objects hold more than ``AUTOMERGE_TPU_DIRTY_FRACTION`` (default
+        0.5) of its rows, or when every object is dirty."""
         import os
 
-        return float(os.environ.get("AUTOMERGE_TPU_DIRTY_FRACTION", "0.5"))
+        limit = float(os.environ.get("AUTOMERGE_TPU_DIRTY_FRACTION", "0.5"))
+        return len(rows) / self.log.n > limit or len(dirty) >= self.log.n_objs
 
     def _reresolve(self, dirty) -> None:
         log = self.log
@@ -931,7 +929,7 @@ class DeviceDoc:
             return
         rows = self._subset_rows(dirty)
         frac = len(rows) / m
-        if frac > self._dirty_fraction_limit() or len(dirty) >= log.n_objs:
+        if self._resolves_whole(rows, dirty):
             # cost model says re-resolving everything is cheaper than the
             # bookkeeping win (still NO re-extraction — columns are resident)
             obs.count("device.reresolve_full")
@@ -991,7 +989,7 @@ class DeviceDoc:
         if log.n == 0 or not len(dirty):
             return None
         rows = self._subset_rows(dirty)
-        if len(rows) / log.n > self._dirty_fraction_limit():
+        if self._resolves_whole(rows, dirty):
             # the caller must drain any in-flight batch BEFORE resolving
             # synchronously, or its stale results would overwrite ours
             return {"fallback": True}
@@ -1077,19 +1075,24 @@ class DeviceDoc:
     def disable_mesh(self) -> None:
         self._mesh = None
 
-    def _mesh_resolve(self) -> Optional[Dict[str, np.ndarray]]:
-        """One sharded full-log resolution over the mesh, or None when
-        mesh residency is off or below threshold. A mesh failure raises."""
-        if self._mesh is None:
-            if self._mesh_env_tried:
-                return None
+    def on_mesh(self) -> bool:
+        """Whether a full re-resolution of this document runs over the
+        mesh: mesh residency is on (``enable_mesh``, or
+        AUTOMERGE_TPU_MESH_DEVICES, probed once) and the log is at least
+        ``min_rows`` long."""
+        if self._mesh is None and not self._mesh_env_tried:
             self._mesh_env_tried = True
             import os
 
             nd = os.environ.get("AUTOMERGE_TPU_MESH_DEVICES")
-            if not nd or not self.enable_mesh(int(nd)):
-                return None
-        if self.log.n < self._mesh_min_rows:
+            if nd:
+                self.enable_mesh(int(nd))
+        return self._mesh is not None and self.log.n >= self._mesh_min_rows
+
+    def _mesh_resolve(self) -> Optional[Dict[str, np.ndarray]]:
+        """One sharded full-log resolution over the mesh, or None when
+        mesh residency is off or below threshold. A mesh failure raises."""
+        if not self.on_mesh():
             return None
         try:
             return self._mesh_resolve_inner()

@@ -259,7 +259,7 @@ def stage_docs(work) -> Tuple[List, Dict[int, DocResult]]:
     still in flight — the host seconds spent here under a live launch
     are the drain's ``overlap_s``.
     """
-    from .batched import BatchStage
+    from .batched import BatchStage, full_stage
 
     # -- dedup + causal order + admission: one span each for the whole
     # drain (the spans cover the surrounding glue too, so the cycle
@@ -305,7 +305,6 @@ def stage_docs(work) -> Tuple[List, Dict[int, DocResult]]:
                 scalar.append((key, dev, ready, label))
 
     stages: List = []
-    pending_reresolve: List[tuple] = []  # (key, plan, dirty)
 
     g = None
     if vec:
@@ -358,23 +357,11 @@ def stage_docs(work) -> Tuple[List, Dict[int, DocResult]]:
                 spliced += 1
                 if kind == "stage":
                     stages.append(BatchStage(p.dev, outcome[1], outcome[2]))
-                elif kind == "reresolve":
-                    pending_reresolve.append((p, outcome[1]))
+                elif kind == "full":
+                    stages.append(full_stage(p.dev))
         obs.count("oplog.append_rows", n=rows_total)
         obs.count("host_batch.docs", n=spliced)
         obs.event("host_batch.splice", docs=spliced, rows=rows_total)
-
-    # device-side per-doc fallbacks run OUTSIDE the host spans so their
-    # kernel/h2d spans attribute to the device side of the cycle split
-    for p, dirty in pending_reresolve:
-        res = results[id(p.dev)]
-        t0 = time.perf_counter()
-        try:
-            p.dev._reresolve(dirty)
-            p.dev._export_doc_gauges()
-        except BaseException as e:  # noqa: BLE001
-            res.error = e
-        _prof.note_doc(p.label, time.perf_counter() - t0)
 
     for key, dev, ready, label in scalar:
         res = results[key]
@@ -545,8 +532,8 @@ def _splice_doc(p: _DocPlan, g):
     what ``OpLog.append_changes`` + ``DeviceDoc._apply_append`` +
     ``stage_batches`` would do for this (tail, same-actors, LazyValues)
     delta, organized as per-encoding column passes with the shared
-    arrays pre-sorted. Returns ("stage", rows, dirty) |
-    ("reresolve", dirty) | ("done",).
+    arrays pre-sorted. Returns ("stage", rows, dirty) | ("full",) |
+    ("done",) | ("scalar",).
 
     No mutation happens until every admission check has passed: the
     only pre-commit writes go to scratch capacity buffers the resident
@@ -936,12 +923,9 @@ def _splice_doc(p: _DocPlan, g):
         for d in dirty:
             dev._all_elems_cache.pop(int(log.obj_table[d]), None)
 
-    # -- stage or per-doc resolve (the stage_batches decision) ------------
+    # -- a subset or a full stage (the stage_batches decision) -----------
     rows = dev._subset_rows(dirty)
-    if (
-        len(rows) / m > dev._dirty_fraction_limit()
-        or len(dirty) >= log.n_objs
-    ):
-        return ("reresolve", dirty)
     dev._export_doc_gauges()
+    if dev._resolves_whole(rows, dirty):
+        return ("full",)
     return ("stage", rows, dirty)

@@ -761,6 +761,14 @@ def _flags_column(cols) -> np.ndarray:
     )
 
 
+def _run_cap(n: int) -> int:
+    """Run-table capacity of a column of ``n`` rows: the most runs
+    ``_slope_rle`` lets a column keep, bucketed. It follows the column's
+    length and not its data, so every launch of one shape stages run
+    tables of one shape and reuses one compiled program."""
+    return _capacity(max(n // 8, 15), 16)
+
+
 def _slope_rle(x: np.ndarray):
     """Slope-RLE one column: x[i] == w[run(i)] + slope*i, or None.
 
@@ -852,7 +860,7 @@ def _note_h2d(actual: int, dense: int) -> None:
     _prof.note("h2d_dense_bytes", dense)
 
 
-def stage_cols_device(cols_np):
+def stage_cols_device(cols_np, runs=True):
     """Compressed H2D staging for the dict-path launch sites.
 
     Per column: slope-RLE runs (the resident format's device image) are
@@ -867,15 +875,15 @@ def stage_cols_device(cols_np):
     Records actual bytes moved as ``bytes=`` on the ``device.h2d`` span
     and on the ``device.h2d_bytes`` counter (dense-equivalent bytes ride
     on ``device.h2d_dense_bytes`` so compression wins are a ratio, not a
-    guess). ``AUTOMERGE_TPU_COMPRESSED=0`` restores the plain dense
-    upload everywhere.
+    guess). ``runs=False``, or ``AUTOMERGE_TPU_COMPRESSED=0`` everywhere,
+    gives the plain dense upload.
     """
     from . import compressed as _C
 
     cols_np = {k: np.asarray(v) for k, v in cols_np.items()}
     P = len(cols_np["action"])
     dense_bytes = sum(v.nbytes for v in cols_np.values())
-    if not _C.enabled():
+    if not runs or not _C.enabled():
         with obs.span("device.h2d", rows=P, bytes=dense_bytes):
             dev = {k: jnp.asarray(v) for k, v in cols_np.items()}
         _note_h2d(dense_bytes, dense_bytes)
@@ -901,7 +909,7 @@ def stage_cols_device(cols_np):
     # per-column ops would pay ~50 dispatch overheads per launch
     stacks = []
     for n, cols in groups.items():
-        rcap = _capacity(max(len(w) for _, (w, _, _), _ in cols), 16)
+        rcap = _run_cap(n)
         K = len(cols)
         W = np.zeros((K, rcap), np.int32)
         C = np.full((K, rcap), np.int32(n), np.int32)
@@ -1043,7 +1051,7 @@ def stage_cols_run_native(cols_np):
     plan = []
     host_stacks = []
     for (n, cls), cols in sorted(groups.items(), key=lambda kv: kv[0]):
-        rcap = _capacity(max(len(w) for _, (w, _, _), _ in cols), 16)
+        rcap = _run_cap(n)
         K = len(cols)
         W = np.zeros((K, rcap), np.int32)
         C = np.full((K, rcap), np.int32(n), np.int32)
@@ -1159,7 +1167,7 @@ def resolution_geom(P: int, n_objs=None, n_props=None, full=False):
 
 
 def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
-                       fetch=RESOLVE_FETCH):
+                       fetch=RESOLVE_FETCH, runs=True):
     """Stage bucket-padded dict columns for one resolution launch and
     return a zero-arg dispatch closure (staging spans
     ``device.h2d``/``device.expand`` land here, before it).
@@ -1178,10 +1186,13 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
     kernel body is the scatter-max winner kernel when the geometry gate
     allows, the sort-based core otherwise; ``full=True`` pins the
     everything-on-device body (on-chip linearization). The launch
-    returns the ``fetch`` outputs only."""
+    returns the ``fetch`` outputs only.
+
+    ``runs=False`` uploads every column plain: the program then follows
+    the columns' shapes alone, never which of them run-encode."""
     P = len(cols_np["action"])
     geom = resolution_geom(P, n_objs, n_props, full)
-    if run_native_enabled():
+    if runs and run_native_enabled():
         dense, stacks, plan = stage_cols_run_native(cols_np)
         if plan:
             obs.count("device.kernel_launches",
@@ -1190,7 +1201,7 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
                       n=sum(len(names) for _, _, _, names, _ in plan),
                       labels={"path": "run_native"})
     else:
-        dense, stacks, plan = stage_cols_device(cols_np), (), ()
+        dense, stacks, plan = stage_cols_device(cols_np, runs), (), ()
     fn = run_native_kernel(plan, geom, tuple(fetch))
     program = ("run_native" if plan
                else "scatter" if geom[0] == "scatter" else "dense")

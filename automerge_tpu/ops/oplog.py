@@ -1183,11 +1183,13 @@ def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
     return out
 
 
-def pad_columns(cols, n_objs: int, min_capacity: int = 16):
+def pad_columns(cols, n_objs: int, min_capacity: int = 16,
+                min_preds: int = 0):
     """Pad a columns() dict to jit-bucket capacities (idempotent: already
-    bucket-sized arrays pass through untouched)."""
+    bucket-sized arrays pass through untouched). The pred columns take
+    at least ``min_preds`` rows' bucket."""
     p = _capacity(len(cols["action"]), min_capacity)
-    q = _capacity(len(cols["pred_src"]), min_capacity)
+    q = _capacity(max(len(cols["pred_src"]), min_preds), min_capacity)
     fills = {
         "action": PAD_ACTION,
         "insert": False,

@@ -220,6 +220,9 @@ class SocketRpcServer:
                 _env_int("AUTOMERGE_TPU_BATCH_DOCS", 32), n_workers
             )
         )
+        # past the window, a flush leader waits for the drains the pool
+        # has in flight, so a generation holds every document draining now
+        self.batcher.in_flight = self.pool.in_flight
         # overload resilience: one per-node admission controller scores
         # load from the pool's dequeue waits + utilization, the store's
         # hydration/RSS pressure, sheds by priority class past the soft

@@ -142,6 +142,13 @@ class ShardPool:
         with self._lock:
             return self._busy / (len(self.workers) or 1)
 
+    def in_flight(self) -> int:
+        """Drains executing right now plus documents queued for a worker:
+        the drains that can reach the cross-document batcher before the
+        pool goes idle (``CrossDocBatcher.in_flight``)."""
+        with self._lock:
+            return self._busy + len(self._ready)
+
     def backlog(self) -> int:
         """Total queued items across every per-document queue."""
         with self._lock:

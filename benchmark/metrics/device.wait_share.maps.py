@@ -1,0 +1,9 @@
+"""The reader of ``device.wait_share.merge`` (see that file), for
+maps.reconnect."""
+
+import os
+
+from benchmark import load
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+read = load(HERE, "device.wait_share.merge").read

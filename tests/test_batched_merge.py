@@ -388,10 +388,16 @@ def test_mesh_full_reresolve_matches_single_device(monkeypatch):
     assert dev.enable_mesh(2, min_rows=0)
     before = launch_counts()
     # the batched staging (the served route on an accelerator) skips the
-    # host delta resolution: over the dirty-fraction limit it
-    # re-resolves the whole log, here over the mesh
+    # host delta resolution: over the dirty-fraction limit it stages the
+    # whole log, and a full stage of a document on a mesh resolves over
+    # the mesh
     monkeypatch.setenv("AUTOMERGE_TPU_DIRTY_FRACTION", "0")
-    assert dev.stage_batches([delta]) == (len(delta), None)
+    n, stage = dev.stage_batches([delta])
+    assert n == len(delta) and stage.full
+    assert len(stage.rows) == dev.log.n
+    from automerge_tpu.ops.batched import resolve_stages
+
+    assert resolve_stages([stage]) == {"batched": 0, "fallback": 1}
     ref.apply_changes(delta)
     after = launch_counts()
     assert after.get("sharded", 0) > before.get("sharded", 0)

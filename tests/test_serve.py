@@ -78,6 +78,30 @@ def test_shard_pool_backpressure_raises_queue_full():
     pool.stop(drain=True)
 
 
+def test_shard_pool_in_flight_counts_busy_and_ready_drains():
+    blocker = threading.Event()
+    started = threading.Semaphore(0)
+
+    def execute(key, items):
+        started.release()
+        blocker.wait(10)
+
+    pool = ShardPool(execute, workers=2, max_queue=4, max_batch=1)
+    try:
+        assert pool.in_flight() == 0
+        for key in ("a", "b", "c"):
+            pool.submit(key, 0)
+        assert started.acquire(timeout=5) and started.acquire(timeout=5)
+        # two workers drain "a" and "b"; "c" waits for one of them; a
+        # second request of a draining document adds no drain
+        pool.submit("a", 1)
+        assert pool.in_flight() == 3
+    finally:
+        blocker.set()
+        pool.stop(drain=True)
+    assert pool.in_flight() == 0
+
+
 def test_shard_pool_single_writer_per_key():
     """Even with many workers, one key is never executed by two workers
     at once — the single-writer guarantee documents rely on."""
