@@ -28,9 +28,12 @@ The resident bundle (``CompressedOpColumns``) is maintained
 *incrementally*: tail appends — the dominant shape, every
 ``OpLog.append_changes`` / ``ops/host_batch._tail_write`` splice —
 extend the last run in place instead of re-encoding
-(``StrideRuns.extend_tail``); anything that rewrites the resident prefix
-(non-tail splices, actor-rank remaps, re-resolved MISSING references)
-invalidates the bundle and the next consumer re-encodes lazily.
+(``StrideRuns.extend_tail``); a splice that inserts before the tail
+keeps the encodings of the rows below its first insertion point
+(``keep_prefix``) and the next sync encodes the rest; anything that
+rewrites the resident prefix (actor-rank remaps, re-resolved MISSING
+references) invalidates the bundle and the next consumer re-encodes
+lazily.
 
 ``AUTOMERGE_TPU_COMPRESSED=0`` restores the dense path everywhere (the
 A/B and differential-oracle knob — read per call, so one process can
@@ -394,6 +397,27 @@ class CompressedOpColumns:
                 continue
             self._sync_col(name, mode, arr, q, item)
         return self
+
+    def keep_prefix(self, p0: int, edges: int, refs: dict) -> None:
+        """After a splice that left resident rows below ``p0`` and the
+        first ``edges`` edges in place: keep only their encodings, so the
+        next ``sync`` encodes the rest. ``refs`` maps each row-reference
+        column to its values before the splice; such a column keeps only
+        the part before its first reference to a moved row (>= ``p0``)."""
+        for spec, keep in ((ROW_SPEC, p0), (EDGE_SPEC, edges)):
+            for name, _mode, _item in spec:
+                ent = self.entries.get(name)
+                if ent is None or ent is _DENSE:
+                    continue
+                k = min(keep, ent.n)
+                old = refs.get(name)
+                if old is not None:
+                    hit = np.flatnonzero(np.asarray(old[:k]) >= p0)
+                    if len(hit):
+                        k = int(hit[0])
+                if k < ent.n:
+                    self.entries[name] = ent.slice(0, k)
+                    self.covered[name] = k
 
     def extend_id(self, d_id) -> Optional[StrideRuns]:
         """Extend ONLY the id_key runs with a tail delta (the append

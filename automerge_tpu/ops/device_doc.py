@@ -40,7 +40,8 @@ from ..patches.patch import (
 from ..types import ObjType, is_make_action, objtype_for_action
 from .merge import merge_columns, readback
 from .oplog import (
-    ELEM_HEAD, ELEM_MISSING, MAKE_ACTIONS, ACTOR_BITS, OpLog, TAG_COUNTER,
+    ELEM_HEAD, ELEM_MISSING, MAKE_ACTIONS, ACTOR_BITS, OpLog, RowSplice,
+    TAG_COUNTER,
 )
 
 _MAKE_OBJ = {0: ObjType.MAP, 2: ObjType.LIST, 4: ObjType.TEXT, 6: ObjType.TABLE}
@@ -61,6 +62,24 @@ def order_elem_rows(log: "OpLog", elem_index: np.ndarray,
         np.asarray(log.insert)[obj_rows] & (elem_index[obj_rows] >= 0)
     ]
     return erows[np.argsort(elem_index[erows], kind="stable")]
+
+
+def _bisect_groups(rows: np.ndarray, lo: np.ndarray, hi: np.ndarray,
+                   want: np.ndarray) -> np.ndarray:
+    """For each query i, ``lo[i]`` plus the number of ``rows[lo[i]:hi[i]]``
+    below ``want[i]``, each such slice ascending: a binary search of all
+    queries at once (each takes the power-of-two steps that keep it below
+    ``want``), O(queries * log rows) whatever the number of slices."""
+    at = np.array(lo, np.int64)
+    hi = np.asarray(hi, np.int64)
+    step = 1 << int((hi - at).max(initial=0)).bit_length() >> 1
+    while step:
+        nxt = at + step
+        at += step * ((nxt <= hi) & (rows[np.minimum(nxt, hi) - 1] < want))
+        step >>= 1
+    return at
+
+
 _OBJ_REPLACEMENT = "￼"
 _PUT = 1
 _DELETE = 3
@@ -269,8 +288,7 @@ class DeviceDoc:
                 continue
             if inflight is not None:
                 # the in-flight handle's row/object ids move with the splice
-                if info.row_map is not None:
-                    inflight["rows"] = info.row_map[inflight["rows"]]
+                inflight["rows"] = info.map_rows(inflight["rows"])
                 if info.obj_remap is not None:
                     inflight["dirty"] = info.obj_remap[inflight["dirty"]]
             self._apply_append(info, ready)
@@ -500,7 +518,7 @@ class DeviceDoc:
         AppendInfo (positions move; values of clean objects are reused)."""
         log = self.log
         m = log.n
-        n_old, rm = info.n_old, info.row_map
+        n_old, rs = info.n_old, info.splice
         for ch in ready:
             self._hash_index[ch.hash] = ch
         if info.actors_changed:
@@ -533,22 +551,14 @@ class DeviceDoc:
             # the new rows' objects are all dirty and re-resolved next.
             # Capacity-bucketed buffers make the tail-append fast path
             # O(delta): only the k new slots are written.
-            win_old = self.winner
-            if rm is not None:
-                safe = max(n_old - 1, 0)
-                win_old = np.where(
-                    self.winner >= 0,
-                    rm[np.clip(self.winner, 0, safe)],
-                    -1,
-                ).astype(np.int32)
+            win_old = rs.map(np.asarray(self.winner, np.int32))
             vis = self._res_splice("visible", np.asarray(self.visible, np.bool_),
-                                   m, rm, n_old, False)
-            win = self._res_splice("winner", np.asarray(win_old, np.int32),
-                                   m, rm, n_old, -1)
+                                   m, rs, False)
+            win = self._res_splice("winner", win_old, m, rs, -1)
             con = self._res_splice("conflicts", np.asarray(self.conflicts, np.int32),
-                                   m, rm, n_old, 0)
+                                   m, rs, 0)
             ei = self._res_splice("elem_index", np.asarray(self.elem_index, np.int32),
-                                  m, rm, n_old, -1)
+                                  m, rs, -1)
             orm = info.obj_remap
             n_objs_old = len(orm) if orm is not None else log.n_objs
             ovl = np.zeros(log.n_objs + 2, np.int32)
@@ -576,13 +586,13 @@ class DeviceDoc:
             # splice, then absorb the delta's edges (kept fresh regardless
             # of which resolution path runs)
             self.succ_count = self._res_splice(
-                "succ_count", self.succ_count, m, rm, n_old, 0
+                "succ_count", self.succ_count, m, rs, 0
             )
             self.inc_count = self._res_splice(
-                "inc_count", self.inc_count, m, rm, n_old, 0
+                "inc_count", self.inc_count, m, rs, 0
             )
             value_int = np.asarray(log.value_int)
-            cv = self._res_splice("counter_val", self.counter_val, m, rm, n_old, 0)
+            cv = self._res_splice("counter_val", self.counter_val, m, rs, 0)
             cv[nr] = value_int[nr]
             self.counter_val = cv
             ps = np.asarray(log.pred_src)
@@ -601,27 +611,28 @@ class DeviceDoc:
                 np.add.at(self.inc_count, tgt[is_inc], 1)
                 np.add.at(self.counter_val, tgt[is_inc], value_int[src[is_inc]])
 
-            # object-sorted row index: merge the (already sorted) old order
-            # with the delta's rows — no full argsort
-            old_rbo = self._rows_by_obj
-            if rm is not None:
-                old_rbo = rm[old_rbo]
+            # object-sorted row index, (object, row) order: the resident
+            # order survives the row moves, and the delta's rows merge into
+            # it at their insertion points — no full argsort
+            old_rbo = rs.map(self._rows_by_obj)
             obj_key = np.asarray(log.obj_key)
-            old_keys = obj_key[old_rbo]
+            old_keys = (obj_key[old_rbo] if info.actors_changed
+                        else self._obj_sorted)
             d_keys = obj_key[nr]
             ordx = np.lexsort((nr, d_keys))
             d_rows = nr[ordx]
             d_keys = d_keys[ordx]
-            pos = np.searchsorted(old_keys, d_keys, side="right")
-            cnt = np.bincount(pos, minlength=n_old + 1)
+            at = np.searchsorted(old_keys, d_keys, side="right")
+            if not rs.tail:
+                at = _bisect_groups(
+                    old_rbo, np.searchsorted(old_keys, d_keys, side="left"),
+                    at, d_rows,
+                )
+            order = RowSplice(n_old, at)
             rbo = np.empty(m, np.int64)
             keys = np.empty(m, np.int64)
-            old_pos = np.arange(n_old, dtype=np.int64) + np.cumsum(cnt[:n_old])
-            rbo[old_pos] = old_rbo
-            keys[old_pos] = old_keys
-            new_pos = pos + np.arange(len(d_rows), dtype=np.int64)
-            rbo[new_pos] = d_rows
-            keys[new_pos] = d_keys
+            order.into(rbo, old_rbo, d_rows)
+            order.into(keys, old_keys, d_keys)
             self._rows_by_obj = rbo
             self._obj_sorted = keys
 
@@ -892,23 +903,20 @@ class DeviceDoc:
         self.res["obj_vis_len"][dirty] = np.asarray(res_sub["obj_vis_len"])[:D]
         self.res["obj_text_width"][dirty] = np.asarray(res_sub["obj_text_width"])[:D]
 
-    def _res_splice(self, name, old, m, rm, n_old, fill):
+    def _res_splice(self, name, old, m, rs, fill):
         """Splice one per-row resolution array through a capacity-bucketed
-        backing buffer (tail appends write only the new slots)."""
+        backing buffer (tail appends write only the new slots; other
+        splices copy the rows below the first insertion point and merge
+        the suffix)."""
         from .oplog import _capacity
 
         buf = self._res_bufs.get(name)
-        if rm is None and buf is not None and old.base is buf and len(buf) >= m:
-            buf[n_old:m] = fill
+        if rs.tail and buf is not None and old.base is buf and len(buf) >= m:
+            buf[rs.n:m] = fill
             return buf[:m]
         nbuf = np.empty(_capacity(m), old.dtype)
         out = nbuf[:m]
-        if rm is None:
-            out[:n_old] = old
-            out[n_old:] = fill
-        else:
-            out[:] = fill
-            out[rm] = old
+        rs.into(out, old, fill)
         self._res_bufs[name] = nbuf
         return out
 

@@ -79,6 +79,7 @@ from .oplog import (
     TAG_UNKNOWN,
     _capacity,
     _merge_table,
+    count_splice,
     join_rows,
 )
 
@@ -355,6 +356,8 @@ def stage_docs(work) -> Tuple[List, Dict[int, DocResult]]:
                 res.applied = len(p.ready)
                 rows_total += p.k
                 spliced += 1
+                if p.k:
+                    count_splice(p.actors_changed, True, 0)
                 if kind == "stage":
                     stages.append(BatchStage(p.dev, outcome[1], outcome[2]))
                 elif kind == "full":

@@ -572,8 +572,9 @@ class OpLog:
     def compressed(self, sync: bool = True):
         """The compressed image of the resident columns, or None when
         ``AUTOMERGE_TPU_COMPRESSED=0``. Maintained incrementally: tail
-        appends extend the last runs; prefix rewrites invalidate and the
-        next call re-encodes lazily."""
+        appends extend the last runs, a splice before the tail keeps the
+        runs below its first insertion point, prefix rewrites invalidate;
+        the next call encodes what is missing."""
         from . import compressed as C
 
         if not C.enabled():
@@ -678,27 +679,23 @@ class OpLog:
             )
         return True
 
-    def _splice_col(self, name, old, new_vals, row_map, new_rows, tail, m):
+    def _splice_col(self, name, old, new_vals, rs, m):
         """One column's splice into a capacity-bucketed backing buffer.
 
         Tail appends into a still-roomy buffer write only the k new slots;
-        everything else allocates at the bucket capacity and scatters both
-        sides through the position maps (one vectorized pass per column)."""
+        everything else allocates at the bucket capacity, copies the rows
+        below the first insertion point and merges the suffix
+        (``RowSplice.into``)."""
         old = np.asarray(old)
         new_vals = np.asarray(new_vals).astype(old.dtype, copy=False)
         n = len(old)
         buf = self._bufs.get(name)
-        if tail and buf is not None and old.base is buf and len(buf) >= m:
+        if rs.tail and buf is not None and old.base is buf and len(buf) >= m:
             buf[n:m] = new_vals
             return buf[:m]
         nbuf = np.empty(_capacity(m), old.dtype)
         out = nbuf[:m]
-        if tail:
-            out[:n] = old
-            out[n:] = new_vals
-        else:
-            out[row_map] = old
-            out[new_rows] = new_vals
+        rs.into(out, old, new_vals)
         self._bufs[name] = nbuf
         return out
 
@@ -706,8 +703,10 @@ class OpLog:
         """Splice new changes into the existing columns WITHOUT re-collecting
         prior replicas: extract only the fresh changes (vectorized, through
         the per-change-hash column cache), merge their rows into the
-        Lamport order with searchsorted position arithmetic, re-resolve
-        references that touch the delta, and report the dirty object set.
+        Lamport order with searchsorted position arithmetic (only the
+        resident rows after the first insertion point move: ``RowSplice``),
+        re-resolve references that touch the delta, and report the dirty
+        object set.
 
         Returns an ``AppendInfo`` on success, or ``None`` when the log
         cannot be updated in place (no retained column bytes, partial
@@ -731,7 +730,7 @@ class OpLog:
             batch_seen.add(ch.hash)
             fresh.append(ch)
         if not fresh:
-            return AppendInfo(self.n, 0, np.empty(0, np.int64), None, True,
+            return AppendInfo(RowSplice.append(self.n, 0),
                               np.empty(0, np.int64), None, False, 0)
         if any(
             ch.op_col_data is None and ch.cached_cols is None for ch in fresh
@@ -787,9 +786,8 @@ class OpLog:
             self._commit_actors(all_bytes, actors_changed, remap_packed, old_id)
             self.changes.extend(fresh)
             known.update(batch_seen)
-            return AppendInfo(n, 0, np.empty(0, np.int64), None, True,
-                              np.empty(0, np.int64), None, actors_changed,
-                              len(fresh))
+            return AppendInfo(RowSplice.append(n, 0), np.empty(0, np.int64),
+                              None, actors_changed, len(fresh))
 
         order = np.argsort(r["id_key"], kind="stable")
         d_id = r["id_key"][order]
@@ -802,7 +800,8 @@ class OpLog:
             if np.any(old_id[posc] == d_id):
                 obs.count("oplog.append_fallback", labels={"reason": "id_collision"})
                 return None
-        tail = n == 0 or pos[0] == n
+        rs = RowSplice(n, pos)
+        tail = rs.tail
         m = n + k
         # offset-value-coded id join: the compressed id_key runs (delta+
         # RLE over the packed (counter, actor) composites), extended
@@ -819,12 +818,7 @@ class OpLog:
                     comp = self._comp = C.CompressedOpColumns()
                 comp._sync_col("id_key", "delta", self.id_key, n)
                 idruns = comp.extend_id(d_id)
-        new_rows = pos + np.arange(k, dtype=np.int64)
-        if tail:
-            row_map = None
-        else:
-            cnt = np.bincount(pos, minlength=n + 1)
-            row_map = np.arange(n, dtype=np.int64) + np.cumsum(cnt[:n])
+        new_rows = rs.new_rows
         if self._bufs is None:
             self._bufs = {}
 
@@ -841,7 +835,7 @@ class OpLog:
 
         # -- splice the plain per-row columns ------------------------------
         sp = lambda name, old, new: self._splice_col(  # noqa: E731
-            name, old, new, row_map, new_rows, tail, m
+            name, old, new, rs, m
         )
         id_new = sp("id_key", old_id, d_id)
         obj_new = sp("obj_key", remap_packed(self.obj_key), r["obj"][order])
@@ -865,11 +859,7 @@ class OpLog:
             return join_rows(id_new, keys, ELEM_MISSING)
 
         # -- element references --------------------------------------------
-        old_er = self.elem_ref
-        if not tail:
-            old_er = np.where(
-                old_er >= 0, row_map[np.clip(old_er, 0, max(n - 1, 0))], old_er
-            )
+        old_er = rs.map(self.elem_ref)
         d_ek = r["elem"][order]
         d_er = np.where(
             d_ek == -1,
@@ -891,15 +881,8 @@ class OpLog:
 
         # -- pred edges (appended at the end; order is irrelevant) ---------
         q = len(self.pred_src)
-        old_ps = self.pred_src
-        old_pt = self.pred_tgt
-        if not tail:
-            safe_n = max(n - 1, 0)
-            old_ps = row_map[np.clip(old_ps, 0, safe_n)].astype(np.int32) \
-                if q else old_ps
-            old_pt = np.where(
-                old_pt >= 0, row_map[np.clip(old_pt, 0, safe_n)], old_pt
-            ).astype(np.int32) if q else old_pt
+        old_ps = rs.map(self.pred_src)
+        old_pt = rs.map(self.pred_tgt)
         inv = np.empty(k, np.int64)
         inv[order] = np.arange(k)
         d_ps = new_rows[inv[r["pred_src"]]].astype(np.int32) \
@@ -909,8 +892,9 @@ class OpLog:
             else np.empty(0, np.int32)
         d_pt = np.where(d_pt == ELEM_MISSING, np.int32(-1), d_pt)
         qm = q + len(d_ps)
+        edges = RowSplice.append(q, len(d_ps))
         cat = lambda name, old, new: self._splice_col(  # noqa: E731
-            name, np.asarray(old), new, None, None, True, qm
+            name, old, new, edges, qm
         )
         ps_new = cat("pred_src", old_ps, d_ps)
         pt_new = cat("pred_tgt", old_pt, d_pt)
@@ -944,7 +928,7 @@ class OpLog:
         od_all = sp("obj_dense", od_old.astype(np.int32, copy=False), od_new)
 
         # -- values heap ----------------------------------------------------
-        self._splice_values(a, order, row_map, new_rows, tail, m)
+        self._splice_values(a, order, rs, m)
 
         # -- dirty objects (NEW dense numbering) ---------------------------
         parts = [od_new, np.searchsorted(new_table, make_new)]
@@ -960,6 +944,22 @@ class OpLog:
             if np.any(hit):
                 parts.append(od_all[d_pt[hit]])
         dirty = np.unique(np.concatenate(parts)).astype(np.int64)
+
+        # -- compressed image: rows below p0 kept their index and values,
+        # so their encodings hold and the next sync encodes the rest.
+        # Actor remaps rewrite every packed key, new objects amid the
+        # resident ones renumber obj_dense, and re-resolved MISSING
+        # references mutate elem_ref / pred_tgt in place: those
+        # invalidate, and the next consumer re-encodes lazily
+        if self._comp is not None:
+            if (actors_changed or len(rere_rows) or len(rere_pred)
+                    or (obj_remap is not None and not tail)):
+                self._comp = None
+            elif not tail:
+                self._comp.keep_prefix(rs.p0, q, {
+                    "elem_ref": self.elem_ref, "pred_src": self.pred_src,
+                    "pred_tgt": self.pred_tgt,
+                })
 
         # -- commit ---------------------------------------------------------
         self.id_key = id_new
@@ -986,23 +986,18 @@ class OpLog:
         self.n_miss_pred = n_miss_pred
         self.actors = [ActorId(b) for b in all_bytes]
         self._actor_order = None
-        # the compressed image survives only the pure tail append: actor
-        # remaps rewrite every packed key, non-tail splices move the
-        # prefix, and re-resolved MISSING references mutate elem_ref /
-        # pred_tgt in place — all invalidate; the next consumer
-        # re-encodes lazily
-        if not tail or actors_changed or len(rere_rows) or len(rere_pred):
-            self._comp = None
         self.changes.extend(fresh)
         known.update(batch_seen)
         obs.count("oplog.append_rows", n=k)
+        count_splice(actors_changed, tail, rs.n_moved)
         obs.event(
             "oplog.append", rows=k, total=m, tail=int(tail),
-            dirty_objs=len(dirty), actors_changed=int(actors_changed),
+            moved=rs.n_moved, dirty_objs=len(dirty),
+            actors_changed=int(actors_changed),
         )
-        return AppendInfo(n, k, new_rows, row_map, tail, dirty, obj_remap,
-                          actors_changed, len(fresh), n_pred_old=q,
-                          rere_elem_rows=rere_rows, rere_pred_edges=rere_pred)
+        return AppendInfo(rs, dirty, obj_remap, actors_changed, len(fresh),
+                          n_pred_old=q, rere_elem_rows=rere_rows,
+                          rere_pred_edges=rere_pred)
 
     def _commit_actors(self, all_bytes, actors_changed, remap_packed, old_id):
         if not actors_changed:
@@ -1043,7 +1038,7 @@ class OpLog:
             obs.count("oplog.append_fallback", labels={"reason": "extract_failed"})
             return None
 
-    def _splice_values(self, a, order, row_map, new_rows, tail, m):
+    def _splice_values(self, a, order, rs, m):
         from .extract import LazyValues
 
         vals = self.values
@@ -1053,12 +1048,9 @@ class OpLog:
         d_raw = a["vraw"]
         if isinstance(vals, LazyValues):
             base = len(vals.raw)
-            code = self._splice_col("vcode", vals.code, d_code,
-                                    row_map, new_rows, tail, m)
-            off = self._splice_col("voff", vals.off, d_off + base,
-                                   row_map, new_rows, tail, m)
-            ln = self._splice_col("vlen", vals.ln, d_ln,
-                                  row_map, new_rows, tail, m)
+            code = self._splice_col("vcode", vals.code, d_code, rs, m)
+            off = self._splice_col("voff", vals.off, d_off + base, rs, m)
+            ln = self._splice_col("vlen", vals.ln, d_ln, rs, m)
             # append-only raw heap: a bytearray grows geometrically, so a
             # delta stream costs O(delta) amortized instead of re-copying
             # the resident bytes each append (offsets of old rows never
@@ -1073,40 +1065,111 @@ class OpLog:
             return
         # eager python list (slow collection path): object-array splice
         dv = LazyValues(d_code, d_off, d_ln, d_raw)
-        new_list = [dv[i] for i in range(len(d_code))]
         arr = np.empty(m, object)
-        if tail:
-            arr[: len(vals)] = vals
-            arr[len(vals):] = new_list
-        else:
-            arr[row_map] = vals
-            arr[new_rows] = new_list
+        rs.into(arr, vals, [dv[i] for i in range(len(d_code))])
         self.values = arr.tolist()
+
+
+class RowSplice:
+    """Where a splice puts the k inserted rows among n resident ones.
+
+    ``pos`` are the inserted rows' sorted insertion points in the resident
+    order. Resident rows below ``p0 = pos[0]`` keep their index; resident
+    row ``p0 + i`` moves to ``moved[i]``; ``new_rows`` are the inserted
+    rows' indices after the splice. So a per-row array splices as a copy
+    of its first ``p0`` rows plus a merge of its suffix (``into``), and a
+    row reference changes only where it is ``>= p0`` (``map``): the work
+    follows the rows after the first insertion point, not the log."""
+
+    __slots__ = ("n", "p0", "moved", "new_rows")
+
+    def __init__(self, n: int, pos):
+        pos = np.asarray(pos, np.int64)
+        k = len(pos)
+        p0 = int(pos[0]) if k else n
+        self.n = n
+        self.p0 = p0
+        self.new_rows = pos + np.arange(k, dtype=np.int64)
+        cnt = np.bincount(pos - p0, minlength=n - p0 + 1)
+        self.moved = (np.arange(p0, n, dtype=np.int64)
+                      + np.cumsum(cnt[:n - p0]))
+
+    @classmethod
+    def append(cls, n: int, k: int) -> "RowSplice":
+        """k rows after the n resident ones."""
+        return cls(n, np.full(k, n, np.int64))
+
+    @property
+    def tail(self) -> bool:
+        return self.p0 == self.n
+
+    @property
+    def n_moved(self) -> int:
+        """Resident rows that land at a new index."""
+        return self.n - self.p0
+
+    def map(self, rows):
+        """Resident row indices (or references, whose negative sentinels
+        pass through) to their indices after the splice. Returns ``rows``
+        itself where nothing moves: do not write into the result."""
+        rows = np.asarray(rows)
+        if self.tail or not len(rows):
+            return rows
+        hit = np.flatnonzero(rows >= self.p0)
+        if not len(hit):
+            return rows
+        out = rows.copy()
+        out[hit] = self.moved[rows[hit] - self.p0]
+        return out
+
+    def into(self, out, old, new) -> None:
+        """Write the merged rows into ``out`` (length n + k): ``old`` the n
+        resident values, ``new`` the k inserted ones or one fill value."""
+        p0 = self.p0
+        out[:p0] = old[:p0]
+        if self.tail:
+            out[p0:] = new
+        else:
+            out[self.moved] = old[p0:]
+            out[self.new_rows] = new
+
+
+def count_splice(actors_changed: bool, tail: bool, moved: int) -> None:
+    """Count one row-carrying splice: ``oplog.splices{mode}`` (``remap``
+    where new actors rewrote every packed key, else ``tail`` or
+    ``suffix``) and the resident rows it moved
+    (``oplog.splice_rows_moved``)."""
+    from .. import obs
+
+    mode = "remap" if actors_changed else "tail" if tail else "suffix"
+    obs.count("oplog.splices", labels={"mode": mode})
+    obs.count("oplog.splice_rows_moved", n=moved)
 
 
 class AppendInfo:
     """What an in-place ``OpLog.append_changes`` did — everything a resident
     consumer (DeviceDoc) needs to splice its own row-indexed state.
 
-    ``row_map`` maps old row index -> new row index (None = identity, the
-    tail-append fast path); ``new_rows`` are the spliced rows' positions;
-    ``dirty_objs`` are the dense object ids (NEW numbering) whose resolution
-    is stale; ``obj_remap`` maps old dense ids -> new (None = identity)."""
+    ``splice`` says where the rows went (``RowSplice``; ``map_rows`` moves
+    resident row ids through it); ``new_rows`` are the spliced rows'
+    positions; ``dirty_objs`` are the dense object ids (NEW numbering)
+    whose resolution is stale; ``obj_remap`` maps old dense ids -> new
+    (None = identity)."""
 
     __slots__ = (
-        "n_old", "n_new", "new_rows", "row_map", "tail", "dirty_objs",
+        "splice", "n_old", "n_new", "new_rows", "tail", "dirty_objs",
         "obj_remap", "actors_changed", "n_changes", "n_pred_old",
         "rere_elem_rows", "rere_pred_edges",
     )
 
-    def __init__(self, n_old, n_new, new_rows, row_map, tail, dirty_objs,
-                 obj_remap, actors_changed, n_changes, n_pred_old=0,
-                 rere_elem_rows=None, rere_pred_edges=None):
-        self.n_old = n_old
-        self.n_new = n_new
-        self.new_rows = new_rows
-        self.row_map = row_map
-        self.tail = tail
+    def __init__(self, splice, dirty_objs, obj_remap, actors_changed,
+                 n_changes, n_pred_old=0, rere_elem_rows=None,
+                 rere_pred_edges=None):
+        self.splice = splice
+        self.n_old = splice.n
+        self.n_new = len(splice.new_rows)
+        self.new_rows = splice.new_rows
+        self.tail = splice.tail
         self.dirty_objs = dirty_objs
         self.obj_remap = obj_remap
         self.actors_changed = actors_changed
@@ -1123,6 +1186,10 @@ class AppendInfo:
             rere_pred_edges if rere_pred_edges is not None
             else np.empty(0, np.int64)
         )
+
+    def map_rows(self, rows):
+        """Row ids taken before the append, in the spliced numbering."""
+        return self.splice.map(rows)
 
 
 def _merge_table(old: List[str], delta_table, ids, order) -> Tuple[List[str], np.ndarray]:
