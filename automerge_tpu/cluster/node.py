@@ -744,11 +744,6 @@ class ClusterNode(SocketRpcServer):
             return None  # rpc.handle answers it NotLeader
         return super()._coalesce_key(req)
 
-    def _coalesce_single(self, method) -> bool:
-        if method == "replApply":
-            return True
-        return super()._coalesce_single(method)
-
     def _run_coalesced(self, run, out) -> None:
         if run[0][1].get("method") == "replApply":
             self._run_repl_apply(run, out)
@@ -823,8 +818,7 @@ class ClusterNode(SocketRpcServer):
         acked; it is authoritative): a mirror whose feed errored is
         dropped and rebuilt on its next use instead of serving stale
         reads."""
-        from ..ops import host_batch
-        from ..ops.batched import resolve_stages
+        from ..ops.batched import stage_and_resolve
 
         try:
             docs = {}
@@ -838,17 +832,13 @@ class ClusterNode(SocketRpcServer):
                     key=lambda d: str(getattr(d, "path", "")),
                 ):
                     st.enter_context(doc.lock)
-                stages, results = host_batch.stage_docs(
+                results, _ = stage_and_resolve(
                     [(dev, b) for _doc, dev, b in feeds]
                 )
                 bad = {
                     key for key, r in results.items()
                     if r.error is not None
                 }
-                if stages:
-                    resolve_stages(
-                        [s for s in stages if id(s.doc) not in bad]
-                    )
                 # one error/drop per DOCUMENT: feeds holds one entry per
                 # coalesced frame, and a 10-frame doc must not count 10
                 # errors or drop its mirror 10 times

@@ -9,7 +9,7 @@ themselves — crashes, partitions, ENOSPC, overload. This module detects
   digest. The accumulator is the XOR of every committed change's 32-byte
   chunk hash, which makes it order-independent by construction (the same
   change set produces the same digest across merge orders, replication
-  interleavings, and dense / compressed / run-native residency — the
+  interleavings, and dense / compressed residency — the
   digest is a function of history, not representation) and O(1) to
   maintain incrementally: ``DurableDocument`` folds each change's hash
   in as it enters history and only recomputes on open. The exposed
@@ -26,8 +26,8 @@ themselves — crashes, partitions, ENOSPC, overload. This module detects
   changes, CRDT merge is a union). A replica that re-diverges after a
   repair is quarantined: dropped from the ack-gate quorum
   (``cluster.quarantined`` gauge) rather than silently re-trusted.
-* **Device-mirror audit** — sampled spot-checks of the compressed /
-  run-native resident image against the dense host oracle
+* **Device-mirror audit** — sampled spot-checks of the compressed
+  resident image against the dense host oracle
   (``CompressedOpColumns.verify_against``); a mismatch drops the mirror
   for rebuild (``device.mirror_divergence``) instead of serving corrupt
   reads.

@@ -93,11 +93,6 @@ STAGES: Dict[str, tuple] = {
     "device.extract": ("extract", "host"),
     "device.pack": ("pack", "device"),
     "device.h2d": ("h2d", "device"),
-    # the eager run-table expansion dispatch (merge.stage_cols_device's
-    # _expander) — its own row so the run-native kernels' win (expansion
-    # fused INTO the kernel, this stage -> 0) is visible, not folded
-    # into h2d
-    "device.expand": ("expand", "device"),
     # the asynchronous enqueue of a launch; the host's wait for its
     # results is device.wait, inside device.readback
     "device.kernel": ("kernel", "device"),
@@ -689,8 +684,8 @@ def render_text(summary: dict, top: Optional[int] = None) -> str:
             f"extract cache: {100.0 * ec['cache_hit_ratio']:.1f}% hits "
             f"({ec.get('hits', 0)}/{ec.get('hits', 0) + ec.get('misses', 0)})"
         )
-    # h2d byte accounting: what the compressed staging actually moved vs
-    # its dense equivalent (ops/compressed.py / merge.stage_cols_device)
+    # h2d byte accounting: what the packed transport's run tables
+    # actually moved vs their dense equivalent (merge.encode_transport)
     hb = summary.get("h2d_bytes", 0)
     if hb:
         ratio = summary.get("h2d_compress_ratio")

@@ -20,20 +20,23 @@ subset launch would, bit for bit (asserted by tests/test_batched_merge).
 Rows stay ascending within each document, preserving the "max row = max
 Lamport" winner rule.
 
-Two entry points:
+Every device feed stages and resolves through ``stage_and_resolve``:
+``host_batch.stage_docs`` over the drained documents, then
+``resolve_stages`` over their stages, on every backend. Its callers:
 
-* ``apply_cross_doc(work)`` — synchronous: stage every document's
-  drained batches (``DeviceDoc.stage_batches``), resolve them in shared
-  launches. The bench / CI driver.
 * ``CrossDocBatcher`` — the serving-layer collector: workers draining
   different documents submit concurrently; the first submitter of a
   generation becomes the flush leader, waits a tiny window
   (``AUTOMERGE_TPU_BATCH_WINDOW_MS``) for co-arriving documents, then
-  packs and launches once for everyone (the group-commit pattern the
+  stages and launches once for everyone (the group-commit pattern the
   journal fsync combiner already uses). Submitters hold their document
   lock while waiting, so per-doc single-writer discipline is preserved:
   nothing else can touch a document between its host-side stage and the
   scatter of its kernel results.
+* ``DeviceDoc.apply_batches`` — a generation of one document.
+* ``apply_cross_doc(work)`` — synchronous, for a known set of
+  documents. The bench / CI driver.
+* the cluster follower's ``replApply`` drain (cluster/node.py).
 
 A stage is one of two kinds. A *subset* stage covers the dirty
 objects' rows. A *full* stage covers every row and object of its
@@ -101,7 +104,7 @@ class BatchStage:
     scatter. ``full`` says the stage covers every row and object of the
     document (``full_stage``)."""
 
-    __slots__ = ("doc", "rows", "dirty", "full", "error", "trace")
+    __slots__ = ("doc", "rows", "dirty", "full", "trace")
 
     def __init__(self, doc, rows: np.ndarray, dirty: np.ndarray,
                  full: bool = False):
@@ -109,7 +112,6 @@ class BatchStage:
         self.rows = rows
         self.dirty = dirty
         self.full = full
-        self.error: Optional[BaseException] = None
         # the submitting request's trace context (trace_id, span_id), so
         # the shared launch span can link back to every request it served
         self.trace = None
@@ -183,8 +185,7 @@ def _pack(stages: Sequence[BatchStage]):
 def _dispatch_packed(cols, n_objs: int, n_props: int):
     """The host + dispatch half of a packed launch: pad, stage plain
     columns, dispatch the kernel WITHOUT reading back, and rank element
-    order host-side while it flies — exactly like the per-doc dispatch
-    (DeviceDoc._dispatch_async). Returns an in-flight handle for
+    order host-side while it flies. Returns an in-flight handle for
     ``_collect_packed``."""
     from .merge import prepare_resolution
     from .oplog import host_linearize, pad_columns
@@ -209,7 +210,7 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
     # follows the documents that happen to drain together, and would
     # pick the program; plain columns leave it to the bucketed shapes,
     # so a set-up that met a launch's shapes has compiled its program
-    dispatch = prepare_resolution(cols, n_objs, n_props, runs=False)
+    dispatch = prepare_resolution(cols, n_objs, n_props)
     out = dispatch()  # async dispatch
     with obs.span("device.linearize", rows=P):
         ei = host_linearize(cols)
@@ -217,8 +218,9 @@ def _dispatch_packed(cols, n_objs: int, n_props: int):
 
 
 def _collect_packed(handle):
-    """The blocking half of a packed launch: read the resolution back."""
-    from .merge import readback
+    """The blocking half of a packed launch: read the resolution back
+    (``conflicts`` as ``merge.conflict_flags``)."""
+    from .merge import conflict_flags, readback
 
     with readback(handle["out"], handle["P"]):
         res = {
@@ -226,14 +228,9 @@ def _collect_packed(handle):
             for k in ("visible", "winner", "conflicts",
                       "obj_vis_len", "obj_text_width")
         }
+    res["conflicts"] = conflict_flags(res["conflicts"])
     res["elem_index"] = handle["ei"]
     return res
-
-
-def _launch_packed(cols, n_objs: int, n_props: int):
-    """One kernel launch over the padded super-batch; element order is
-    ranked host-side overlapped with the kernel."""
-    return _collect_packed(_dispatch_packed(cols, n_objs, n_props))
 
 
 def _scatter(metas, res) -> None:
@@ -314,11 +311,44 @@ def resolve_stages(
     return collect_stages(dispatch_stages(stages, fallback_ratio))
 
 
+def stage_and_resolve(
+    work,
+    *,
+    fallback_ratio: Optional[float] = None,
+    max_docs_per_launch: Optional[int] = None,
+    traces: Optional[dict] = None,
+):
+    """Stage ``work`` — ``(device_doc, batches)`` pairs, duplicates
+    merged — through ``host_batch.stage_docs`` and resolve the stages
+    with ``resolve_stages``, at most ``max_docs_per_launch`` of them a
+    launch (None = all in one). ``traces`` maps ``id(device_doc)`` to
+    the trace context the shared launch span links back to.
+
+    Returns ``(results, resolved)``: the per-document
+    ``host_batch.DocResult`` keyed by ``id(device_doc)`` (a document
+    whose staging failed carries its error and has no stage), and
+    ``{"batched": n_docs, "fallback": n_docs}``. A failed launch
+    raises."""
+    from . import host_batch
+
+    stages, results = host_batch.stage_docs(work)
+    if traces:
+        for st in stages:
+            st.trace = traces.get(id(st.doc))
+    resolved = {"batched": 0, "fallback": 0}
+    step = max_docs_per_launch or len(stages) or 1
+    for lo in range(0, len(stages), step):
+        r = resolve_stages(stages[lo : lo + step], fallback_ratio)
+        resolved["batched"] += r["batched"]
+        resolved["fallback"] += r["fallback"]
+    return results, resolved
+
+
 def pipeline_enabled() -> bool:
-    """Whether the drain double-buffers: chunk N's packed kernel flies
-    while chunk N+1 runs its host pack/sort/splice. Host seconds spent
-    under an in-flight launch are noted as ``overlap_s`` and surface as
-    ``drain.overlap_fraction``."""
+    """Whether ``apply_cross_doc``'s chunked drain double-buffers: chunk
+    N's packed kernel flies while chunk N+1 runs its host staging. Host
+    seconds spent under an in-flight launch are noted as ``overlap_s``
+    and surface as ``drain.overlap_fraction``."""
     return os.environ.get("AUTOMERGE_TPU_DRAIN_PIPELINE", "1") != "0"
 
 
@@ -333,7 +363,9 @@ def apply_cross_doc(
     ``(device_doc, batches)`` pairs (``batches`` = a sequence of change
     batches, as ``apply_batches`` takes). Stages every document
     host-side, then resolves the stages in shared packed launches of at
-    most ``max_docs_per_launch`` documents (None = all in one).
+    most ``max_docs_per_launch`` documents (None = all in one). The
+    first document whose staging failed raises its error once the
+    others are resolved.
 
     Returns {"applied": total changes, "batched": docs resolved in
     packed launches, "fallback": docs resolved per-doc}.
@@ -347,11 +379,13 @@ def apply_cross_doc(
     Host seconds spent under an in-flight launch are noted as
     ``overlap_s`` → ``drain.overlap_fraction``.
     """
+    from . import host_batch
+
     # the same DeviceDoc may appear several times in ``work``; its
     # batches must merge into ONE staging — a later append splices the
     # log and would silently invalidate an earlier stage's row/object
-    # indices (apply_batches remaps its in-flight handle for exactly
-    # this; the stage path merges up front instead)
+    # indices. stage_docs merges within a call; merging up front keeps
+    # each document inside one chunk of the pipelined drain
     merged: dict = {}
     order: List[int] = []
     for dev, batches in work:
@@ -362,61 +396,34 @@ def apply_cross_doc(
             merged[k] = (dev, list(batches))
             order.append(k)
 
-    from . import host_batch
-
-    def _stage_chunk(keys, idx0):
-        """Stage one chunk of documents host-side; returns
-        (stages, applied). Self-contained per call — host_batch.stage_docs
-        dedups within the call and the chunks are disjoint documents."""
-        applied = 0
-        stages: List[BatchStage] = []
-        if host_batch.enabled():
-            # the vectorized cross-doc staging: dedup/causal-order/
-            # extract/Lamport-sort/splice run as shared columnar passes
-            # with per-doc offset ranges; ineligible documents stage
-            # through the scalar path inside (host_batch.stage_docs
-            # merges duplicates itself, but the merge above also backs
-            # the scalar branch below)
-            stages, results = host_batch.stage_docs(
-                [merged[k] for k in keys]
-            )
-            for r in results.values():
-                if r.error is not None:
-                    raise r.error
-                applied += r.applied
-        else:
-            for i, k in enumerate(keys):
-                dev, batches = merged[k]
-                t0 = time.perf_counter()
-                n, st = dev.stage_batches(batches)
-                _prof.note_doc(
-                    getattr(dev, "obs_name", None) or f"doc{idx0 + i}",
-                    time.perf_counter() - t0,
-                )
-                applied += n
-                if st is not None:
-                    stages.append(st)
-        return stages, applied
-
     out = {"applied": 0, "batched": 0, "fallback": 0}
 
     def _account(r):
         out["batched"] += r["batched"]
         out["fallback"] += r["fallback"]
 
+    def _applied(results):
+        out["applied"] += sum(r.applied for r in results.values())
+        return next((r.error for r in results.values()
+                     if r.error is not None), None)
+
     if pipeline is None:
         pipeline = pipeline_enabled()
     step = max_docs_per_launch or len(order) or 1
+    error = None
     if pipeline and len(order) > step:
         # double-buffered drain: chunk the WORK (not the stages) so each
         # chunk's host staging runs while the previous chunk's packed
-        # kernel is in flight
+        # kernel is in flight; each stage_docs call is self-contained
+        # (dedup, actor table and offsets are per call)
         pending = None
         try:
             for lo in range(0, len(order), step):
                 t0 = time.perf_counter()
-                stages, n = _stage_chunk(order[lo : lo + step], lo)
-                out["applied"] += n
+                stages, results = host_batch.stage_docs(
+                    [merged[k] for k in order[lo : lo + step]]
+                )
+                error = error or _applied(results)
                 d = dispatch_stages(stages, fallback_ratio)
                 if pending is not None:
                     # everything since the loop top ran under pending's
@@ -432,13 +439,16 @@ def apply_cross_doc(
         if pending is not None:
             _account(collect_stages(pending))
     else:
-        stages, n = _stage_chunk(order, 0)
-        out["applied"] += n
-        sstep = max_docs_per_launch or len(stages) or 1
-        for lo in range(0, len(stages), sstep):
-            _account(resolve_stages(stages[lo : lo + sstep], fallback_ratio))
+        results, resolved = stage_and_resolve(
+            [merged[k] for k in order], fallback_ratio=fallback_ratio,
+            max_docs_per_launch=max_docs_per_launch,
+        )
+        error = _applied(results)
+        _account(resolved)
     _prof.note("docs", len(order))
     _prof.note("changes", out["applied"])
+    if error is not None:
+        raise error
     return out
 
 
@@ -446,10 +456,9 @@ def apply_cross_doc(
 
 
 class _Submission:
-    """One document's raw drained batches awaiting the leader-staged
-    vectorized flush (host_batch mode): the submitter keeps holding its
-    document lock while the flush leader stages every co-arriving
-    document in one columnar pass."""
+    """One document's raw drained batches awaiting its generation's
+    flush: the submitter keeps holding its document lock while the flush
+    leader stages every co-arriving document in one columnar pass."""
 
     __slots__ = ("dev", "batches", "trace", "applied", "error")
 
@@ -462,23 +471,22 @@ class _Submission:
 
 
 class _Generation:
-    __slots__ = ("stages", "subs", "done")
+    __slots__ = ("subs", "done")
 
     def __init__(self):
-        self.stages: List[BatchStage] = []  # scalar (submitter-staged)
-        self.subs: List[_Submission] = []  # vectorized (leader-staged)
+        self.subs: List[_Submission] = []
         self.done = threading.Event()
 
 
 class CrossDocBatcher:
     """Group-commit collector for concurrent per-document workers.
 
-    ``apply(dev, batches)`` stages the document's drained device feed
-    (the caller MUST hold that document's execution lock) and blocks
-    until a shared launch has resolved it. The first stager of a
-    generation is the leader: it waits ``window_ms`` for co-arriving
-    documents (waking early at ``max_docs``), closes the generation, and
-    runs ``resolve_stages`` for everyone.
+    ``apply(dev, batches)`` hands the document's drained device feed
+    (the caller MUST hold that document's execution lock) to the next
+    shared launch and blocks until it has resolved. The first submitter
+    of a generation is the leader: it waits ``window_ms`` for
+    co-arriving documents (waking early at ``max_docs``), closes the
+    generation, and runs ``stage_and_resolve`` for everyone.
 
     ``in_flight``, where the caller sets it (the serving layer passes its
     shard pool's), counts the drains running or ready to run. Once the
@@ -488,11 +496,6 @@ class CrossDocBatcher:
     fill instead of closing on whichever drain happened to come first.
     With no other drain in flight the leader closes at the window, as
     without ``in_flight``.
-
-    ``mode``: "1" always batches, "0" never (callers fall back to
-    ``apply_batches``), "auto" batches only on accelerator backends —
-    on CPU the per-doc host delta-resolution path is faster than any
-    kernel, packed or not.
     """
 
     def __init__(
@@ -501,7 +504,6 @@ class CrossDocBatcher:
         window_ms: Optional[float] = None,
         max_docs: Optional[int] = None,
         fallback_ratio: Optional[float] = None,
-        mode: Optional[str] = None,
     ):
         self.window = (
             window_ms
@@ -514,92 +516,24 @@ class CrossDocBatcher:
             else _env_float("AUTOMERGE_TPU_BATCH_DOCS", 32)
         )
         self.fallback_ratio = fallback_ratio
-        self.mode = (
-            mode
-            if mode is not None
-            else os.environ.get("AUTOMERGE_TPU_SERVE_BATCHED", "auto")
-        )
-        # generations at least this many docs wide flush as TWO
-        # half-launches so the second half's pack/linearize runs under
-        # the first half's in-flight kernel (the drain pipeline); small
-        # generations keep the single launch — splitting them would
-        # trade kernel occupancy for overlap that can't cover the cost
-        self.pipeline_min_docs = int(
-            _env_float("AUTOMERGE_TPU_PIPELINE_MIN_DOCS", 16)
-        )
         self._cv = threading.Condition(threading.Lock())
         self._gen = _Generation()
-        self._active: Optional[bool] = None
         self.in_flight: Optional[Callable[[], int]] = None
 
-    def active(self) -> bool:
-        """Whether device feeds should route through this batcher."""
-        if self._active is None:
-            if self.mode == "0":
-                self._active = False
-            elif self.mode == "auto":
-                import jax
-
-                self._active = jax.default_backend() != "cpu"
-            else:
-                self._active = True
-        return self._active
-
     def apply(self, dev, batches) -> int:
-        """Stage ``dev``'s drained batches and resolve them in the next
-        shared launch; blocks until resolved. Returns changes applied.
+        """Resolve ``dev``'s drained batches in the next shared launch;
+        blocks until resolved. Returns changes applied.
 
-        With the vectorized host staging active (the default,
-        ``AUTOMERGE_TPU_HOST_BATCH``), the submitter hands its RAW
-        batches over and the generation's flush leader stages every
-        co-arriving document in one shared columnar pass
-        (host_batch.stage_docs) before the shared kernel launch — the
-        submitter keeps holding its document lock while it waits, so the
-        single-writer discipline is unchanged. With the knob off, each
-        submitter stages its own document (the scalar per-doc path) and
-        only the launch is shared, exactly as before."""
-        if not self.active():
-            return dev.apply_batches(batches)
-        from . import host_batch
-
-        if host_batch.enabled():
-            return self._apply_leader_staged(dev, batches)
-        t0 = time.perf_counter()
-        applied, stage = dev.stage_batches(batches)
-        _prof.note("docs")
-        _prof.note("changes", applied)
-        _prof.note_doc(
-            getattr(dev, "obs_name", None), time.perf_counter() - t0
-        )
-        if stage is None:
-            return applied
-        # attribute the (possibly other-thread) shared launch back to
-        # this submitter's propagated trace, if one is active
-        stage.trace = obs.current_trace_context()
-        with self._cv:
-            gen = self._gen
-            gen.stages.append(stage)
-            # leadership is elected over BOTH submission kinds: a
-            # mid-generation AUTOMERGE_TPU_HOST_BATCH flip can mix
-            # leader-staged subs and submitter-staged stages in one
-            # generation, and exactly ONE leader must flush it
-            leader = len(gen.stages) + len(gen.subs) == 1
-            if not leader:
-                self._cv.notify_all()  # the leader counts the arrivals
-        if leader:
-            self._lead(gen)
-        else:
-            gen.done.wait()
-        if stage.error is not None:
-            raise stage.error
-        return applied
-
-    def _apply_leader_staged(self, dev, batches) -> int:
+        The submitter hands its RAW batches over and the generation's
+        flush leader stages every co-arriving document in one shared
+        columnar pass (host_batch.stage_docs) before the shared kernel
+        launch — the submitter keeps holding its document lock while it
+        waits, so the single-writer discipline holds."""
         sub = _Submission(dev, list(batches), obs.current_trace_context())
         with self._cv:
             gen = self._gen
             gen.subs.append(sub)
-            leader = len(gen.stages) + len(gen.subs) == 1
+            leader = len(gen.subs) == 1
             if not leader:
                 self._cv.notify_all()  # the leader counts the arrivals
         if leader:
@@ -621,7 +555,7 @@ class CrossDocBatcher:
         with obs.span("device.batch_window") as sp:
             with self._cv:
                 while True:
-                    docs = len(gen.stages) + len(gen.subs)
+                    docs = len(gen.subs)
                     waited = time.monotonic() - t0
                     if docs >= self.max_docs:
                         full = True
@@ -643,65 +577,33 @@ class CrossDocBatcher:
         self._flush(gen)
 
     def _flush(self, gen: _Generation) -> None:
-        """Close one generation: stage any leader-staged submissions in
-        one vectorized pass (host_batch.stage_docs), merge them with any
-        submitter-staged stages (the scalar-knob mode — an env-knob flip
-        mid-generation can mix the two; both drain here), launch once,
-        and release every waiter. A failure reaches every waiter."""
-        from . import host_batch
-
-        stages: List[BatchStage] = list(gen.stages)
+        """Close one generation: stage every submission in one
+        vectorized pass, launch once (``stage_and_resolve``), and release
+        every waiter. A failure reaches every waiter."""
         try:
-            if gen.subs:
-                more, results = host_batch.stage_docs(
-                    [(s.dev, s.batches) for s in gen.subs]
-                )
-                trace_of = {}
-                n_changes = 0
-                for s in gen.subs:
-                    r = results.get(id(s.dev))
-                    if r is not None:
-                        s.applied = r.applied
-                        s.error = r.error
-                        n_changes += r.applied
-                    if s.trace is not None:
-                        trace_of.setdefault(id(s.dev), s.trace)
-                for st in more:
-                    st.trace = trace_of.get(id(st.doc))
-                _prof.note("docs", len(gen.subs))
-                _prof.note("changes", n_changes)
-                stages.extend(more)
-            if (
-                pipeline_enabled()
-                and len(stages) >= self.pipeline_min_docs
-            ):
-                # wide generation: flush as two half-launches so the
-                # second half's pack/linearize runs under the first
-                # half's in-flight kernel (drain.overlap_fraction)
-                mid = len(stages) // 2
-                d1 = dispatch_stages(stages[:mid], self.fallback_ratio)
-                try:
-                    t0 = time.perf_counter()
-                    d2 = dispatch_stages(
-                        stages[mid:], self.fallback_ratio
-                    )
-                    _prof.note("overlap_s", time.perf_counter() - t0)
-                except BaseException:
-                    collect_stages(d1)
-                    raise
-                collect_stages(d1)
-                collect_stages(d2)
-            else:
-                resolve_stages(stages, self.fallback_ratio)
+            traces = {}
+            for s in gen.subs:
+                if s.trace is not None:
+                    traces.setdefault(id(s.dev), s.trace)
+            results, _ = stage_and_resolve(
+                [(s.dev, s.batches) for s in gen.subs],
+                fallback_ratio=self.fallback_ratio, traces=traces,
+            )
+            n_changes = 0
+            for s in gen.subs:
+                r = results.get(id(s.dev))
+                if r is not None:
+                    s.applied = r.applied
+                    s.error = r.error
+                    n_changes += r.applied
+            _prof.note("docs", len(gen.subs))
+            _prof.note("changes", n_changes)
         except BaseException as e:
             # every document of the generation sees the failure: its
             # changes were spliced but never resolved, so its caller must
             # not acknowledge the device work
             obs.count("device.batched_error")
             obs.event("device.batched_error", error=str(e)[:200])
-            for st in stages:
-                if st.error is None:
-                    st.error = e
             for s in gen.subs:
                 if s.error is None:
                     s.error = e

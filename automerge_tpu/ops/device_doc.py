@@ -18,7 +18,6 @@ materialized views / ``apply_patches`` without a host re-apply
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,7 +37,7 @@ from ..patches.patch import (
     SpliceText,
 )
 from ..types import ObjType, is_make_action, objtype_for_action
-from .merge import merge_columns, readback
+from .merge import conflict_flags, merge_columns
 from .oplog import (
     ELEM_HEAD, ELEM_MISSING, MAKE_ACTIONS, ACTOR_BITS, OpLog, RowSplice,
     TAG_COUNTER,
@@ -241,12 +240,11 @@ class DeviceDoc:
         return len(ready)
 
     def apply_batches(self, batches: Sequence[Sequence]) -> int:
-        """Pipelined variant for a stream of delta batches: on accelerator
-        backends batch k+1's host-side append and h2d staging overlap
-        batch k's in-flight kernel (double-buffered; readback of batch k
-        happens only after batch k+1 is dispatched). On the CPU backend
-        this degrades to sequential ``apply_changes`` calls."""
-        import jax
+        """Integrate a stream of delta batches as a cross-document
+        generation of one (``batched.stage_and_resolve``): the same
+        staging and launch the serving layer's batcher runs, on every
+        backend. Returns the number of changes integrated."""
+        from .batched import stage_and_resolve
 
         if self._base is not self:
             raise ValueError("apply_batches on a historical view; use the base doc")
@@ -254,63 +252,11 @@ class DeviceDoc:
             # the serving layer's sync coalescing lands here: how many
             # per-message applies each drain amortized is the signal
             obs.count("device.coalesced_batches", n=len(batches))
-        if jax.default_backend() == "cpu":
-            return sum(self.apply_changes(b) for b in batches)
-        total = 0
-        inflight = None
-        t_buf = 0.0  # host work start while a handle was in flight
-
-        def collect_inflight():
-            # host seconds since the loop-top while this handle's kernel
-            # was in flight are pipeline overlap — the drain's measurable
-            # double-buffering win (prof: drain.overlap_fraction)
-            _prof.note("overlap_s", time.perf_counter() - t_buf)
-            self._collect_async(inflight)
-
-        for chs in batches:
-            if inflight is not None:
-                t_buf = time.perf_counter()
-            ready = self._take_ready(chs)
-            if not ready:
-                continue
-            if self.log.n:
-                with obs.span("device.stage.splice", changes=len(ready)):
-                    info = self.log.append_changes(ready)
-            else:
-                info = None
-            if info is None:
-                if inflight is not None:
-                    collect_inflight()
-                    inflight = None
-                obs.count("device.apply_rebuild")
-                self._rebuild(list(self.log.changes) + ready)
-                total += len(ready)
-                continue
-            if inflight is not None:
-                # the in-flight handle's row/object ids move with the splice
-                inflight["rows"] = info.map_rows(inflight["rows"])
-                if info.obj_remap is not None:
-                    inflight["dirty"] = info.obj_remap[inflight["dirty"]]
-            self._apply_append(info, ready)
-            if info.n_new:
-                handle = self._dispatch_async(info.dirty_objs)
-                if handle is not None and handle.get("fallback"):
-                    # cost-model fallback resolves synchronously over the
-                    # CURRENT log — anything still in flight was computed
-                    # from an older snapshot and must land first
-                    if inflight is not None:
-                        collect_inflight()
-                        inflight = None
-                    self._reresolve(info.dirty_objs)
-                else:
-                    if inflight is not None:
-                        collect_inflight()
-                    inflight = handle
-            total += len(ready)
-        if inflight is not None:
-            self._collect_async(inflight)
-        self._export_doc_gauges()
-        return total
+        results, _ = stage_and_resolve([(self, batches)])
+        r = results[id(self)]
+        if r.error is not None:
+            raise r.error
+        return r.applied
 
     def stage_batches(self, batches: Sequence[Sequence]):
         """Host-side half of the cross-document batched apply
@@ -749,7 +695,7 @@ class DeviceDoc:
             old_w = np.where(old_len, w[np.clip(wold, 0, m - 1)], 0)
 
             self.winner[rr] = win[seg]
-            self.conflicts[rr] = cnt[seg]
+            self.conflicts[rr] = conflict_flags(cnt[seg])
 
             wnew = self.winner[el_rows]
             new_len = wnew >= 0
@@ -979,61 +925,6 @@ class DeviceDoc:
         with obs.span("device.scatter", rows=len(rows)):
             self._scatter_subset(rows, dirty, res_sub)
 
-    # staged async subset resolution (apply_batches) --------------------------
-
-    def _dispatch_async(self, dirty):
-        """Stage one dirty-set resolution on the accelerator WITHOUT reading
-        back: h2d (device_put) and the kernel dispatch are asynchronous, and
-        document ordering runs host-side (host_linearize) while the kernel
-        is in flight. Returns a handle for _collect_async, None when there
-        is nothing to resolve, or ``{"fallback": True}`` when the dirty
-        fraction demands a synchronous full re-resolution (which the caller
-        runs AFTER draining any in-flight batch)."""
-        from .merge import prepare_resolution
-        from .oplog import host_linearize, pad_columns
-
-        log = self.log
-        dirty = np.asarray(dirty, np.int64)
-        if log.n == 0 or not len(dirty):
-            return None
-        rows = self._subset_rows(dirty)
-        if self._resolves_whole(rows, dirty):
-            # the caller must drain any in-flight batch BEFORE resolving
-            # synchronously, or its stale results would overwrite ours
-            return {"fallback": True}
-        D = len(dirty)
-        cols_np = pad_columns(self._subset_cols(rows, dirty), D)
-        P = len(cols_np["action"])
-        # staging: run-native mode hands the kernel the run tables
-        # themselves; otherwise device_put moves run tables and the
-        # expansion dispatch runs eagerly (merge.stage_cols_device)
-        dispatch = prepare_resolution(cols_np, D, len(log.props))
-        obs.count("device.kernel_launches", labels={"path": "per_doc"})
-        _prof.note("launches")
-        out = dispatch()  # async dispatch
-        # element order overlaps the kernel — it needs only the columns
-        with obs.span("device.linearize", rows=P):
-            ei = host_linearize(cols_np)
-        return {"rows": rows, "dirty": dirty, "out": out, "ei": ei}
-
-    def _collect_async(self, handle) -> None:
-        if handle is None:
-            return
-        out = handle["out"]
-        S = len(handle["rows"])
-        D = len(handle["dirty"])
-        with readback(out, S):
-            res_sub = {
-                "visible": np.asarray(out["visible"]),
-                "winner": np.asarray(out["winner"]),
-                "conflicts": np.asarray(out["conflicts"]),
-                "elem_index": handle["ei"],
-                "obj_vis_len": np.asarray(out["obj_vis_len"]),
-                "obj_text_width": np.asarray(out["obj_text_width"]),
-            }
-        with obs.span("device.scatter", rows=S):
-            self._scatter_subset(handle["rows"], handle["dirty"], res_sub)
-
     # -- whale-doc mesh residency (parallel/sharding.py) ---------------------
     #
     # Opt-in: full-log re-resolutions of a document too big for one chip
@@ -1160,6 +1051,7 @@ class DeviceDoc:
                 o = np.empty(m, a.dtype)
                 o[perm] = a
                 res[k] = o
+            res["conflicts"] = conflict_flags(res["conflicts"])
             w = np.asarray(out["winner"])[:m]
             w_o = np.where(w >= 0, perm[np.clip(w, 0, m - 1)], -1)
             wo = np.empty(m, np.int32)

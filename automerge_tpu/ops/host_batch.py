@@ -43,15 +43,14 @@ path, which stays both the fallback and the differential oracle
 (tests/test_host_batch.py asserts column-level OpLog equality and
 identical materialized documents between the two).
 
-Feed points: ``ops/batched.apply_cross_doc`` (the bench/CI driver),
-``CrossDocBatcher`` (the serving drain — submitters hand raw batches to
-the flush leader, which stages every co-arriving document in one
-vectorized pass before the shared kernel launch), and the cluster
-follower apply path (``cluster/node.py`` drains coalesced ``replApply``
-runs through the same staging).
-
-Env: ``AUTOMERGE_TPU_HOST_BATCH=0`` forces the per-doc scalar path
-everywhere (the A/B and bisection knob).
+Feed point: ``ops/batched.stage_and_resolve``, which every device feed
+takes — ``CrossDocBatcher`` (the serving drain: submitters hand raw
+batches to the flush leader, which stages every co-arriving document in
+one vectorized pass before the shared kernel launch),
+``DeviceDoc.apply_batches`` (a generation of one), ``apply_cross_doc``
+(the bench/CI driver) and the cluster follower apply path
+(``cluster/node.py`` drains coalesced ``replApply`` runs through it).
+Its chunked pipeline calls ``stage_docs`` per chunk itself.
 
 Profiler stages: ``host_pack`` / ``host_sort`` / ``host_splice`` join
 the PR 11 taxonomy, so ``drain.attributed_fraction`` stays >= 0.9 on
@@ -110,13 +109,6 @@ def _tail_write(bufs: dict, name: str, old: np.ndarray, new, mm: int):
     nbuf[n:mm] = new
     bufs[name] = nbuf
     return nbuf[:mm]
-
-
-def enabled() -> bool:
-    """Whether the vectorized cross-doc staging path is active
-    (``AUTOMERGE_TPU_HOST_BATCH``, default on; ``0`` forces the per-doc
-    scalar path for A/B comparison and bisection)."""
-    return os.environ.get("AUTOMERGE_TPU_HOST_BATCH", "1") != "0"
 
 
 class _DocPlan:
@@ -298,7 +290,7 @@ def stage_docs(work) -> Tuple[List, Dict[int, DocResult]]:
         for key, dev, ready, label in entries:
             if not ready:
                 continue
-            plan = _admit(dev, ready, label) if enabled() else None
+            plan = _admit(dev, ready, label)
             if plan is not None:
                 vec.append(plan)
                 results[key].vectorized = True

@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +53,8 @@ NONE32 = -1
 
 # the names every resolution program is jitted under (its XLA module is
 # ``jit_<name>``): ``merge_kernel`` ranks element order on the device,
-# ``merge_kernel_core`` leaves it to the host. Every other program here
-# is named for what it does (``expand_runs``)
+# ``merge_kernel_core`` leaves it to the host. No other program is
+# jitted here
 RESOLUTION_PROGRAMS = ("merge_kernel", "merge_kernel_core")
 
 
@@ -734,6 +733,19 @@ def scatter_kernel_core(n_objs: int, n_props: int):
 # results nor any extra transfer. device_linearize remains for the
 # pure-device flow (multi-chip dry run, no native core).
 
+
+def conflict_flags(counts) -> np.ndarray:
+    """``DeviceDoc.conflicts``' one encoding: 2 where a key has more
+    than one visible op, else 1. The packed transport reads back only
+    that flag, bit-packed; every path that resolves rows for a
+    ``DeviceDoc`` (packed, the plain launch, the host delta resolution,
+    the mesh) stores its exact visible-op counts through here, so a
+    document compares exactly with its oracle whichever path resolved
+    it. Readers compare ``> 1``. The dict path of ``merge_columns``
+    still returns the exact count."""
+    return np.where(np.asarray(counts) > 1, np.int32(2), np.int32(1))
+
+
 _F_ACTION = 15
 _F_INSERT = 1 << 4
 _F_TAG_SHIFT = 5
@@ -759,14 +771,6 @@ def _flags_column(cols) -> np.ndarray:
         | (cols["value_tag"].astype(np.int32) << _F_TAG_SHIFT)
         | (cols["covered"].astype(np.int32) << 9)
     )
-
-
-def _run_cap(n: int) -> int:
-    """Run-table capacity of a column of ``n`` rows: the most runs
-    ``_slope_rle`` lets a column keep, bucketed. It follows the column's
-    length and not its data, so every launch of one shape stages run
-    tables of one shape and reuses one compiled program."""
-    return _capacity(max(n // 8, 15), 16)
 
 
 def _slope_rle(x: np.ndarray):
@@ -860,228 +864,6 @@ def _note_h2d(actual: int, dense: int) -> None:
     _prof.note("h2d_dense_bytes", dense)
 
 
-def stage_cols_device(cols_np, runs=True):
-    """Compressed H2D staging for the dict-path launch sites.
-
-    Per column: slope-RLE runs (the resident format's device image) are
-    ``device_put`` as (w, cum) run tables padded to run-capacity buckets
-    — so ``device_put`` moves compressed bytes, not dense int32 rows —
-    and expanded ON device by ``_expand_runs``, one dispatch per stack
-    (run eagerly so the jit kernel caches never churn on data-dependent
-    run shapes). A column whose run structure degenerates past the
-    ``_slope_rle`` gate ships dense (counted via
-    ``oplog.compress_fallback{column,reason=h2d}``).
-
-    Records actual bytes moved as ``bytes=`` on the ``device.h2d`` span
-    and on the ``device.h2d_bytes`` counter (dense-equivalent bytes ride
-    on ``device.h2d_dense_bytes`` so compression wins are a ratio, not a
-    guess). ``runs=False``, or ``AUTOMERGE_TPU_COMPRESSED=0`` everywhere,
-    gives the plain dense upload.
-    """
-    from . import compressed as _C
-
-    cols_np = {k: np.asarray(v) for k, v in cols_np.items()}
-    P = len(cols_np["action"])
-    dense_bytes = sum(v.nbytes for v in cols_np.values())
-    if not runs or not _C.enabled():
-        with obs.span("device.h2d", rows=P, bytes=dense_bytes):
-            dev = {k: jnp.asarray(v) for k, v in cols_np.items()}
-        _note_h2d(dense_bytes, dense_bytes)
-        return dev
-    dense = {}
-    groups = {}  # column length -> [(name, (w, cum, slope), is_bool)]
-    h2d_bytes = 0
-    for k, v in cols_np.items():
-        n = len(v)
-        enc = None
-        if n >= 32 and v.dtype in (np.int32, np.bool_):
-            enc = _slope_rle(v if v.dtype == np.int32 else v.astype(np.int32))
-            if enc is None:
-                obs.count("oplog.compress_fallback",
-                          labels={"column": k, "reason": "h2d"})
-        if enc is None:
-            dense[k] = v
-            h2d_bytes += v.nbytes
-        else:
-            groups.setdefault(n, []).append((k, enc, v.dtype == np.bool_))
-    # one stacked run table per column length (rows vs pred edges), so
-    # the whole expansion is ONE fused jit dispatch per group — eager
-    # per-column ops would pay ~50 dispatch overheads per launch
-    stacks = []
-    for n, cols in groups.items():
-        rcap = _run_cap(n)
-        K = len(cols)
-        W = np.zeros((K, rcap), np.int32)
-        C = np.full((K, rcap), np.int32(n), np.int32)
-        S = np.empty(K, np.int32)
-        for idx, (_, (w, cum, s), _) in enumerate(cols):
-            W[idx, : len(w)] = w
-            C[idx, : len(cum)] = cum
-            S[idx] = s
-        stacks.append((n, rcap, cols, W, C, S))
-        h2d_bytes += W.nbytes + C.nbytes + S.nbytes
-    with obs.span("device.h2d", rows=P, bytes=h2d_bytes):
-        out = {k: jnp.asarray(v) for k, v in dense.items()}
-        dev_stacks = [
-            (n, rcap, cols, jnp.asarray(W), jnp.asarray(C), jnp.asarray(S))
-            for n, rcap, cols, W, C, S in stacks
-        ]
-    # the eager run->dense expansion dispatch is its own profiler stage
-    # (device.expand): it is the exact work the run-native kernels fuse
-    # away, so the split must show it apart from the device_put h2d
-    if dev_stacks:
-        obs.count("device.runs_expanded",
-                  n=sum(len(cols) for _, _, cols, _, _, _ in dev_stacks),
-                  labels={"path": "eager"})
-        with obs.span("device.expand", rows=P, stacks=len(dev_stacks)):
-            for n, rcap, cols, W, C, S in dev_stacks:
-                bools = tuple(b for _, _, b in cols)
-                expanded = _expander(n, rcap, bools)(W, C, S)
-                for (k, _, _), col in zip(cols, expanded):
-                    out[k] = col
-    _note_h2d(h2d_bytes, dense_bytes)
-    return out
-
-
-_EXPAND_CACHE = {}
-
-
-def _expander(n, rcap, bools):
-    """Jit'd stacked run expansion: (K, rcap) run tables -> K dense
-    (n,) columns in one dispatch. Cache key is (bucketed) shapes plus
-    which outputs cast back to bool — slopes are dynamic inputs, so
-    data-dependent slope choices never churn the jit cache."""
-    key = (n, rcap, bools)
-    fn = _EXPAND_CACHE.get(key)
-    if fn is None:
-        def expand(W, C, S):
-            cols = _expand_runs(W, C, S, n)
-            return tuple(
-                cols[k].astype(jnp.bool_) if b else cols[k]
-                for k, b in enumerate(bools)
-            )
-
-        fn = _EXPAND_CACHE[key] = _program("expand_runs", expand)
-    return fn
-
-
-# -- run-native resolution ----------------------------------------------------
-#
-# stage_cols_device ships run tables but expands them to dense columns
-# EAGERLY (the device.expand dispatch) before the resolution kernel runs,
-# so kernel input bandwidth is dense again the moment resolution starts.
-# Run-native mode keeps the run tables as the KERNEL's input: the
-# expansion (_expand_runs: run steps scattered at their first rows, a
-# prefix sum, plus stride arithmetic) moves INSIDE the kernel jit, where
-# XLA fuses it into its consumers, so device input traffic for
-# run-eligible columns scales with run count, not history size (the
-# LSM-OPD compute-on-compressed argument, arXiv:2508.11862). Kernels are
-# specialized per column-encoding signature via control-flow duplication
-# (arXiv:2302.10098): pure-RLE stacks (every stride 0) expand without a
-# stride term, delta+RLE stacks add the dynamic one s*i, and a column
-# whose run structure degenerates past the resident ratio gate
-# (compressed.run_gate) ships dense, counted per column on
-# device.run_native_fallback{column,reason}.
-
-
-def run_native_enabled() -> bool:
-    """Whether resolution kernels consume run tables directly (default
-    on wherever compressed residency is). ``AUTOMERGE_TPU_RUN_NATIVE=0``
-    restores the eager-expansion staging; ``AUTOMERGE_TPU_COMPRESSED=0``
-    restores the fully dense differential oracle."""
-    from . import compressed as _C
-
-    return (
-        _C.enabled()
-        and os.environ.get("AUTOMERGE_TPU_RUN_NATIVE", "1") != "0"
-    )
-
-
-def stage_cols_run_native(cols_np):
-    """Run-native H2D staging: per column, slope-RLE run tables are
-    ``device_put`` padded to run-capacity buckets and STAY the kernel
-    input (no eager expansion dispatch). Returns ``(dense, stacks,
-    plan)``:
-
-    * ``dense`` — {name: device array} for pass-through columns,
-    * ``stacks`` — one tuple of device arrays per stack: ``(W, C)`` for
-      a pure-RLE stack, ``(W, C, S)`` for a delta stack,
-    * ``plan`` — static metadata, one ``(n, rcap, enc, names, bools)``
-      entry per stack (``enc``: "rle" | "delta"), the specialization
-      key ``run_native_kernel`` compiles against.
-
-    Bytes staged here are exactly the resolution kernel's input; they
-    ride the ``device.kernel_input_bytes`` counter next to their dense
-    equivalent so the input-bandwidth win is a ratio, not a guess.
-    """
-    from . import compressed as _C
-
-    cols_np = {k: np.asarray(v) for k, v in cols_np.items()}
-    P = len(cols_np["action"])
-    dense_bytes = sum(v.nbytes for v in cols_np.values())
-    dense = {}
-    groups = {}  # (length, enc class) -> [(name, (w, cum, slope), is_bool)]
-    h2d_bytes = 0
-    for k, v in cols_np.items():
-        n = len(v)
-        enc = None
-        reason = None
-        if n < 32:
-            reason = "short"
-        elif v.dtype not in (np.int32, np.bool_):
-            reason = "dtype"
-        else:
-            enc = _slope_rle(v if v.dtype == np.int32 else v.astype(np.int32))
-            if enc is not None and _C.run_gate(len(enc[0]), n):
-                enc = None
-            if enc is None:
-                reason = "ratio"
-                obs.count("oplog.compress_fallback",
-                          labels={"column": k, "reason": "h2d"})
-        if enc is None:
-            obs.count("device.run_native_fallback",
-                      labels={"column": k, "reason": reason})
-            dense[k] = v
-            h2d_bytes += v.nbytes
-        else:
-            cls = "rle" if enc[2] == 0 else "delta"
-            groups.setdefault((n, cls), []).append(
-                (k, enc, v.dtype == np.bool_)
-            )
-    plan = []
-    host_stacks = []
-    for (n, cls), cols in sorted(groups.items(), key=lambda kv: kv[0]):
-        rcap = _run_cap(n)
-        K = len(cols)
-        W = np.zeros((K, rcap), np.int32)
-        C = np.full((K, rcap), np.int32(n), np.int32)
-        S = np.empty(K, np.int32)
-        for idx, (_, (w, cum, s), _) in enumerate(cols):
-            W[idx, : len(w)] = w
-            C[idx, : len(cum)] = cum
-            S[idx] = s
-        plan.append((
-            n, rcap, cls,
-            tuple(k for k, _, _ in cols),
-            tuple(b for _, _, b in cols),
-        ))
-        arrs = (W, C) if cls == "rle" else (W, C, S)
-        host_stacks.append(arrs)
-        h2d_bytes += sum(a.nbytes for a in arrs)
-    with obs.span("device.h2d", rows=P, bytes=h2d_bytes):
-        dense_dev = {k: jnp.asarray(v) for k, v in dense.items()}
-        stacks = tuple(
-            tuple(jnp.asarray(a) for a in arrs) for arrs in host_stacks
-        )
-    _note_h2d(h2d_bytes, dense_bytes)
-    obs.count("device.kernel_input_bytes", n=h2d_bytes)
-    obs.count("device.kernel_input_dense_bytes", n=dense_bytes)
-    return dense_dev, stacks, tuple(plan)
-
-
-_RUN_NATIVE_CACHE = {}
-
-
 def _resolution_body(geom):
     """The resolution body for one geometry key: ``("core", obj_cap)`` =
     resolve_state (its group sort packs into one int32 key when the
@@ -1109,42 +891,26 @@ RESOLVE_FETCH = (
     "visible", "winner", "conflicts", "obj_vis_len", "obj_text_width",
 )
 
+_RESOLUTION_CACHE = {}
 
-def run_native_kernel(plan, geom, fetch=RESOLVE_FETCH):
-    """The jit'd resolution kernel for one encoding plan and geometry
-    (``_resolution_body``); an empty plan is the plain dense launch.
-    It returns only the ``fetch`` outputs, so XLA drops what nobody
-    reads: returning all of them kept the sibling-forest sort alive and
-    took the TPU compiler ~29 s at 262k rows, against ~2 s without it.
 
-    One compiled variant exists per (plan, geom) — the
-    control-flow-duplication axis: every distinct per-column encoding
-    signature compiles its own kernel whose in-jit expansion
-    (``_expand_runs``) is specialized to the encoding class (pure-RLE:
-    no stride term; delta+RLE: ``+ s*i`` with dynamic slopes), and XLA
-    fuses it into the resolution consumers."""
-    key = (plan, geom, fetch)
-    fn = _RUN_NATIVE_CACHE.get(key)
+def geometry_kernel(geom, fetch=RESOLVE_FETCH):
+    """The jit'd resolution kernel over plain columns for one geometry
+    (``_resolution_body``), one compiled program per (geom, fetch). It
+    returns only the ``fetch`` outputs, so XLA drops what nobody reads:
+    returning all of them kept the sibling-forest sort alive and took
+    the TPU compiler ~29 s at 262k rows, against ~2 s without it."""
+    key = (geom, fetch)
+    fn = _RESOLUTION_CACHE.get(key)
     if fn is None:
         core = _resolution_body(geom)
 
-        @_stage("expand_runs")
-        def expand(dense, stacks):
-            c = dict(dense)
-            for (n, _, cls, names, bools), arrs in zip(plan, stacks):
-                colv = _expand_runs(
-                    arrs[0], arrs[1], None if cls == "rle" else arrs[2], n
-                )
-                for idx, (name, b) in enumerate(zip(names, bools)):
-                    c[name] = colv[idx].astype(jnp.bool_) if b else colv[idx]
-            return c
-
-        def resolve(dense, stacks):
-            out = core(expand(dense, stacks))
+        def resolve(c):
+            out = core(c)
             return {k: out[k] for k in fetch}
 
         name = "merge_kernel" if geom[0] == "full" else "merge_kernel_core"
-        fn = _RUN_NATIVE_CACHE[key] = _program(name, resolve)
+        fn = _RESOLUTION_CACHE[key] = _program(name, resolve)
     return fn
 
 
@@ -1167,48 +933,33 @@ def resolution_geom(P: int, n_objs=None, n_props=None, full=False):
 
 
 def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
-                       fetch=RESOLVE_FETCH, runs=True):
-    """Stage bucket-padded dict columns for one resolution launch and
-    return a zero-arg dispatch closure (staging spans
-    ``device.h2d``/``device.expand`` land here, before it).
+                       fetch=RESOLVE_FETCH):
+    """Upload bucket-padded dict columns plain for one resolution launch
+    (a ``device.h2d`` span) and return a zero-arg dispatch closure.
 
     The closure enqueues the launch inside a ``device.kernel`` span and
     returns without waiting: the span times the dispatch, and the
     device's time shows in the ``device.wait`` span of the ``readback``
-    that collects it. Its ``program`` field says which program ran:
-    ``run_native`` (run tables staged as the kernel's input), else,
-    over eagerly expanded columns, ``scatter`` (the scatter-max winner
-    body) or ``dense`` (the sort-based body).
-
-    Chooses the run-native staging (run tables stay the kernel input,
-    counted as a ``path=run_native`` launch) when enabled and at least
-    one column run-encodes, the eager-expansion staging otherwise. The
-    kernel body is the scatter-max winner kernel when the geometry gate
-    allows, the sort-based core otherwise; ``full=True`` pins the
-    everything-on-device body (on-chip linearization). The launch
-    returns the ``fetch`` outputs only.
-
-    ``runs=False`` uploads every column plain: the program then follows
-    the columns' shapes alone, never which of them run-encode."""
+    that collects it. Its ``program`` field says which body ran:
+    ``scatter`` (the scatter-max winner kernel, where the geometry gate
+    allows) or ``dense`` (the sort-based core; ``full=True`` pins it
+    with on-chip linearization). The launch returns the ``fetch``
+    outputs only. Plain columns make the program follow the columns'
+    shapes alone, so a set-up that met a launch's shapes has compiled
+    its program."""
+    cols_np = {k: np.asarray(v) for k, v in cols_np.items()}
     P = len(cols_np["action"])
     geom = resolution_geom(P, n_objs, n_props, full)
-    if runs and run_native_enabled():
-        dense, stacks, plan = stage_cols_run_native(cols_np)
-        if plan:
-            obs.count("device.kernel_launches",
-                      labels={"path": "run_native"})
-            obs.count("device.runs_expanded",
-                      n=sum(len(names) for _, _, _, names, _ in plan),
-                      labels={"path": "run_native"})
-    else:
-        dense, stacks, plan = stage_cols_device(cols_np, runs), (), ()
-    fn = run_native_kernel(plan, geom, tuple(fetch))
-    program = ("run_native" if plan
-               else "scatter" if geom[0] == "scatter" else "dense")
+    nbytes = sum(v.nbytes for v in cols_np.values())
+    with obs.span("device.h2d", rows=P, bytes=nbytes):
+        cols = {k: jnp.asarray(v) for k, v in cols_np.items()}
+    _note_h2d(nbytes, nbytes)
+    fn = geometry_kernel(geom, tuple(fetch))
+    program = "scatter" if geom[0] == "scatter" else "dense"
 
     def dispatch():
         with obs.span("device.kernel", rows=P, program=program):
-            return fn(dense, stacks)
+            return fn(cols)
 
     return dispatch
 
@@ -1370,8 +1121,8 @@ def _split_flat(flat, fetch, P, obj_cap):
             v = _bitunpack(flat[pos : pos + words], P)
             pos += words
             if k == "conflicts":
-                # travels as a "conflicted" flag; consumers compare > 1
-                v = np.where(v, np.int32(2), np.int32(1))
+                # the bit says "more than one visible op": a count of 2
+                v = conflict_flags(v * 2)
         else:
             if k in _OBJ_STATS:
                 size = obj_cap
@@ -1465,15 +1216,13 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
     scatter resolution (resolve_state_scatter) on the device paths;
     without it the sort-based kernel runs.
 
-    Transport: against a non-CPU backend the packed path is used whenever
-    ``fetch`` is restricted and ``linearize`` is left on "auto" (one array
-    each way — see "packed transport" above); the dict path serves
-    local/CPU runs where per-array transfer is free and the native
-    preorder walk beats the on-device ranking, and any call that pins
-    ``linearize`` explicitly. Override with AUTOMERGE_TPU_TRANSPORT=
-    dict|packed. Packed caveat: ``conflicts`` comes back as a 1/2
-    conflicted flag (consumers compare ``> 1``), not the exact
-    visible-op count the dict path returns.
+    Transport, chosen from the arguments alone and the same on every
+    backend: the packed path (one array each way — see "packed
+    transport" above) whenever ``fetch`` is given and ``linearize`` is
+    left on "auto"; the dict path otherwise (``fetch=None`` or an
+    explicit ``linearize`` pin), which stays the reference. The packed
+    path returns ``conflicts`` as ``conflict_flags`` (1 or 2), the dict
+    path the exact visible-op count.
     """
     from .. import native
 
@@ -1489,27 +1238,8 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
     ):
         return {"elem_index": host_linearize(cols_np)}
 
-    # The merge has two equivalent engines: the jit kernel (device, the
-    # default on every backend) and the O(n) native host merge
-    # (merge_cols.cpp), which AUTOMERGE_TPU_ENGINE=native selects
-    # explicitly so both can be measured on the same input.
-    if (
-        os.environ.get("AUTOMERGE_TPU_ENGINE") == "native"
-        and linearize in ("auto", "native")
-        and native.merge_available()
-    ):
-        need = fetch if fetch is not None else ALL_OUTPUTS
-        with obs.span("merge.host", rows=len(cols_np["action"])):
-            out = native.merge_cols(
-                cols_np,
-                n_objs if n_objs is not None else len(cols_np["action"]),
-                want_elem_index="elem_index" in need,
-            )
-        return {k: out[k] for k in need}
-
     # the jit kernels need bucket-padded shapes; callers may hand over the
-    # raw (unpadded) columns dict — the host engine above consumed it
-    # as-is, the device path pads here (idempotent for padded input)
+    # raw (unpadded) columns dict (idempotent for padded input)
     from .oplog import pad_columns
 
     n_objs_eff = (
@@ -1523,20 +1253,8 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
     )
     cols_np = pad_columns(cols_np, n_objs_eff)
 
-    transport = os.environ.get("AUTOMERGE_TPU_TRANSPORT")
-    if transport is None:
-        transport = (
-            "packed"
-            if fetch is not None
-            and linearize == "auto"
-            and jax.default_backend() != "cpu"
-            else "dict"
-        )
-    if transport == "packed":
-        return _packed_merge(
-            cols_np, fetch if fetch is not None else ALL_OUTPUTS, n_objs,
-            n_props,
-        )
+    if fetch is not None and linearize == "auto":
+        return _packed_merge(cols_np, fetch, n_objs, n_props)
 
     if linearize == "auto":
         linearize = "native" if native.preorder_available() else "device"
@@ -1553,8 +1271,8 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
         return host
 
     if linearize == "native":
-        # staging (run-native or eager-expand) happens here, outside the
-        # kernel span; the closure dispatches the specialized kernel
+        # the plain upload happens here, outside the kernel span; the
+        # closure dispatches the geometry's kernel
         dispatch = prepare_resolution(
             cols_np, n_objs, n_props,
             fetch=tuple(

@@ -1150,7 +1150,7 @@ class AppendInfo:
     """What an in-place ``OpLog.append_changes`` did — everything a resident
     consumer (DeviceDoc) needs to splice its own row-indexed state.
 
-    ``splice`` says where the rows went (``RowSplice``; ``map_rows`` moves
+    ``splice`` says where the rows went (``RowSplice``; its ``map`` moves
     resident row ids through it); ``new_rows`` are the spliced rows'
     positions; ``dirty_objs`` are the dense object ids (NEW numbering)
     whose resolution is stale; ``obj_remap`` maps old dense ids -> new
@@ -1186,10 +1186,6 @@ class AppendInfo:
             rere_pred_edges if rere_pred_edges is not None
             else np.empty(0, np.int64)
         )
-
-    def map_rows(self, rows):
-        """Row ids taken before the append, in the spliced numbering."""
-        return self.splice.map(rows)
 
 
 def _merge_table(old: List[str], delta_table, ids, order) -> Tuple[List[str], np.ndarray]:

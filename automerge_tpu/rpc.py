@@ -1061,7 +1061,8 @@ class RpcServer:
         doc.receive_sync_message(self._syncs[p["sync"]], msg)
         # a durable doc opened with device=true carries a resident
         # DeviceDoc: feed it incrementally so device reads stay current
-        # (the serving layer coalesces runs of these into apply_batches)
+        # (the serving layer coalesces runs of these into one batcher
+        # submission)
         self._feed_mirror(doc, [list(msg.changes)])
         return None
 

@@ -28,9 +28,11 @@ document's ``ack_scope`` — every journal append in the batch rides ONE
 policy fsync, and no response is written until that fsync has returned
 (the ack is durable, just amortized; ``group_commit.batch_size`` in the
 journal records how many appends each physical fsync covered). Runs of
-``receiveSyncMessage`` / ``syncSessionReceive`` frames for the same
-document additionally coalesce their resident-device feed into a single
-``DeviceDoc.apply_batches`` call.
+``receiveSyncMessage`` / ``syncSessionReceive`` / ``applyChanges``
+frames for the same document (a run of one included) coalesce their
+resident-device feed into one submission to the cross-document batcher
+(ops/batched.py), which resolves every document draining at once in a
+shared kernel launch — on every backend.
 
 Env knobs (all overridable by constructor arguments):
 
@@ -64,7 +66,7 @@ from .shards import QueueFull, ShardPool
 _OPEN_DURABLE_KEY = "__open_durable__"  # serializes name-cache races
 
 # methods whose frames coalesce when adjacent in a drain (same doc, same
-# sync/session handle): their device feed batches into one apply_batches
+# sync/session handle): their device feed joins the batcher as one submission
 _COALESCE_METHODS = ("receiveSyncMessage", "syncSessionReceive",
                      "applyChanges")
 
@@ -204,16 +206,11 @@ class SocketRpcServer:
         )
         # cross-document device-merge batcher: workers draining DIFFERENT
         # documents in the same drain cycle share ONE kernel launch for
-        # their coalesced device feeds (AUTOMERGE_TPU_SERVE_BATCHED=
-        # 1|0|auto; auto batches only on accelerator backends — on CPU the
-        # per-doc host delta resolution is the fast path). The early-wake
-        # threshold is capped at the POOL SIZE: at most `workers` docs can
-        # ever be draining at once, so a full complement of submitters
-        # wakes the flush leader immediately instead of every drain
-        # sleeping out the whole batch window. Generations at least
-        # AUTOMERGE_TPU_PIPELINE_MIN_DOCS wide flush as two overlapped
-        # half-launches (the drain pipeline; see batched.CrossDocBatcher)
-        # — submitters still block until their half is collected
+        # their coalesced device feeds. The early-wake threshold is
+        # capped at the POOL SIZE: at most `workers` docs can ever be
+        # draining at once, so a full complement of submitters wakes the
+        # flush leader immediately instead of every drain sleeping out
+        # the whole batch window
         n_workers = len(self.pool.workers)
         self.batcher = CrossDocBatcher(
             max_docs=min(
@@ -620,16 +617,12 @@ class SocketRpcServer:
                 while i < len(items):
                     conn, req = items[i]
                     j = self._coalesce_end(items, i)
-                    # with the cross-doc batcher active, even a LENGTH-1
-                    # receive run takes the coalesced path: its device
-                    # feed then joins whatever other documents are
+                    # even a LENGTH-1 run takes the coalesced path: its
+                    # device feed then joins whatever other documents are
                     # draining right now in one shared kernel launch
                     # (a drain of 100 docs x 1 frame each is the case
                     # the batcher exists for)
-                    if j > i or (
-                        self._coalesce_key(req) is not None
-                        and self._coalesce_single(req.get("method"))
-                    ):
+                    if self._coalesce_key(req) is not None:
                         self._run_coalesced(items[i : j + 1], out)
                     else:
                         with contextlib.ExitStack() as st:
@@ -692,12 +685,6 @@ class SocketRpcServer:
             else params.get("doc"),
         )
 
-    def _coalesce_single(self, method) -> bool:
-        """Whether a LENGTH-1 run of ``method`` still routes through the
-        coalesced path (so its device feed joins the cross-doc
-        batcher)."""
-        return self.batcher.active()
-
     def _coalesce_end(self, items, i) -> int:
         """Last index of the run starting at ``i`` of coalescable
         frames (length-1 runs return ``i``)."""
@@ -712,7 +699,7 @@ class SocketRpcServer:
     def _run_coalesced(self, run, out) -> None:
         """A run of receive frames for one doc/session: the host applies
         stay per-message (protocol state machines need each), but the
-        resident-device feed drains into one ``apply_batches`` call."""
+        resident-device feed drains into one batcher submission."""
         method = run[0][1].get("method")
         if len(run) > 1:  # length-1 runs only ride the cross-doc batcher
             obs.count("rpc.coalesced", n=len(run), labels={"method": method})
@@ -824,10 +811,6 @@ class SocketRpcServer:
 
     def _feed_device(self, dev, batches) -> None:
         """Route a drained document's device feed through the cross-doc
-        batcher (one shared kernel launch with whatever other documents
-        are draining right now) or, when batching is off for this
-        backend, through the per-doc pipelined path."""
-        if self.batcher.active():
-            self.batcher.apply(dev, batches)
-        else:
-            dev.apply_batches(batches)
+        batcher: one shared kernel launch with whatever other documents
+        are draining right now."""
+        self.batcher.apply(dev, batches)

@@ -544,7 +544,7 @@ class DurableDocument:
             self.device_doc = dev
             dev._export_doc_gauges()
             # the promotion shipped the compressed image (the resolve's
-            # H2D staging moves run tables, merge.stage_cols_device);
+            # packed transport moves run tables, merge.encode_transport);
             # record what warm->hot residency actually costs
             obs.count("store.promote_resident_bytes",
                       n=dev.resident_nbytes())

@@ -256,9 +256,8 @@ class SyncSession:
         """Drain a run of pending wire frames in arrival order, coalescing
         the resident-device feed: instead of one ``DeviceDoc.apply_changes``
         per message, every message's changes collect into a single
-        ``apply_batches`` call at the end — on accelerator backends that
-        pipelines the kernel launches (h2d staging of batch k+1 overlaps
-        batch k's kernel), amortizing per-launch cost across the run.
+        ``apply_batches`` call at the end — one staging pass and one
+        launch for the whole run, amortizing per-launch cost.
 
         ``device_feed`` (a callable taking the collected batches)
         replaces the direct ``apply_batches`` call — the serving layer

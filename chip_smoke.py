@@ -430,7 +430,7 @@ RES_KEYS = ("visible", "winner", "conflicts", "elem_index",
             "obj_vis_len", "obj_text_width")
 
 
-def phase_mesh(seed, n_edits, sessions, n_chips):
+def phase_mesh(seed, n_edits, sessions, n_chips, linearize="auto"):
     import jax
     import numpy as np
 
@@ -466,7 +466,8 @@ def phase_mesh(seed, n_edits, sessions, n_chips):
                   for d in jax.local_devices()[:n_chips]]
 
     t0 = time.perf_counter()
-    res_one = merge_columns(log.columns(), fetch=DeviceDoc.READ_FETCH, **kw)
+    res_one = merge_columns(log.columns(), linearize=linearize,
+                            fetch=DeviceDoc.READ_FETCH, **kw)
     t_one = time.perf_counter() - t0
 
     n = log.n
@@ -474,8 +475,8 @@ def phase_mesh(seed, n_edits, sessions, n_chips):
         a, b = np.asarray(res_mesh[k]), np.asarray(res_one[k])
         m = n if k not in ("obj_vis_len", "obj_text_width") else log.n_objs + 2
         if k == "conflicts":
-            # the packed transport returns a conflicted flag, not the
-            # count (merge_columns: readers compare > 1)
+            # the packed transport returns conflict_flags, not the count
+            # (merge_columns: readers compare > 1)
             a, b = a > 1, b > 1
         check(np.array_equal(a[:m], b[:m]),
               f"mesh: {k} differs between {n_chips} chips and one")

@@ -127,7 +127,7 @@ def test_small_maps_share_one_launch_and_match_the_reference(seed):
     docs = [MapDoc(d, seed, int(rng.integers(2, 7))) for d in range(4)]
     packed = [m.resolved() for m in docs]
     serial = [m.resolved() for m in docs]
-    batcher = CrossDocBatcher(mode="1", window_ms=2000.0, max_docs=len(docs))
+    batcher = CrossDocBatcher(window_ms=2000.0, max_docs=len(docs))
     windows0 = len([r for r in obs.recorder.snapshot()
                     if r.name == "device.batch_window"])
     for cycle in range(3):
@@ -159,7 +159,7 @@ def test_a_lone_document_and_a_peeled_whale_resolve_per_doc():
     dev, ref = m.resolved(), m.resolved()
     chs = [m.change(m.replicas[0])]
     l0, s0 = launches(), batch_stages()
-    apply_generation(CrossDocBatcher(mode="1", window_ms=1.0, max_docs=8),
+    apply_generation(CrossDocBatcher(window_ms=1.0, max_docs=8),
                      [(dev, chs)])
     l1, s1 = launches(), batch_stages()
     assert l1.get("batched", 0) == l0.get("batched", 0)
@@ -177,7 +177,7 @@ def test_a_lone_document_and_a_peeled_whale_resolve_per_doc():
     work = [[d.change(d.replicas[0])] for d in docs]
     l0, s0 = launches(), batch_stages()
     f0 = obs.counter_values("device.batched_fallback", "").get("", 0)
-    apply_generation(CrossDocBatcher(mode="1", window_ms=2000.0, max_docs=3),
+    apply_generation(CrossDocBatcher(window_ms=2000.0, max_docs=3),
                      list(zip(devs, work)))
     l1, s1 = launches(), batch_stages()
     assert l1.get("batched", 0) - l0.get("batched", 0) == 1
@@ -228,7 +228,7 @@ def test_leader_waits_for_the_drains_in_flight(monkeypatch, in_flight,
     devs = [m.resolved() for m in docs]
     refs = [m.resolved() for m in docs]
     work = [[m.change(m.replicas[0])] for m in docs]
-    batcher = CrossDocBatcher(mode="1", window_ms=1.0, max_docs=8)
+    batcher = CrossDocBatcher(window_ms=1.0, max_docs=8)
     batcher.in_flight = lambda: in_flight
     n0 = len(window_spans(0))
     l0 = launches()
@@ -256,13 +256,19 @@ def test_shared_launch_program_follows_its_shapes_alone():
         gens.append([batched.full_stage(dev) for dev in devs])
     cols = [batched._pack(stages)[0] for stages in gens]
     assert len(cols[0]["action"]) == len(cols[1]["action"])
-    plans = [merge.stage_cols_run_native(c)[2] for c in cols]
-    assert plans[0] != plans[1], "the run structures agree: vacuous"
-    programs0 = len(merge._RUN_NATIVE_CACHE)
+    # each column's runs and slope, as the packed transport would
+    # encode them; the shared launch uploads plain columns and ignores them
+    runs = [
+        {k: None if e is None else (len(e[0]), e[2])
+         for k, e in ((k, merge._slope_rle(np.asarray(v, np.int32)))
+                      for k, v in c.items())}
+        for c in cols
+    ]
+    assert runs[0] != runs[1], "the run structures agree: vacuous"
+    programs0 = len(merge._RESOLUTION_CACHE)
     l0 = launches()
     for stages in gens:
         batched.resolve_stages(stages)
     l1 = launches()
-    assert len(merge._RUN_NATIVE_CACHE) - programs0 <= 1
+    assert len(merge._RESOLUTION_CACHE) - programs0 <= 1
     assert l1.get("batched", 0) - l0.get("batched", 0) == 2
-    assert l1.get("run_native", 0) == l0.get("run_native", 0)

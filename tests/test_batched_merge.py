@@ -307,7 +307,7 @@ def test_cross_doc_batcher_threads():
         s = DeviceDoc.resolve(OpLog.from_changes(chs))
         s.apply_changes(delta)
         serial.append(s)
-    batcher = CrossDocBatcher(mode="1", window_ms=200.0, max_docs=n)
+    batcher = CrossDocBatcher(window_ms=200.0, max_docs=n)
     before = launch_counts()
     errs = []
     barrier = threading.Barrier(n)
@@ -334,15 +334,23 @@ def test_cross_doc_batcher_threads():
         assert_bit_identical(dev, s)
 
 
-def test_cross_doc_batcher_inactive_mode():
-    """mode='0' routes through the per-doc apply_batches path."""
+def test_apply_batches_takes_the_batcher_route():
+    """apply_batches is a batcher generation of one: the same launch
+    and the same arrays as a lone batcher submission."""
     chs, delta = _doc_with_delta(0)
     dev = DeviceDoc.resolve(OpLog.from_changes(chs))
+    lone = DeviceDoc.resolve(OpLog.from_changes(chs))
     ref = DeviceDoc.resolve(OpLog.from_changes(chs))
     ref.apply_changes(delta)
-    batcher = CrossDocBatcher(mode="0")
-    assert not batcher.active()
-    assert batcher.apply(dev, [delta]) == len(delta)
+    l0 = launch_counts()
+    assert dev.apply_batches([delta]) == len(delta)
+    l1 = launch_counts()
+    assert CrossDocBatcher(window_ms=1.0).apply(lone, [delta]) == len(delta)
+    l2 = launch_counts()
+    assert l1.get("per_doc", 0) - l0.get("per_doc", 0) == 1
+    assert l2.get("per_doc", 0) - l1.get("per_doc", 0) == 1
+    assert l2.get("batched", 0) == l0.get("batched", 0)
+    assert_bit_identical(dev, lone)
     assert_bit_identical(dev, ref)
 
 

@@ -174,11 +174,11 @@ def test_compressed_image_decodes_to_live_columns():
     assert log.compress_ratio() > 2.0
 
 
-# -- compressed H2D staging ---------------------------------------------------
+# -- run-encoded H2D staging -------------------------------------------------
 
 
-def test_stage_cols_device_expands_bit_identically(monkeypatch):
-    from automerge_tpu.ops.merge import stage_cols_device
+def test_packed_transport_decodes_what_the_dict_path_uploads():
+    from automerge_tpu.ops import merge as M
 
     base = AutoDoc(actor=ActorId(bytes([20]) * 16))
     t = base.put_object("_root", "t", ObjType.TEXT)
@@ -187,18 +187,24 @@ def test_stage_cols_device_expands_bit_identically(monkeypatch):
     base.commit()
     log = OpLog.from_documents([base])
     cols = log.padded_columns()
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
     h0 = obs.counter_values("device.h2d_bytes", "").get("", 0)
-    dev_c = stage_cols_device(cols)
+    d0 = obs.counter_values("device.h2d_dense_bytes", "").get("", 0)
+    packed = M.merge_columns(cols, fetch=M.RESOLVE_FETCH, n_objs=log.n_objs)
     h1 = obs.counter_values("device.h2d_bytes", "").get("", 0)
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
-    dev_d = stage_cols_device(cols)
+    d1 = obs.counter_values("device.h2d_dense_bytes", "").get("", 0)
+    plain = M.merge_columns(cols, linearize="native", fetch=M.RESOLVE_FETCH,
+                            n_objs=log.n_objs)
     h2 = obs.counter_values("device.h2d_bytes", "").get("", 0)
-    for k in cols:
-        a, b = np.asarray(dev_c[k]), np.asarray(dev_d[k])
-        assert a.dtype == b.dtype and np.array_equal(a, b), k
-    # compressed staging moved measurably fewer bytes than dense
+    for k in M.RESOLVE_FETCH:
+        want = plain[k]
+        if k == "conflicts":
+            want = M.conflict_flags(want)
+        m = log.n_objs + 2 if k in M._OBJ_STATS else log.n
+        assert np.array_equal(np.asarray(packed[k])[:m], want[:m]), k
+    # the run tables moved measurably fewer bytes than the plain upload,
+    # and the dense equivalent they are counted against is that upload's
     assert (h1 - h0) * 2 < (h2 - h1), (h1 - h0, h2 - h1)
+    assert d1 - d0 == h2 - h1
 
 
 # -- end-to-end differential: compressed vs dense residency -------------------

@@ -7,8 +7,8 @@ delivery, staging the same deltas through ``host_batch.stage_docs`` (+
 the shared packed launch) must leave every document in a bit-identical
 state — column-level OpLog equality, identical resolution arrays and
 host caches, identical materialized documents including ``at(heads)``
-views. Fallback routes (scalar knob, empty logs, non-tail splices) are
-exercised and asserted non-vacuous.
+views. Fallback routes (empty logs, non-tail splices) are exercised
+and asserted non-vacuous.
 """
 
 import os
@@ -153,11 +153,24 @@ def test_differential_random_interleavings(seed):
         assert vec[i].at([]).hydrate() == sca[i].at([]).hydrate()
 
 
-def test_scalar_knob_forces_per_doc(monkeypatch):
-    monkeypatch.setenv("AUTOMERGE_TPU_HOST_BATCH", "0")
+def test_apply_batches_matches_the_scalar_stage_ready():
+    """``apply_batches`` is a generation of one through the vectorized
+    staging and the shared route; the scalar ``stage_ready``, called
+    directly, is its oracle."""
     docs, deltas = build_workload(5, n_docs=3, cycles=2)
-    vec, sca, n_vec = drive_pair(docs, deltas, 2)
-    assert n_vec == 0  # every doc went through the scalar oracle path
+    gen = [DeviceDoc.resolve(OpLog.from_documents([d])) for d in docs]
+    sca = [DeviceDoc.resolve(OpLog.from_documents([d])) for d in docs]
+    n0 = obs.counter_values("host_batch.docs", "").get("", 0)
+    for c in range(2):
+        for i in range(len(docs)):
+            applied = gen[i].apply_batches([deltas[i][c]])
+            n, st = sca[i].stage_ready(sca[i]._take_ready(deltas[i][c]))
+            assert applied == n
+            if st is not None:
+                resolve_stages([st])
+            assert_identical(gen[i], sca[i], (c, i))
+    # non-vacuous: apply_batches staged through the vectorized pass
+    assert obs.counter_values("host_batch.docs", "").get("", 0) > n0
 
 
 def test_out_of_order_delivery_buffers_pending():
@@ -259,16 +272,15 @@ def test_non_tail_delivery_demotes_to_scalar():
     assert_identical(vec, sca)
 
 
-def test_cross_doc_batcher_leader_staged(monkeypatch):
+def test_cross_doc_batcher_leader_staged():
     """Concurrent submitters hand RAW batches to the flush leader, which
     stages every co-arriving document in one vectorized pass before one
     shared launch — results identical to the scalar reference."""
-    monkeypatch.setenv("AUTOMERGE_TPU_HOST_BATCH", "1")
     docs, deltas = build_workload(13, n_docs=4, cycles=2, dup=False,
                                   ballast=400)
     vec = [DeviceDoc.resolve(OpLog.from_documents([d])) for d in docs]
     sca = [DeviceDoc.resolve(OpLog.from_documents([d])) for d in docs]
-    batcher = CrossDocBatcher(window_ms=200.0, max_docs=4, mode="1")
+    batcher = CrossDocBatcher(window_ms=200.0, max_docs=4)
     for c in range(2):
         launches0 = obs.counter_values(
             "device.kernel_launches", "path").get("batched", 0)

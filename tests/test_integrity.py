@@ -104,21 +104,20 @@ def test_digest_invariant_under_out_of_order_delivery(seed):
 
 
 def test_digest_invariant_across_residency_modes(monkeypatch):
-    """Dense, compressed, and run-native residency hold the same
-    history, so the digest must not move; the column-level oracle
-    (decoded resident image == dense image) backs it up."""
+    """Dense and compressed residency hold the same history, so the
+    digest must not move; the column-level oracle (decoded resident
+    image == dense image) backs it up."""
     base, _t, forks = _build_forks(7)
     m = AutoDoc.load(base.save())
     for f in forks:
         m.merge(f)
     want = None
-    for comp, rn in (("1", "1"), ("1", "0"), ("0", "0")):
+    for comp in ("1", "0"):
         monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", comp)
-        monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", rn)
         d = integrity.doc_digest(AutoDoc.load(m.save()).doc)
         if want is None:
             want = d
-        assert d == want, (comp, rn, d, want)
+        assert d == want, (comp, d, want)
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
     from automerge_tpu.ops.oplog import OpLog
 

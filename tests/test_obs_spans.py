@@ -220,17 +220,22 @@ def _device_doc_log():
 
 
 @pytest.mark.parametrize("transport,program", [
-    ("dict", ("run_native", "scatter", "dense")),
+    ("dict", ("scatter", "dense")),
     ("packed", ("packed",)),
 ])
-def test_device_wait_nests_inside_device_readback(monkeypatch, transport,
-                                                  program):
+def test_device_wait_nests_inside_device_readback(transport, program):
     from automerge_tpu.ops import DeviceDoc
+    from automerge_tpu.ops.merge import merge_columns
 
-    monkeypatch.setenv("AUTOMERGE_TPU_TRANSPORT", transport)
     log = _device_doc_log()
     obs.reset_all()
-    DeviceDoc.resolve(log)
+    if transport == "packed":
+        DeviceDoc.resolve(log)
+    else:
+        # the dict path: an explicit linearize pin
+        merge_columns(log.columns(), linearize="native",
+                      fetch=DeviceDoc.READ_FETCH, n_objs=log.n_objs,
+                      n_props=len(log.props))
     by_id = {r.span_id: r for r in obs.recorder.snapshot()}
     waits, copies = _spans("device.wait"), _spans("device.d2h")
     assert waits and copies
@@ -269,16 +274,15 @@ def test_resolution_programs_carry_the_resolution_names():
     log = _device_doc_log()
     cols = pad_columns(log.columns(), log.n_objs)
     P, Q = len(cols["action"]), len(cols["pred_src"])
-    _, _, plan = M.stage_cols_run_native(cols)
     fetch = M.RESOLVE_FETCH
     resolution = {
         M.merge_kernel, M.merge_kernel_core,
         M.merge_kernel_condensed(32, 16),
         M.scatter_kernel_core(log.n_objs, len(log.props)),
-        M.run_native_kernel(plan, M.resolution_geom(P, log.n_objs), fetch),
-        M.run_native_kernel(plan, M.resolution_geom(
+        M.geometry_kernel(M.resolution_geom(P, log.n_objs), fetch),
+        M.geometry_kernel(M.resolution_geom(
             P, log.n_objs, full=True), fetch + ("elem_index",)),
-        M.run_native_kernel(plan, M.resolution_geom(
+        M.geometry_kernel(M.resolution_geom(
             P, log.n_objs, len(log.props)), fetch),
         M._runs_fn(fetch, M._obj_cap(log.n_objs, P),
                    M.encode_transport(cols)[0], P, Q),
@@ -286,8 +290,6 @@ def test_resolution_programs_carry_the_resolution_names():
                    M.encode_transport(cols)[0], P, Q),
     }
     assert {fn.__name__ for fn in resolution} == set(M.RESOLUTION_PROGRAMS)
-    expand = M._expander(P, 16, (False,))
-    assert expand.__name__ == "expand_runs"
     # the name reaches the XLA module a profile shows
     text = M.merge_kernel_core.lower(
         {k: np.asarray(v) for k, v in cols.items()}).as_text()

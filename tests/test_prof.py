@@ -235,7 +235,7 @@ def test_batched_drain_cycle_report():
         assert r["stages"].get(stage, 0) > 0, (stage, r["stages"])
     # the host staging half attributes through the vectorized cross-doc
     # stages (host_pack/host_splice) — or through the scalar splice
-    # stage when AUTOMERGE_TPU_HOST_BATCH=0 forces the per-doc path
+    # stage for a document the fast path does not admit
     assert (
         r["stages"].get("host_splice", 0) > 0
         or r["stages"].get("splice", 0) > 0

@@ -5,11 +5,15 @@ first row and one prefix sum over the rows. These tests hold it to the
 column ``_slope_rle`` was given, bit for bit, on the shapes the encoder
 produces (one run, constant, slope 1, a stride-N slope, the n/8 gate, a
 run capacity padded far past the runs, values and steps at the ends of
-int32, 32 rows, bool columns), then hold the three launch sites that
-share it (packed transport, run-native kernel, eager expansion) to the
-dense oracle (``AUTOMERGE_TPU_COMPRESSED=0``) on small edit traces, and
-check the counters that say how many columns each launch decoded.
+int32, 32 rows, the flag column the bool columns ride), then hold the
+two launches every backend takes — the packed transport and the shared
+plain launch (``prepare_resolution``) — over compressed and dense
+residency to the dict path's reference (an explicit ``linearize`` pin,
+dense residency) on small edit traces, and check the counters that say
+how many columns a packed launch decoded.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -18,7 +22,7 @@ import jax
 
 from automerge_tpu import bench as W
 from automerge_tpu import obs
-from automerge_tpu.ops import OpLog
+from automerge_tpu.ops import OpLog, batched
 from automerge_tpu.ops import merge as M
 from automerge_tpu.ops.oplog import _next_pow2, pad_columns
 
@@ -109,27 +113,46 @@ def test_expand_runs_decodes_a_stack_at_once():
     np.testing.assert_array_equal(got, np.stack(cols))
 
 
-def test_eager_expander_keeps_bool_columns():
+def test_transport_unpack_restores_the_flag_columns():
+    # action/insert/value_tag/covered ride one run-encoded flags column;
+    # the decode hands back the kernel's dtypes, bools included
     n = 256
-    b = np.zeros(n, bool)
-    b[40:90] = True
-    b[200:] = True
-    x = _column("slope_1", n)
-    encs = [M._slope_rle(b.astype(np.int32)), M._slope_rle(x)]
-    rcap = _next_pow2(max(len(e[0]) for e in encs) + 16)
-    got_b, got_x = M._expander(n, rcap, (True, False))(*_table(encs, n, rcap))
-    assert got_b.dtype == np.bool_ and got_x.dtype == np.int32
-    np.testing.assert_array_equal(np.asarray(got_b), b)
-    np.testing.assert_array_equal(np.asarray(got_x), x)
+    ins = np.zeros(n, bool)
+    ins[40:90] = True
+    ins[200:] = True
+    cols = {
+        "action": np.where(ins, 1, 3).astype(np.int32),
+        "insert": ins,
+        "value_tag": np.repeat(np.arange(4, dtype=np.int32), n // 4),
+        "covered": np.arange(n) < 128,
+        "prop": np.zeros(n, np.int32),
+        "elem_ref": _column("slope_1", n),
+        "obj_dense": np.zeros(n, np.int32),
+        "value_i32": np.zeros(n, np.int32),
+        "width": np.ones(n, np.int32),
+        "pred_src": np.zeros(32, np.int32),
+        "pred_tgt": np.zeros(32, np.int32),
+    }
+    static_key, arrays = M.encode_transport(cols)
+    assert "flags" in static_key[0]
+    got = jax.jit(lambda a: M._unpack_transport(static_key, a, n, 32))(
+        {k: np.asarray(v) for k, v in arrays.items()})
+    for k in ("action", "insert", "value_tag", "covered"):
+        have = np.asarray(got[k])
+        assert have.dtype == cols[k].dtype, k
+        np.testing.assert_array_equal(have, cols[k], err_msg=k)
 
 
-# -- the three launch sites against the dense oracle --------------------------
+# -- the two launches against the reference ---------------------------------
 
 
-def _log(kind):
+def _log(kind, monkeypatch=None, residency=None):
     """``edit``: an edit trace; ``fanin``: the same with 12 replicas'
     16-edit changes merged in; ``typing``: one sequential typing run with
-    those replicas, whose pred columns run-encode with slopes."""
+    those replicas, whose pred columns run-encode with slopes. With a
+    ``residency``, the log is built under it from the first half of the
+    history and the second half is spliced in (``append_changes``), as a
+    resident document grows (a one-change history is built whole)."""
     n_edits = 1500
     trace = W.synth_edit_trace(n_edits, seed=3)
     typed = [[i, 0, "a"] for i in range(n_edits)]
@@ -137,51 +160,63 @@ def _log(kind):
     changes = list(base.changes)
     if kind != "edit":
         changes += W.synth_fanin(base, trace, 12, 16, n_edits)
-    return OpLog.from_changes(changes)
+    if residency is None:
+        return OpLog.from_changes(changes)
+    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED",
+                       "1" if residency == "compressed" else "0")
+    half = max(len(changes) // 2, 1)
+    log = OpLog.from_changes(changes[:half])
+    if half < len(changes):
+        assert log.append_changes(changes[half:]) is not None
+    return log
 
 
-def _resolve(log, monkeypatch, env):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
+def _resolve(log, site):
+    """``site``: ``packed`` (``merge_columns`` with ``fetch``, as a
+    document's own launch), ``batched`` (the shared launch: plain
+    columns through ``prepare_resolution``, ``conflicts`` as flags),
+    ``dict`` (the reference: an explicit ``linearize`` pin)."""
+    kw = dict(n_objs=log.n_objs, n_props=len(log.props))
+    if site == "batched":
+        return batched._collect_packed(batched._dispatch_packed(
+            pad_columns(log.columns(), log.n_objs), **kw))
     return M.merge_columns(log.columns(), fetch=M.RESOLVE_FETCH,
-                           n_objs=log.n_objs, n_props=len(log.props))
+                           linearize="native" if site == "dict" else "auto",
+                           **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(kind):
+    return _resolve(_log(kind), "dict")
 
 
 def _runs_expanded(path):
     return obs.counter_values("device.runs_expanded", "path").get(path, 0)
 
 
-SITES = {
-    "packed": {"AUTOMERGE_TPU_TRANSPORT": "packed",
-               "AUTOMERGE_TPU_COMPRESSED": "1"},
-    "run_native": {"AUTOMERGE_TPU_TRANSPORT": "dict",
-                   "AUTOMERGE_TPU_COMPRESSED": "1",
-                   "AUTOMERGE_TPU_RUN_NATIVE": "1"},
-    "eager": {"AUTOMERGE_TPU_TRANSPORT": "dict",
-              "AUTOMERGE_TPU_COMPRESSED": "1",
-              "AUTOMERGE_TPU_RUN_NATIVE": "0"},
-}
-
-
 @pytest.mark.parametrize("kind", ["edit", "fanin", "typing"])
-@pytest.mark.parametrize("site", sorted(SITES))
-def test_site_matches_the_dense_oracle(site, kind, monkeypatch):
-    log = _log(kind)
-    dense = _resolve(log, monkeypatch, {"AUTOMERGE_TPU_TRANSPORT": "dict",
-                                        "AUTOMERGE_TPU_COMPRESSED": "0"})
-    before = _runs_expanded(site)
-    got = _resolve(log, monkeypatch, SITES[site])
-    assert _runs_expanded(site) > before, "no column was decoded from runs"
+@pytest.mark.parametrize("residency", ["compressed", "dense"])
+@pytest.mark.parametrize("site", ["batched", "packed"])
+def test_site_matches_the_dense_oracle(site, residency, kind, monkeypatch):
+    dense = _reference(kind)
+    log = _log(kind, monkeypatch, residency)
+    assert log.n == len(dense["visible"][: log.n])
+    before = _runs_expanded("packed")
+    got = _resolve(log, site)
+    if site == "packed":
+        assert _runs_expanded("packed") > before, \
+            "no column was decoded from runs"
+    else:
+        assert _runs_expanded("packed") == before
     for k in M.RESOLVE_FETCH:
         have, want = got[k], dense[k]
-        if site == "packed":
-            if k == "conflicts":
-                # the packed transport returns a conflicted flag (1 or 2)
-                have, want = have > 1, want > 1
-            if k in M._OBJ_STATS:
-                # ... and the per-object stats at a bucketed capacity
-                have = have[: log.n_objs + 2]
-        np.testing.assert_array_equal(have, want, err_msg=k)
+        if k == "conflicts":
+            # both launches return conflict_flags (1 or 2)
+            want = M.conflict_flags(want)
+        # per-row outputs to the log's rows, per-object stats to its
+        # objects (the launches return them at bucketed capacities)
+        m = log.n_objs + 2 if k in M._OBJ_STATS else log.n
+        np.testing.assert_array_equal(have[:m], want[:m], err_msg=k)
 
 
 def _fallbacks(reason):
@@ -200,7 +235,7 @@ def test_packed_launch_counts_every_column(monkeypatch):
     plain = len(static_key[1]) + len(static_key[3])
     assert runs and plain
     r0, f0 = _runs_expanded("packed"), _fallbacks("transport")
-    _resolve(log, monkeypatch, SITES["packed"])
+    _resolve(log, "packed")
     r1, f1 = _runs_expanded("packed"), _fallbacks("transport")
     assert (r1 - r0, f1 - f0) == (runs, plain)
     assert r1 - r0 + f1 - f0 == len(M._P_ORDER) + len(M._Q_ORDER)

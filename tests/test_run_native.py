@@ -1,20 +1,24 @@
-"""Differential suite for run-native resolution kernels (ops/merge.py)
-and the double-buffered drain pipeline (ops/batched.py).
+"""Differential suite for the one resolution route every backend takes
+over compressed and dense residency: ``host_batch.stage_docs`` then the
+shared plain launch or, for a lone document, the packed transport
+(ops/batched.py, ops/merge.py), plus the double-buffered drain.
 
-The fully dense path (``AUTOMERGE_TPU_COMPRESSED=0``) is the oracle:
-the same multi-seed workloads resolved through run-native kernels (run
-tables as the kernel's input, expansion gathers fused in-jit) and
-through the eager-expansion staging (``AUTOMERGE_TPU_RUN_NATIVE=0``)
-must leave every document bit-identical — column-level OpLog equality,
-full DeviceDoc state, identical ``at(heads)`` views — across the
-stage_docs + packed-launch path, the per-doc async dispatch, the
-pipelined (double-buffered) drain with out-of-order/duplicate delivery,
-and ratio-gate-demoted mixed encodings. Plus staging-level properties:
-the run-table expansion decodes exactly, degenerate columns demote
-dense through ``compressed.run_gate`` with per-column fallback
-counters, and kernel input bytes genuinely undercut the dense image.
+Dense residency (``AUTOMERGE_TPU_COMPRESSED=0``) is the oracle: the same
+multi-seed workloads resolved with compressed residency must leave every
+document bit-identical — column-level OpLog equality, full DeviceDoc
+state including ``conflicts``, identical ``at(heads)`` views — across
+the stage_docs + shared launch path, ``apply_batches`` (a generation of
+one), the pipelined (double-buffered) drain with out-of-order/duplicate
+delivery, and high-entropy edits whose resident columns the ratio gate
+demotes dense. Each drive asserts that the launches it means ran:
+``device.kernel_launches{path=batched}`` for the shared launch,
+``device.kernel`` spans with ``program=packed`` for a lone document's.
+Plus launch-level properties: the packed transport decodes its run
+tables exactly, ships a degenerate column plain and counts it, and the
+shared launch uploads plain columns.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -22,9 +26,20 @@ from automerge_tpu import obs
 from automerge_tpu.ops import host_batch, merge
 from automerge_tpu.ops.batched import apply_cross_doc, resolve_stages
 from automerge_tpu.ops.device_doc import DeviceDoc
-from automerge_tpu.ops.oplog import OpLog
+from automerge_tpu.ops.oplog import OpLog, pad_columns
 
 from .test_host_batch import assert_identical, build_workload
+
+
+def _batched_launches():
+    return obs.counter_values("device.kernel_launches", "path").get(
+        "batched", 0)
+
+
+def _packed_kernels():
+    return sum(1 for r in obs.recorder.snapshot()
+               if r.name == "device.kernel"
+               and r.fields.get("program") == "packed")
 
 
 def _drive_staged(docs, deltas, cycles):
@@ -62,47 +77,48 @@ def _check_same(got, oracle, docs):
         assert got[i].at([]).hydrate() == oracle[i].at([]).hydrate()
 
 
-# -- end-to-end differential: run-native vs eager-expand vs dense ------------
+# -- end-to-end differential: compressed vs dense residency -----------------
 
 
 @pytest.mark.parametrize("seed", [2, 17, 40])
 def test_differential_staged_launches(monkeypatch, seed):
     docs, deltas = build_workload(seed, n_docs=4, cycles=4)
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "1")
-    rn0 = obs.counter_values("device.kernel_launches", "path").get(
-        "run_native", 0)
-    native = _drive_staged(docs, deltas, 4)
-    rn1 = obs.counter_values("device.kernel_launches", "path").get(
-        "run_native", 0)
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "0")
-    eager = _drive_staged(docs, deltas, 4)
+    obs.recorder.clear()
+    b0 = _batched_launches()
+    comp = _drive_staged(docs, deltas, 4)
+    b1 = _batched_launches()
+    packed = _packed_kernels()
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
     dense = _drive_staged(docs, deltas, 4)
-    _check_same(native, dense, docs)
-    _check_same(eager, dense, docs)
-    # non-vacuous: the run-native dispatch path actually launched
-    assert rn1 > rn0
+    _check_same(comp, dense, docs)
+    # non-vacuous: the shared launch carried the drains, and the
+    # documents' own resolutions took the packed transport
+    assert b1 > b0
+    assert packed > 0
 
 
 @pytest.mark.parametrize("seed", [7, 29])
 def test_differential_per_doc_async_dispatch(monkeypatch, seed):
-    # the per-doc apply_batches path (DeviceDoc._dispatch_async →
-    # prepare_resolution), including its in-flight double buffering
+    # apply_batches: a generation of one through stage_docs, resolved
+    # by the document's own packed launch
     docs, deltas = build_workload(seed, n_docs=2, cycles=4, dup=True)
 
     def run():
         devs = [DeviceDoc.resolve(OpLog.from_documents([d])) for d in docs]
+        obs.recorder.clear()
         for i, dv in enumerate(devs):
             dv.apply_batches([deltas[i][c] for c in range(4)])
-        return devs
+        return devs, _packed_kernels()
 
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "1")
-    native = run()
+    b0 = _batched_launches()
+    comp, packed = run()
+    assert packed >= len(docs)
+    assert _batched_launches() == b0
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
-    dense = run()
-    _check_same(native, dense, docs)
+    dense, _ = run()
+    _check_same(comp, dense, docs)
 
 
 @pytest.mark.parametrize("seed", [5, 33])
@@ -112,8 +128,9 @@ def test_differential_pipelined_drain(monkeypatch, seed):
     docs, deltas = build_workload(seed, n_docs=5, cycles=4, dup=True,
                                   shuffle=True)
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "1")
+    b0 = _batched_launches()
     piped = _drive_pipelined(docs, deltas, 4, step=2)
+    assert _batched_launches() > b0
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
     dense = _drive_pipelined(docs, deltas, 4, step=2)
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
@@ -122,107 +139,113 @@ def test_differential_pipelined_drain(monkeypatch, seed):
     _check_same(serial, dense, docs)
 
 
+def _fallbacks(reason):
+    return sum(
+        e["value"] for e in obs.snapshot()
+        if e["type"] == "counter" and e["name"] == "oplog.compress_fallback"
+        and e["labels"].get("reason") == reason
+    )
+
+
 def test_differential_gate_demoted_mixed_encodings(monkeypatch):
     # high-entropy edits (many tiny objects, scattered splice points)
-    # drive some columns past the run gate: a MIX of run-native stacks
-    # and dense-demoted columns in one launch must still match the
-    # oracle, and the demotions must be counted per column
+    # drive some resident columns past the run gate: a MIX of run-encoded
+    # and dense-demoted columns must still match the oracle through the
+    # shared launch, and the demotions must be counted per column
     docs, deltas = build_workload(13, n_docs=3, cycles=4, shuffle=True)
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "1")
-    fb0 = sum(obs.counter_values(
-        "device.run_native_fallback", "reason").values())
-    native = _drive_staged(docs, deltas, 4)
-    fb1 = sum(obs.counter_values(
-        "device.run_native_fallback", "reason").values())
+    fb0, b0 = _fallbacks("ratio"), _batched_launches()
+    comp = _drive_staged(docs, deltas, 4)
+    for dv in comp:
+        dv.resident_nbytes()  # syncs the compressed image
+    fb1, b1 = _fallbacks("ratio"), _batched_launches()
     monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
     dense = _drive_staged(docs, deltas, 4)
-    _check_same(native, dense, docs)
+    _check_same(comp, dense, docs)
     assert fb1 > fb0  # some column really did demote dense
+    assert b1 > b0
 
 
-# -- staging-level properties -------------------------------------------------
+# -- launch-level properties -------------------------------------------------
 
 
-def _expand_plan(dense, stacks, plan, to_np=np.asarray):
-    """Host-side oracle for the in-jit expansion: w[j] (+ s*i)."""
-    out = {k: to_np(v) for k, v in dense.items()}
-    for (n, rcap, cls, names, bools), arrs in zip(plan, stacks):
-        i = np.arange(n)
-        for idx, name in enumerate(names):
-            w = to_np(arrs[0][idx])
-            cum = to_np(arrs[1][idx])
-            j = np.clip(np.searchsorted(cum, i, side="right"), 0, rcap - 1)
-            col = w[j]
-            if cls == "delta":
-                col = col + int(to_np(arrs[2][idx])) * i
-            out[name] = col.astype(bool) if bools[idx] else col
-    return out
-
-
-def test_staging_expansion_decodes_exactly(monkeypatch):
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    rng = np.random.default_rng(3)
-    n = 256
-    cols = {
-        "action": np.zeros(n, np.int32),                       # 1 run
-        "obj": np.repeat(np.arange(8, dtype=np.int32), 32),    # RLE
-        "elem_ref": (np.arange(n) - 1).astype(np.int32),       # delta
-        "insert": np.ones(n, bool),                            # bool RLE
-        "noise": rng.integers(0, 1 << 20, n).astype(np.int32),  # dense
+def _transport_cols(n=256, seed=3):
+    rng = np.random.default_rng(seed)
+    return {
+        "action": np.ones(n, np.int32),                            # 1 run
+        "insert": np.arange(n) % 64 < 40,                          # bool RLE
+        "value_tag": np.zeros(n, np.int32),
+        "covered": np.ones(n, bool),
+        "prop": np.repeat(np.arange(8, dtype=np.int32), n // 8),   # RLE
+        "elem_ref": (np.arange(n) - 1).astype(np.int32),           # slope
+        "obj_dense": np.zeros(n, np.int32),
+        "value_i32": rng.integers(0, 1 << 20, n).astype(np.int32),  # noise
+        "width": np.ones(n, np.int32),
+        "pred_src": np.arange(n, dtype=np.int32),
+        "pred_tgt": (np.arange(n) - 1).astype(np.int32),
     }
-    dense, stacks, plan = merge.stage_cols_run_native(cols)
-    assert plan, "nothing run-encoded"
-    assert "noise" in dense  # past the gate → shipped dense
-    got = _expand_plan(dense, stacks, plan)
+
+
+def test_packed_transport_decodes_exactly():
+    cols = _transport_cols()
+    n = len(cols["action"])
+    static_key, arrays = merge.encode_transport(cols)
+    run_p, plain_p, run_q, plain_q = static_key
+    assert "elem_ref" in run_p and "value_i32" in plain_p
+    assert set(run_q) == {"pred_src", "pred_tgt"} and not plain_q
+    got = jax.jit(
+        lambda a: merge._unpack_transport(static_key, a, n, n)
+    )({k: np.asarray(v) for k, v in arrays.items()})
     for k, v in cols.items():
-        assert np.array_equal(got[k], v), k
-    # input bytes genuinely undercut the dense image for this shape
-    run_bytes = sum(
-        a.nbytes for arrs in stacks for a in arrs
-    ) + sum(v.nbytes for v in dense.values())
-    assert run_bytes * 2 < sum(
-        np.asarray(v).nbytes for v in cols.values())
+        have = np.asarray(got[k])
+        assert have.dtype == v.dtype and np.array_equal(have, v), k
+    # the noise column alone ships plain; the runs undercut the dense image
+    sent = sum(a.nbytes for a in arrays.values())
+    assert sent * 2 < sum(v.nbytes for v in cols.values())
 
 
-def test_degenerate_columns_demote_with_reasons(monkeypatch):
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
+def test_packed_transport_ships_degenerate_columns_plain():
+    cols = _transport_cols()
     rng = np.random.default_rng(9)
-    n = 128
-    cols = {
-        "action": rng.integers(0, 1 << 20, n).astype(np.int32),  # ratio
-        "wide": np.arange(n, dtype=np.int64),                    # dtype
-    }
-    def fallbacks():
-        # exact (column, reason) series — counter_values collapses
-        # multi-label families last-wins, so read the snapshot
-        return {
-            (e["labels"].get("column"), e["labels"].get("reason")):
-                e["value"]
-            for e in obs.snapshot()
+    cols["prop"] = rng.integers(0, 1 << 20, len(cols["prop"])).astype(
+        np.int32)
+    static_key, arrays = merge.encode_transport(cols)
+    assert set(static_key[1]) == {"prop", "value_i32"}
+    assert arrays["plainP"].shape == (2, len(cols["prop"]))
+
+    def plain(column):
+        return sum(
+            e["value"] for e in obs.snapshot()
             if e["type"] == "counter"
-            and e["name"] == "device.run_native_fallback"
-        }
+            and e["name"] == "oplog.compress_fallback"
+            and e["labels"].get("reason") == "transport"
+            and e["labels"].get("column") == column
+        )
 
-    before = fallbacks()
-    dense, stacks, plan = merge.stage_cols_run_native(cols)
-    after = fallbacks()
-    assert not plan and set(dense) == {"action", "wide"}
-    assert after.get(("action", "ratio"), 0) == \
-        before.get(("action", "ratio"), 0) + 1
-    assert after.get(("wide", "dtype"), 0) == \
-        before.get(("wide", "dtype"), 0) + 1
-    # short columns never run-encode (run table would not pay for itself)
-    _, _, plan2 = merge.stage_cols_run_native(
-        {"action": np.zeros(8, np.int32)})
-    assert not plan2
+    before = {k: plain(k) for k in ("prop", "value_i32", "elem_ref")}
+    merge.count_transport(static_key)
+    assert plain("prop") == before["prop"] + 1
+    assert plain("value_i32") == before["value_i32"] + 1
+    assert plain("elem_ref") == before["elem_ref"]
 
 
-def test_run_native_disabled_restores_eager_staging(monkeypatch):
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "1")
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "0")
-    assert not merge.run_native_enabled()
-    monkeypatch.setenv("AUTOMERGE_TPU_RUN_NATIVE", "1")
-    assert merge.run_native_enabled()
-    monkeypatch.setenv("AUTOMERGE_TPU_COMPRESSED", "0")
-    assert not merge.run_native_enabled()  # dense oracle wins outright
+def test_shared_launch_uploads_plain_columns():
+    docs, _ = build_workload(4, n_docs=1, cycles=1)
+    log = OpLog.from_documents(docs)
+    cols = pad_columns(log.columns(), log.n_objs)
+    dense = sum(np.asarray(v).nbytes for v in cols.values())
+    h0 = obs.counter_values("device.h2d_bytes", "").get("", 0)
+    d0 = obs.counter_values("device.h2d_dense_bytes", "").get("", 0)
+    obs.recorder.clear()
+    out = merge.prepare_resolution(cols, log.n_objs, len(log.props))()
+    assert obs.counter_values("device.h2d_bytes", "").get("", 0) - h0 == dense
+    assert obs.counter_values(
+        "device.h2d_dense_bytes", "").get("", 0) - d0 == dense
+    (k,) = [r for r in obs.recorder.snapshot() if r.name == "device.kernel"]
+    assert k.fields["program"] in ("scatter", "dense")
+    ref = merge.merge_columns(cols, linearize="native",
+                              fetch=merge.RESOLVE_FETCH, n_objs=log.n_objs,
+                              n_props=len(log.props))
+    for f in merge.RESOLVE_FETCH:
+        np.testing.assert_array_equal(
+            np.asarray(out[f])[: len(ref[f])], ref[f], err_msg=f)

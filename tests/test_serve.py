@@ -377,6 +377,33 @@ def test_receive_sync_coalescing_feeds_device_once(server):
     c.close()
 
 
+def test_served_apply_changes_takes_the_chip_route(server):
+    """On the CPU backend as on the chip, a served applyChanges to a
+    device-mirrored document joins the cross-document batcher (a
+    ``device.batch_window`` span) and its document resolves through the
+    packed transport (a ``device.kernel`` span with ``program=packed``)."""
+    c = Client(server.address)
+    d = c.call("openDurable", name="route", device=True)["doc"]
+    w = AutoDoc(actor=ActorId(bytes([4]) * 16))
+    changes = []
+    for i in range(2):
+        w.put("_root", f"k{i}", i)
+        w.commit()
+        changes.append(base64.b64encode(
+            w.get_last_local_change().raw_bytes).decode())
+    c.call("applyChanges", doc=d, data=changes[0])
+    obs.recorder.clear()
+    c.call("applyChanges", doc=d, data=changes[1])
+    spans = obs.recorder.snapshot()
+    assert [r for r in spans if r.name == "device.batch_window"]
+    assert [r for r in spans if r.name == "device.kernel"
+            and r.fields.get("program") == "packed"]
+    dd = server.rpc._docs[d]
+    assert dd.device_doc is not None
+    assert dd.device_doc.hydrate() == {"k0": 0, "k1": 1}
+    c.close()
+
+
 @pytest.mark.parametrize("pipelined", [False, True])
 def test_failed_device_feed_fails_apply_changes(server, monkeypatch,
                                                 pipelined):

@@ -342,11 +342,11 @@ def test_older_readers_keep_their_values():
     dev._apply_append(info, case.delta)
     for k, v in held.items():
         assert np.array_equal(v, before[k]), k
-    # an in-flight handle's rows, through the splice, name the same ops
-    moved = info.map_rows(rows)
+    # rows taken before the splice, through it, name the same ops
+    moved = info.splice.map(rows)
     assert np.array_equal(np.asarray(log.id_key)[moved], old_ids)
     refs = np.array([-3, -2, -1], np.int64)
-    assert np.array_equal(info.map_rows(refs), refs)
+    assert np.array_equal(info.splice.map(refs), refs)
 
 
 def test_row_splice_moves_only_the_suffix():

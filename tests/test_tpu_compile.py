@@ -4,10 +4,10 @@ The TPU compiler is installed here and compiles for a chip that is
 described, not attached: what it refuses (an unaligned slice, too much
 fast memory, a program that cannot be partitioned) fails here at no chip
 time. Each case compiles one kernel at a 4,096-row bucket with the real
-columns' widths and dtypes: the run-native resolution bodies, the
+columns' widths and dtypes: the plain launch's resolution bodies, the
 packed-transport kernel, and the 4-device sharded merge on a mesh of the
-described chips. The run-table expansion of the packed and run-native
-kernels is checked to compile without a loop.
+described chips. The run-table expansion of the packed kernel, over
+either winner body, is checked to compile without a loop.
 
 The topology is described only inside the module fixture (never at
 import or collection): one process at a time may load libtpu, and every
@@ -70,23 +70,20 @@ def _spec(a, sharding):
 
 
 @pytest.mark.parametrize("body", ["core", "scatter", "full"])
-def test_run_native_body_compiles(body, one_chip, doc_log):
+def test_resolution_body_compiles(body, one_chip, doc_log):
     log = doc_log
     cols = pad_columns(log.columns(), log.n_objs)
     Pn = len(cols["action"])
     assert Pn == 4096
-    dense, stacks, plan = M.stage_cols_run_native(cols)
-    assert plan, "the edit-trace columns must run-encode"
     geom = M.resolution_geom(
         Pn, log.n_objs, len(log.props) if body == "scatter" else None,
         full=(body == "full"),
     )
     assert geom[0] == body
     fetch = M.RESOLVE_FETCH + (("elem_index",) if body == "full" else ())
-    fn = M.run_native_kernel(plan, geom, fetch)
+    fn = M.geometry_kernel(geom, fetch)
     compiled = fn.lower(
-        {k: _spec(v, one_chip) for k, v in dense.items()},
-        tuple(tuple(_spec(a, one_chip) for a in st) for st in stacks),
+        {k: _spec(v, one_chip) for k, v in cols.items()}
     ).compile()
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 64 << 20
@@ -114,31 +111,25 @@ def _while_op_names(hlo_text):
     ]
 
 
-@pytest.mark.parametrize("program", ["packed", "run_native_core"])
+@pytest.mark.parametrize("program", ["packed", "packed_core"])
 def test_run_expansion_has_no_loop(program, one_chip, doc_log):
     # the run tables expand with one scatter and a prefix sum; a search
-    # over the runs lowers to a while loop over every row, per column
+    # over the runs lowers to a while loop over every row, per column.
+    # ``packed`` resolves winners with the scatter body, ``packed_core``
+    # with the sort-based one
     from automerge_tpu.ops.device_doc import DeviceDoc
 
     log = doc_log
     cols = pad_columns(log.columns(), log.n_objs)
     Pn, Q = len(cols["action"]), len(cols["pred_src"])
-    if program == "packed":
-        static_key, arrays = M.encode_transport(cols)
-        assert static_key[0], "the edit-trace columns must run-encode"
-        fetch = tuple(k for k in DeviceDoc.READ_FETCH if k != "elem_index")
-        fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn,
-                        Q, M.scatter_geom_key(log.n_objs, len(log.props)))
-        lowered = fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()})
-    else:
-        dense, stacks, plan = M.stage_cols_run_native(cols)
-        assert plan, "the edit-trace columns must run-encode"
-        fn = M.run_native_kernel(plan, M.resolution_geom(Pn, log.n_objs),
-                                 M.RESOLVE_FETCH)
-        lowered = fn.lower(
-            {k: _spec(v, one_chip) for k, v in dense.items()},
-            tuple(tuple(_spec(a, one_chip) for a in st) for st in stacks),
-        )
+    static_key, arrays = M.encode_transport(cols)
+    assert static_key[0], "the edit-trace columns must run-encode"
+    fetch = tuple(k for k in DeviceDoc.READ_FETCH if k != "elem_index")
+    geom = (M.scatter_geom_key(log.n_objs, len(log.props))
+            if program == "packed" else None)
+    fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn, Q,
+                    geom)
+    lowered = fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()})
     names = _while_op_names(lowered.compile().as_text())
     assert not [n for n in names
                 if "transport_unpack" in n or "expand_runs" in n], names
