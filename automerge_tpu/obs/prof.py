@@ -684,8 +684,9 @@ def render_text(summary: dict, top: Optional[int] = None) -> str:
             f"extract cache: {100.0 * ec['cache_hit_ratio']:.1f}% hits "
             f"({ec.get('hits', 0)}/{ec.get('hits', 0) + ec.get('misses', 0)})"
         )
-    # h2d byte accounting: what the packed transport's run tables
-    # actually moved vs their dense equivalent (merge.encode_transport)
+    # h2d byte accounting: what the uploads moved vs their dense
+    # equivalent (only the mesh's run tables, merge.encode_transport,
+    # move fewer)
     hb = summary.get("h2d_bytes", 0)
     if hb:
         ratio = summary.get("h2d_compress_ratio")

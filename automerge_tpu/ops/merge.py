@@ -40,7 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from .oplog import ELEM_HEAD, PAD_ACTION, TAG_COUNTER, _capacity, _next_pow2
+from .oplog import (
+    ELEM_HEAD, PAD_ACTION, PAD_FILLS, TAG_COUNTER, _capacity, _next_pow2,
+)
 
 _DELETE = 3
 _INCREMENT = 5
@@ -719,16 +721,19 @@ def scatter_kernel_core(n_objs: int, n_props: int):
 
 # -- packed transport ---------------------------------------------------------
 #
-# One array each way per launch, with the fewest bytes in both directions:
-#   in : per column, either slope-RLE runs (decoded on device, usually a
-#        few KB total — encode_transport) or a plain int32 column when it
-#        doesn't compress; action/insert/value_tag/covered travel bit-packed
-#        in one flags word
+# A document's own launch moves three arrays:
+#   in : the padded columns plain, written once each into two int32 stacks
+#        (plain_transport): (6, P) per-row columns, action/insert/
+#        value_tag/covered bit-packed into one flags word, and (2, Q) pred
+#        columns, so a program follows the shapes alone. Plain, because
+#        run-encoding them (encode_transport, the mesh path's transport)
+#        costs the host many passes over the rows to save a copy that
+#        PCIe makes in a fraction of that time
 #   out: one flat int32 vector, the requested per-row outputs concatenated;
 #        boolean outputs bit-packed 32/word; per-object stats truncated to
 #        a bucketed object capacity on device
 # Linearization (elem_index) is computed HOST-side by host_linearize from
-# the same numpy columns, overlapped with the device kernel — element
+# the stacks it uploads, overlapped with the device kernel — element
 # order depends only on the insert forest, so it needs neither the merge
 # results nor any extra transfer. device_linearize remains for the
 # pure-device flow (multi-chip dry run, no native core).
@@ -771,6 +776,17 @@ def _flags_column(cols) -> np.ndarray:
         | (cols["value_tag"].astype(np.int32) << _F_TAG_SHIFT)
         | (cols["covered"].astype(np.int32) << 9)
     )
+
+
+def _unflag(cols):
+    """Replace ``cols``' flags word by the four columns it packs (numpy
+    or traced arrays alike)."""
+    flags = cols.pop("flags")
+    cols["action"] = flags & _F_ACTION
+    cols["insert"] = (flags & _F_INSERT) != 0
+    cols["value_tag"] = (flags >> _F_TAG_SHIFT) & 15
+    cols["covered"] = (flags & _F_COVERED) != 0
+    return cols
 
 
 def _slope_rle(x: np.ndarray):
@@ -964,8 +980,29 @@ def prepare_resolution(cols_np, n_objs=None, n_props=None, full=False,
     return dispatch
 
 
+def plain_transport(cols, n_objs: int) -> dict:
+    """The per-document launch's input: ``cols`` (raw or already padded)
+    padded to their buckets with ``pad_columns``' fills, ``obj_dense``
+    with ``n_objs``, each written once into an int32 stack: ``P``, (6, P)
+    in ``_P_ORDER``, and ``Q``, (2, Q) in ``_Q_ORDER``."""
+    fills = dict(PAD_FILLS, obj_dense=n_objs)
+    fills["flags"] = int(_flags_column(
+        {k: np.asarray(v) for k, v in fills.items()}))
+    cols = {k: np.asarray(v) for k, v in cols.items()}
+    cols["flags"] = _flags_column(cols)
+    stacks = {}
+    for name, order, n in (("P", _P_ORDER, len(cols["action"])),
+                           ("Q", _Q_ORDER, len(cols["pred_src"]))):
+        out = stacks[name] = np.empty((len(order), _capacity(n)), np.int32)
+        for i, k in enumerate(order):
+            out[i, :n] = cols[k]
+            out[i, n:] = fills[k]
+    return stacks
+
+
 def encode_transport(cols) -> tuple:
-    """Choose per column between slope-RLE runs and plain transfer.
+    """Choose per column between slope-RLE runs and plain transfer: the
+    mesh path's transport (parallel/sharding.py).
 
     The op columns are extremely runny in real workloads (typing runs give
     ``elem_ref[i] = i-1`` or stride-N interleaves, long spans share one
@@ -1044,12 +1081,15 @@ def _unpack_transport(static_key, arrays, P, Q):
                 cols[k] = runs[i]
         for i, k in enumerate(plain_names):
             cols[k] = arrays[f"plain{gname}"][i]
-    flags = cols.pop("flags")
-    cols["action"] = flags & _F_ACTION
-    cols["insert"] = (flags & _F_INSERT) != 0
-    cols["value_tag"] = (flags >> _F_TAG_SHIFT) & 15
-    cols["covered"] = (flags & _F_COVERED) != 0
-    return cols
+    return _unflag(cols)
+
+
+@_stage("transport_unpack")
+def _unstack(arrays):
+    """``plain_transport``'s stacks back into the kernel's columns."""
+    cols = dict(zip(_P_ORDER, arrays["P"]))
+    cols.update(zip(_Q_ORDER, arrays["Q"]))
+    return _unflag(cols)
 
 
 def _bitpack(v):
@@ -1086,11 +1126,11 @@ def _emit(core, fetch, obj_cap):
     return jnp.concatenate(outs)
 
 
-def _runs_fn(fetch, obj_cap, static_key, P, Q, scatter_geom=None):
-    """The packed-transport resolution program: run tables in, one flat
-    int32 vector out."""
+def _packed_fn(fetch, obj_cap, scatter_geom=None):
+    """The per-document resolution program: ``plain_transport``'s two
+    stacks in, one flat int32 vector out."""
     def packed(arrays):
-        c = _unpack_transport(static_key, arrays, P, Q)
+        c = _unstack(arrays)
         if scatter_geom is not None:
             core = resolve_state_scatter(
                 c, *succ_resolution(c),
@@ -1138,11 +1178,11 @@ def _split_flat(flat, fetch, P, obj_cap):
     return out
 
 
-def _packed_merge(cols_np, fetch, n_objs, n_props=None):
+def _packed_merge(cols_np, fetch, n_objs, n_props, pad_obj):
     from .. import native
 
-    P = len(cols_np["action"])
-    Q = len(cols_np["pred_src"])
+    P = _capacity(len(cols_np["action"]))
+    Q = _capacity(len(cols_np["pred_src"]))
     obj_cap = _obj_cap(n_objs, P)
     fetch = tuple(fetch)
     scatter_geom = (
@@ -1166,24 +1206,22 @@ def _packed_merge(cols_np, fetch, n_objs, n_props=None):
         tuple(k for k in fetch if k != "elem_index") if host_elem else fetch
     )
 
-    static_key, arrays = encode_transport(cols_np)
-    count_transport(static_key)
-    key = (dev_fetch, obj_cap, static_key, P, Q, scatter_geom)
+    key = (dev_fetch, obj_cap, P, Q, scatter_geom)
     fn = _packed_cache.get(key)
     if fn is None:
-        fn = _packed_cache[key] = _runs_fn(
-            dev_fetch, obj_cap, static_key, P, Q, scatter_geom
-        )
-    # the packed transport is already run-encoded (encode_transport);
-    # record the bytes it actually moves so compression wins surface in
-    # perf-report alongside the dict-path staging
-    pk_bytes = sum(a.nbytes for a in arrays.values())
-    with obs.span("device.h2d", rows=P, bytes=pk_bytes):
+        fn = _packed_cache[key] = _packed_fn(dev_fetch, obj_cap, scatter_geom)
+    nbytes = 4 * (len(_P_ORDER) * P + len(_Q_ORDER) * Q)
+    with obs.span("device.h2d", rows=P, bytes=nbytes):
+        arrays = plain_transport(cols_np, pad_obj)
         arrays_dev = {k: jnp.asarray(v) for k, v in arrays.items()}
-    _note_h2d(pk_bytes, sum(np.asarray(v).nbytes for v in cols_np.values()))
+    _note_h2d(nbytes, nbytes)
     with obs.span("device.kernel", rows=P, program="packed"):
         flat_dev = fn(arrays_dev)  # async dispatch
-    elem_index = host_linearize(cols_np) if host_elem else None
+    # ranked from the rows the device got, decoded as it decodes them
+    elem_index = (
+        host_linearize(_unflag(dict(zip(_P_ORDER, arrays["P"]))))
+        if host_elem else None
+    )
     with readback(flat_dev, P):
         flat = np.asarray(flat_dev)
     with obs.span("device.materialize", rows=P):
@@ -1217,12 +1255,13 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
     without it the sort-based kernel runs.
 
     Transport, chosen from the arguments alone and the same on every
-    backend: the packed path (one array each way — see "packed
-    transport" above) whenever ``fetch`` is given and ``linearize`` is
-    left on "auto"; the dict path otherwise (``fetch=None`` or an
-    explicit ``linearize`` pin), which stays the reference. The packed
-    path returns ``conflicts`` as ``conflict_flags`` (1 or 2), the dict
-    path the exact visible-op count.
+    backend: the packed path (two plain stacks in, one array out — see
+    "packed transport" above) whenever ``fetch`` is given and
+    ``linearize`` is left on "auto"; the dict path otherwise
+    (``fetch=None`` or an explicit ``linearize`` pin), which stays the
+    reference. The packed path returns ``conflicts`` as
+    ``conflict_flags`` (1 or 2), the dict path the exact visible-op
+    count.
     """
     from .. import native
 
@@ -1251,10 +1290,10 @@ def merge_columns(cols_np, linearize: str = "auto", fetch=None, n_objs=None,
             else 1
         )
     )
-    cols_np = pad_columns(cols_np, n_objs_eff)
-
     if fetch is not None and linearize == "auto":
-        return _packed_merge(cols_np, fetch, n_objs, n_props)
+        # pads as it stacks the columns for the upload
+        return _packed_merge(cols_np, fetch, n_objs, n_props, n_objs_eff)
+    cols_np = pad_columns(cols_np, n_objs_eff)
 
     if linearize == "auto":
         linearize = "native" if native.preorder_available() else "device"

@@ -1246,6 +1246,22 @@ def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
     return out
 
 
+# what pad_columns writes past a column's rows; ``obj_dense`` pads with
+# the object count and ``aorder`` with the row capacity
+PAD_FILLS = {
+    "action": PAD_ACTION,
+    "insert": False,
+    "prop": -1,
+    "elem_ref": ELEM_MAP,
+    "value_tag": TAG_NULL,
+    "value_i32": 0,
+    "width": 0,
+    "covered": False,
+    "pred_src": 0,
+    "pred_tgt": -1,
+}
+
+
 def pad_columns(cols, n_objs: int, min_capacity: int = 16,
                 min_preds: int = 0):
     """Pad a columns() dict to jit-bucket capacities (idempotent: already
@@ -1253,22 +1269,9 @@ def pad_columns(cols, n_objs: int, min_capacity: int = 16,
     at least ``min_preds`` rows' bucket."""
     p = _capacity(len(cols["action"]), min_capacity)
     q = _capacity(max(len(cols["pred_src"]), min_preds), min_capacity)
-    fills = {
-        "action": PAD_ACTION,
-        "insert": False,
-        "prop": -1,
-        "elem_ref": ELEM_MAP,
-        "obj_dense": np.int32(n_objs),
-        "value_tag": TAG_NULL,
-        "value_i32": 0,
-        "width": 0,
-        "covered": False,
-        "pred_src": 0,
-        "pred_tgt": -1,
-        # compacted element order: pad slots carry the out-of-range
-        # sentinel p (the kernel tests "slot < P" for validity)
-        "aorder": p,
-    }
+    # compacted element order: pad slots carry the out-of-range sentinel
+    # p (the kernel tests "slot < P" for validity)
+    fills = dict(PAD_FILLS, obj_dense=np.int32(n_objs), aorder=p)
     return {
         k: _pad(
             np.asarray(v),

@@ -543,9 +543,8 @@ class DurableDocument:
             dev.obs_name = self.obs_name
             self.device_doc = dev
             dev._export_doc_gauges()
-            # the promotion shipped the compressed image (the resolve's
-            # packed transport moves run tables, merge.encode_transport);
-            # record what warm->hot residency actually costs
+            # record what warm->hot residency costs: the compressed image
+            # the promoted document keeps resident
             obs.count("store.promote_resident_bytes",
                       n=dev.resident_nbytes())
         return dev

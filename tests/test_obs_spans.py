@@ -273,7 +273,7 @@ def test_resolution_programs_carry_the_resolution_names():
 
     log = _device_doc_log()
     cols = pad_columns(log.columns(), log.n_objs)
-    P, Q = len(cols["action"]), len(cols["pred_src"])
+    P = len(cols["action"])
     fetch = M.RESOLVE_FETCH
     resolution = {
         M.merge_kernel, M.merge_kernel_core,
@@ -284,10 +284,8 @@ def test_resolution_programs_carry_the_resolution_names():
             P, log.n_objs, full=True), fetch + ("elem_index",)),
         M.geometry_kernel(M.resolution_geom(
             P, log.n_objs, len(log.props)), fetch),
-        M._runs_fn(fetch, M._obj_cap(log.n_objs, P),
-                   M.encode_transport(cols)[0], P, Q),
-        M._runs_fn(fetch + ("elem_index",), M._obj_cap(log.n_objs, P),
-                   M.encode_transport(cols)[0], P, Q),
+        M._packed_fn(fetch, M._obj_cap(log.n_objs, P)),
+        M._packed_fn(fetch + ("elem_index",), M._obj_cap(log.n_objs, P)),
     }
     assert {fn.__name__ for fn in resolution} == set(M.RESOLUTION_PROGRAMS)
     # the name reaches the XLA module a profile shows

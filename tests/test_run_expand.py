@@ -5,12 +5,15 @@ first row and one prefix sum over the rows. These tests hold it to the
 column ``_slope_rle`` was given, bit for bit, on the shapes the encoder
 produces (one run, constant, slope 1, a stride-N slope, the n/8 gate, a
 run capacity padded far past the runs, values and steps at the ends of
-int32, 32 rows, the flag column the bool columns ride), then hold the
-two launches every backend takes — the packed transport and the shared
-plain launch (``prepare_resolution``) — over compressed and dense
-residency to the dict path's reference (an explicit ``linearize`` pin,
-dense residency) on small edit traces, and check the counters that say
-how many columns a packed launch decoded.
+int32, 32 rows, the flag column the bool columns ride): the mesh path's
+transport. Then they hold the two launches every backend takes — a
+document's own launch (``merge_columns`` with a ``fetch``) and the
+shared launch (``prepare_resolution``), both of which upload plain
+columns — over compressed and dense residency to the dict path's
+reference (an explicit ``linearize`` pin, dense residency) on small edit
+traces, check that one program serves a document's launch whatever its
+columns' runs, and check the counters that say how many columns a launch
+decoded from runs.
 """
 
 import functools
@@ -22,9 +25,12 @@ import jax
 
 from automerge_tpu import bench as W
 from automerge_tpu import obs
+from automerge_tpu.api import AutoDoc
 from automerge_tpu.ops import OpLog, batched
 from automerge_tpu.ops import merge as M
 from automerge_tpu.ops.oplog import _next_pow2, pad_columns
+from automerge_tpu.parallel.sharding import default_mesh, sharded_merge_columns
+from automerge_tpu.types import ActorId
 
 I32_MIN, I32_MAX = -(1 << 31), (1 << 31) - 1
 
@@ -194,20 +200,12 @@ def _runs_expanded(path):
     return obs.counter_values("device.runs_expanded", "path").get(path, 0)
 
 
-@pytest.mark.parametrize("kind", ["edit", "fanin", "typing"])
-@pytest.mark.parametrize("residency", ["compressed", "dense"])
-@pytest.mark.parametrize("site", ["batched", "packed"])
-def test_site_matches_the_dense_oracle(site, residency, kind, monkeypatch):
-    dense = _reference(kind)
-    log = _log(kind, monkeypatch, residency)
-    assert log.n == len(dense["visible"][: log.n])
-    before = _runs_expanded("packed")
-    got = _resolve(log, site)
-    if site == "packed":
-        assert _runs_expanded("packed") > before, \
-            "no column was decoded from runs"
-    else:
-        assert _runs_expanded("packed") == before
+def _h2d():
+    return tuple(obs.counter_values(name, "").get("", 0)
+                 for name in ("device.h2d_bytes", "device.h2d_dense_bytes"))
+
+
+def _matches(got, dense, log):
     for k in M.RESOLVE_FETCH:
         have, want = got[k], dense[k]
         if k == "conflicts":
@@ -219,6 +217,68 @@ def test_site_matches_the_dense_oracle(site, residency, kind, monkeypatch):
         np.testing.assert_array_equal(have[:m], want[:m], err_msg=k)
 
 
+@pytest.mark.parametrize("kind", ["edit", "fanin", "typing"])
+@pytest.mark.parametrize("residency", ["compressed", "dense"])
+@pytest.mark.parametrize("site", ["batched", "packed"])
+def test_site_matches_the_dense_oracle(site, residency, kind, monkeypatch):
+    dense = _reference(kind)
+    log = _log(kind, monkeypatch, residency)
+    assert log.n == len(dense["visible"][: log.n])
+    before = _runs_expanded("packed")
+    h0, d0 = _h2d()
+    got = _resolve(log, site)
+    # neither launch decodes a column from runs: both upload plain
+    assert _runs_expanded("packed") == before
+    if site == "packed":
+        # the two stacks, each padded column written once: what moved
+        # is its own dense size
+        cols = pad_columns(log.columns(), log.n_objs)
+        P, Q = len(cols["action"]), len(cols["pred_src"])
+        h1, d1 = _h2d()
+        assert h1 - h0 == d1 - d0 == 4 * (6 * P + 2 * Q)
+    _matches(got, dense, log)
+
+
+def _map_log(runny):
+    """512 puts of one change to 16 root keys, each key written first in
+    order and then overwritten (preds), so both kinds pad to the same
+    rows, preds and group geometry. ``runny``: keys in blocks of 32 and
+    values counting up (their columns run-encode); otherwise keys and
+    values drawn at random (``prop`` and ``value_i32`` do not)."""
+    rng = np.random.default_rng(11)
+    doc = AutoDoc(actor=ActorId(bytes([7]) * 16))
+    keys = (np.arange(512) // 32 if runny else
+            np.concatenate([np.arange(16), rng.integers(0, 16, 496)]))
+    values = np.arange(512) if runny else rng.integers(0, 1 << 20, 512)
+    for k, v in zip(keys, values):
+        doc.put("_root", f"k{k:02d}", int(v))
+    doc.commit()
+    return OpLog.from_documents([doc])
+
+
+def test_one_program_serves_columns_that_compress_differently(monkeypatch):
+    # the run encoder tells the two logs apart, as the per-document
+    # program's key once did; with plain stacks one program serves both
+    monkeypatch.setattr(M, "_packed_cache", {})
+    logs = [_map_log(runny) for runny in (True, False)]
+    keys = [M.encode_transport(pad_columns(log.columns(), log.n_objs))[0]
+            for log in logs]
+    assert keys[0] != keys[1]
+    assert {"prop", "value_i32"} <= set(keys[1][1]) - set(keys[0][1])
+    shapes = set()
+    for log in logs:
+        kw = dict(n_objs=log.n_objs, n_props=len(log.props))
+        stacks = M.plain_transport(log.columns(), log.n_objs)
+        shapes.add((stacks["P"].shape, stacks["Q"].shape, kw["n_objs"],
+                    kw["n_props"]))
+        got = M.merge_columns(log.columns(), fetch=M.RESOLVE_FETCH, **kw)
+        dense = M.merge_columns(log.columns(), fetch=M.RESOLVE_FETCH,
+                                linearize="native", **kw)
+        _matches(got, dense, log)
+    assert len(shapes) == 1
+    assert len(M._packed_cache) == 1
+
+
 def _fallbacks(reason):
     return sum(
         e["value"] for e in obs.snapshot()
@@ -228,6 +288,9 @@ def _fallbacks(reason):
 
 
 def test_packed_launch_counts_every_column(monkeypatch):
+    # a document's own launch decodes nothing from runs and counts no
+    # column; the mesh's packed transport counts each column once,
+    # decoded from runs or shipped plain
     log = _log("edit")
     cols = pad_columns(log.columns(), log.n_objs)
     static_key, _ = M.encode_transport(cols)
@@ -237,5 +300,9 @@ def test_packed_launch_counts_every_column(monkeypatch):
     r0, f0 = _runs_expanded("packed"), _fallbacks("transport")
     _resolve(log, "packed")
     r1, f1 = _runs_expanded("packed"), _fallbacks("transport")
-    assert (r1 - r0, f1 - f0) == (runs, plain)
-    assert r1 - r0 + f1 - f0 == len(M._P_ORDER) + len(M._Q_ORDER)
+    assert (r1, f1) == (r0, f0)
+    sharded_merge_columns(cols, default_mesh(2), n_objs=log.n_objs,
+                          n_props=len(log.props), transport="packed")
+    r2, f2 = _runs_expanded("packed"), _fallbacks("transport")
+    assert (r2 - r1, f2 - f1) == (runs, plain)
+    assert r2 - r1 + f2 - f1 == len(M._P_ORDER) + len(M._Q_ORDER)

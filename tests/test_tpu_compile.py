@@ -94,11 +94,11 @@ def test_packed_transport_kernel_compiles(one_chip, doc_log):
 
     log = doc_log
     cols = pad_columns(log.columns(), log.n_objs)
-    Pn, Q = len(cols["action"]), len(cols["pred_src"])
-    static_key, arrays = M.encode_transport(cols)
+    Pn = len(cols["action"])
+    arrays = M.plain_transport(cols, log.n_objs)
     fetch = tuple(k for k in DeviceDoc.READ_FETCH if k != "elem_index")
-    fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn, Q,
-                    M.scatter_geom_key(log.n_objs, len(log.props)))
+    fn = M._packed_fn(fetch, M._obj_cap(log.n_objs, Pn),
+                      M.scatter_geom_key(log.n_objs, len(log.props)))
     fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()}).compile()
 
 
@@ -113,8 +113,9 @@ def _while_op_names(hlo_text):
 
 @pytest.mark.parametrize("program", ["packed", "packed_core"])
 def test_run_expansion_has_no_loop(program, one_chip, doc_log):
-    # the run tables expand with one scatter and a prefix sum; a search
-    # over the runs lowers to a while loop over every row, per column.
+    # the mesh path's run tables expand with one scatter and a prefix
+    # sum; a search over the runs lowers to a while loop over every row,
+    # per column. Here they feed the per-document program's resolution:
     # ``packed`` resolves winners with the scatter body, ``packed_core``
     # with the sort-based one
     from automerge_tpu.ops.device_doc import DeviceDoc
@@ -125,10 +126,12 @@ def test_run_expansion_has_no_loop(program, one_chip, doc_log):
     static_key, arrays = M.encode_transport(cols)
     assert static_key[0], "the edit-trace columns must run-encode"
     fetch = tuple(k for k in DeviceDoc.READ_FETCH if k != "elem_index")
-    geom = (M.scatter_geom_key(log.n_objs, len(log.props))
-            if program == "packed" else None)
-    fn = M._runs_fn(fetch, M._obj_cap(log.n_objs, Pn), static_key, Pn, Q,
-                    geom)
+    obj_cap = M._obj_cap(log.n_objs, Pn)
+    geom = (("scatter", *M.scatter_geom_key(log.n_objs, len(log.props)))
+            if program == "packed" else ("core", obj_cap))
+    body = M._resolution_body(geom)
+    fn = jax.jit(lambda a: M._emit(
+        body(M._unpack_transport(static_key, a, Pn, Q)), fetch, obj_cap))
     lowered = fn.lower({k: _spec(v, one_chip) for k, v in arrays.items()})
     names = _while_op_names(lowered.compile().as_text())
     assert not [n for n in names
